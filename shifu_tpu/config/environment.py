@@ -159,8 +159,10 @@ _declare("SHIFU_TPU_H2D_DOUBLE_BUFFER", "flag", "1",
          "1 = place chunk N+1 on device while chunk N computes "
          "(auto-disabled on the cpu backend unless set explicitly)")
 _declare("SHIFU_TPU_COMPILE_CACHE_DIR", "str", None,
-         "persistent XLA compilation cache dir; unset = auto under "
-         "the model workspace tmp/, 0/off/none = disabled")
+         "persistent XLA compilation cache dir when "
+         "JAX_COMPILATION_CACHE_DIR is NOT set (that one, set from "
+         "outside, always wins); unset = the fixed <checkout>/.jax_cache, "
+         "0/off/none = disabled")
 _declare("SHIFU_TPU_COMPILE_CACHE_MIN_S", "float", 0.0,
          "minimum compile seconds before a kernel is cached "
          "(jax_persistent_cache_min_compile_time_secs)")
@@ -241,7 +243,9 @@ _declare("SHIFU_TPU_HIST_PRECISION", "str", None,
 _declare("SHIFU_TPU_HIST_SUBTRACT", "bool", "1",
          "sibling-subtraction trick in GBT histogram builds")
 _declare("SHIFU_TPU_HIST_VMEM_MB", "int", 64,
-         "VMEM budget for pallas histogram tiling")
+         "VMEM budget for pallas histogram tiling — the tiles are "
+         "derived from it AND the kernels are compiled with it as "
+         "their VMEM limit")
 _declare("SHIFU_TPU_GBT_ROUTE", "str", "gather",
          "GBT split-feature routing: gather | onehot")
 _declare("SHIFU_TPU_GBT_SCAN_GROUP", "int", 0,
@@ -268,8 +272,9 @@ _declare("SHIFU_TPU_TREE_FUSED", "str", "auto",
          "binning + whole-ensemble breadth-first walk + convert in "
          "one pallas kernel): auto | pallas | xla")
 _declare("SHIFU_TPU_TREE_VMEM_MB", "int", 64,
-         "VMEM budget for the fused tree-inference kernel's row "
-         "tiling (pallas_trees._derive_row_tile)")
+         "VMEM budget for the fused tree-inference kernel's row/tree "
+         "tiling (pallas_trees._derive_tiles); also its compiled "
+         "VMEM limit")
 _declare("SHIFU_TPU_TREE_SCAN", "bool", "1",
          "1 = build_tree/build_forest and the resident streaming GBT "
          "tier grow all levels inside one lax.fori_loop dispatch "

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from shifu_tpu.config.environment import knob_int, knob_str
 
@@ -101,27 +102,46 @@ def pack_ensemble(trees: Dict[str, Any]) -> Tuple[np.ndarray, int]:
     return packed, n_pad
 
 
-def _derive_row_tile(s_nodes: int, n_cols: int, n_cuts: int) -> int:
-    """Row tile sized to the SHIFU_TPU_TREE_VMEM_MB budget. Per grid
-    step the kernel keeps ~6 live (S, TR) f32 maps (routed bin,
-    go_left, stop, leaf value, the select and its masked operand)
-    plus the (C, TR) value/bin tiles and the resident (8, S) node
-    block + (C, K) cuts."""
+# Mosaic unrolls every vector op over the block's vregs, so compile
+# time (and VMEM) grow with the (tree-tile nodes × row tile) maps, not
+# with the ensemble: 256 vregs (1024 f32 each) per map keeps a bucket's
+# compile to seconds where a whole 500-tree ensemble would not fit VMEM
+_MAP_ELEMS = 256 * 1024
+
+
+def _derive_tiles(n_trees: int, n_pad: int, n_cols: int, n_cuts: int,
+                  n_rows: int):
+    """(row_tile, tree_tile). The row tile covers the request (a
+    multiple of 128 lanes, at most 2048); the tree tile is however many
+    whole trees keep one (tree-tile nodes, row tile) f32 map inside
+    both the compile-time cap and SHIFU_TPU_TREE_VMEM_MB — per grid
+    step the kernel keeps ~12 such maps live (routed bin, the four
+    broadcast node scalars, go_left, the walk's selects and their
+    masked operands) plus the (C, TR) value/bin tiles and the resident
+    (8, S) node block + (C, K) cuts."""
     budget = knob_int("SHIFU_TPU_TREE_VMEM_MB") << 20
-    fixed = 4 * (8 * s_nodes + n_cols * max(n_cuts, 1))
-    per_row = 4 * (6 * s_nodes + 3 * n_cols + 16)
-    tile = (budget - fixed) // max(per_row, 1)
-    tile = max(128, min(2048, (tile // 128) * 128))
-    return int(tile)
+    row_tile = max(128, min(2048, -(-n_rows // 128) * 128))
+    while True:
+        fixed = 4 * (n_cols * max(n_cuts, 1) + 3 * n_cols * row_tile)
+        elems = min(_MAP_ELEMS, max(0, budget - fixed) // (4 * 12))
+        tree_tile = elems // (n_pad * row_tile)
+        if tree_tile >= 1 or row_tile == 128:
+            break
+        row_tile = max(128, row_tile // 2 // 128 * 128)
+    return int(row_tile), int(max(1, min(n_trees, tree_tile)))
 
 
 def _tree_kernel(vals_ref, cuts_ref, nodes_ref, out_ref, *,
-                 n_trees: int, n_pad: int, n_cols: int, n_bins: int,
-                 n_cuts: int, max_depth: int, kind: str, loss: str,
-                 lr: float):
+                 n_trees: int, tree_tile: int, n_pad: int, n_cols: int,
+                 n_bins: int, n_cuts: int, max_depth: int, kind: str,
+                 loss: str, lr: float):
+    # grid = (row_tiles, tree_tiles): the TREE (reduction) dimension is
+    # innermost, so each output block's revisits are consecutive grid
+    # steps — the += accumulation pattern on TPU
+    t = pl.program_id(1)
     v = vals_ref[:, :]                                # (C, TR) raw
     tr = v.shape[1]
-    s = n_trees * n_pad
+    s = tree_tile * n_pad
     # in-register binning — bins_from_values semantics (+inf pad cuts
     # never fire for finite values; the clamp keeps the Σ at the last
     # main bin when they do for +inf values)
@@ -136,9 +156,10 @@ def _tree_kernel(vals_ref, cuts_ref, nodes_ref, out_ref, *,
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     # every node's routed bin for every row: one-hot(feature) × bins on
-    # the MXU — 0/1 times integer-valued f32, exact at HIGHEST
-    feat = nodes_ref[0:1, :]                          # (1, S)
-    oh = (jax.lax.broadcasted_iota(jnp.float32, (n_cols, s), 0)
+    # the MXU — 0/1 times integer-valued f32, exact at HIGHEST. Mosaic
+    # only makes integer iotas, so ids are compared as int32.
+    feat = nodes_ref[0:1, :].astype(jnp.int32)        # (1, S)
+    oh = (jax.lax.broadcasted_iota(jnp.int32, (n_cols, s), 0)
           == feat).astype(jnp.float32)                # (C, S)
     rb = dot(oh, bins)                                # (S, TR)
     # per-node scalars broadcast across rows as ones-outer-products
@@ -149,70 +170,90 @@ def _tree_kernel(vals_ref, cuts_ref, nodes_ref, out_ref, *,
     lval = dot(nodes_ref[4:5, :], ones)
 
     miss = rb == float(n_bins - 1)
-    go_left = jnp.where(miss, dl > 0.0,
-                        rb <= sbin).astype(jnp.float32)
+    # (selecting between two bool vectors does not lower — widen first)
+    go_left = jnp.where(miss, (dl > 0.0).astype(jnp.int32),
+                        (rb <= sbin).astype(jnp.int32))
     # flat (S, TR) → (T, N_pad, TR): N_pad is a sublane multiple so the
     # split is tile-aligned; the walk is select-only from here on
-    gl3 = go_left.reshape(n_trees, n_pad, tr)
-    st3 = stop.reshape(n_trees, n_pad, tr)
-    lv3 = lval.reshape(n_trees, n_pad, tr)
-    iota_n = jax.lax.broadcasted_iota(jnp.float32,
-                                      (n_trees, n_pad, tr), 1)
-    node = jnp.zeros((n_trees, 1, tr), jnp.float32)
+    gl3 = go_left.reshape(tree_tile, n_pad, tr)
+    st3 = (stop > 0.0).astype(jnp.int32).reshape(tree_tile, n_pad, tr)
+    lv3 = lval.reshape(tree_tile, n_pad, tr)
+    iota_n = jax.lax.broadcasted_iota(jnp.int32,
+                                      (tree_tile, n_pad, tr), 1)
+    node = jnp.zeros((tree_tile, 1, tr), jnp.int32)
     for _ in range(max_depth):
         sel = iota_n == node                          # (T, N_pad, TR)
-        gl_here = jnp.max(jnp.where(sel, gl3, 0.0), axis=1,
+        gl_here = jnp.max(jnp.where(sel, gl3, 0), axis=1,
                           keepdims=True)              # (T, 1, TR)
-        st_here = jnp.max(jnp.where(sel, st3, 0.0), axis=1,
+        st_here = jnp.max(jnp.where(sel, st3, 0), axis=1,
                           keepdims=True)
-        # left child 2i+1, right 2i+2 — node ids < 2^24 stay f32-exact
-        nxt = 2.0 * node + 2.0 - gl_here
-        node = jnp.where(st_here > 0.0, node, nxt)
+        # left child 2i+1, right 2i+2
+        node = jnp.where(st_here > 0, node, 2 * node + 2 - gl_here)
     sel = iota_n == node
     contrib = jnp.sum(jnp.where(sel, lv3, 0.0), axis=1,
                       keepdims=True)                  # (T, 1, TR)
     total = jnp.sum(contrib, axis=0)                  # (1, TR)
 
-    if kind == "rf":
-        score = total / float(n_trees)
-    else:
-        raw = float(lr) * total
-        if loss.startswith("log"):
-            raw = jnp.clip(raw, -30.0, 30.0)          # predict()'s clip
-            score = 1.0 / (1.0 + jnp.exp(-raw))
+    @pl.when(t == 0)
+    def _init():
+        out_ref[:, :] = jnp.broadcast_to(total, out_ref.shape)
+
+    @pl.when(t > 0)
+    def _accum():
+        out_ref[:, :] += jnp.broadcast_to(total, out_ref.shape)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _convert():
+        total_ = out_ref[:, :]
+        if kind == "rf":
+            score = total_ / float(n_trees)
         else:
-            score = raw
-    out_ref[:, :] = jnp.broadcast_to(score, out_ref.shape)
+            raw = float(lr) * total_
+            if loss.startswith("log"):
+                raw = jnp.clip(raw, -30.0, 30.0)      # predict()'s clip
+                score = 1.0 / (1.0 + jnp.exp(-raw))
+            else:
+                score = raw
+        out_ref[:, :] = score
 
 
 @functools.partial(jax.jit, static_argnames=(
     "n_trees", "kind", "loss", "learning_rate", "max_depth", "n_bins",
-    "row_tile", "interpret"))
+    "row_tile", "tree_tile", "interpret"))
 def _predict_ensemble_pallas(nodes, valuesT, cuts, n_trees: int,
                              kind: str, loss: str, learning_rate: float,
                              max_depth: int, n_bins: int, row_tile: int,
-                             interpret: bool):
+                             tree_tile: int, interpret: bool):
     c, r = valuesT.shape
     s = nodes.shape[1]
+    n_pad = s // n_trees
     k = cuts.shape[1]
     pad_r = (-r) % row_tile
     vp = jnp.pad(valuesT.astype(jnp.float32), ((0, 0), (0, pad_r)))
     rp = r + pad_r
-    grid = (rp // row_tile,)
+    # pad trees are a lone parked root with leaf value 0: they add 0
+    pad_s = ((-n_trees) % tree_tile) * n_pad
+    nodes = jnp.pad(nodes, ((0, 0), (0, pad_s)))
+    nodes = nodes.at[0, s:].set(-1.0).at[3, s:].set(1.0)
+    ts = tree_tile * n_pad
+    grid = (rp // row_tile, (s + pad_s) // ts)
 
     out = pl.pallas_call(
         functools.partial(
-            _tree_kernel, n_trees=n_trees, n_pad=s // n_trees,
-            n_cols=c, n_bins=n_bins, n_cuts=k, max_depth=max_depth,
-            kind=kind, loss=loss, lr=learning_rate),
+            _tree_kernel, n_trees=n_trees, tree_tile=tree_tile,
+            n_pad=n_pad, n_cols=c, n_bins=n_bins, n_cuts=k,
+            max_depth=max_depth, kind=kind, loss=loss, lr=learning_rate),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((c, row_tile), lambda i: (0, i)),
-            pl.BlockSpec((c, k), lambda i: (0, 0)),
-            pl.BlockSpec((8, s), lambda i: (0, 0)),
+            pl.BlockSpec((c, row_tile), lambda i, t: (0, i)),
+            pl.BlockSpec((c, k), lambda i, t: (0, 0)),
+            pl.BlockSpec((8, ts), lambda i, t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((8, row_tile), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((8, row_tile), lambda i, t: (0, i)),
         out_shape=jax.ShapeDtypeStruct((8, rp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=knob_int("SHIFU_TPU_TREE_VMEM_MB") << 20),
         interpret=interpret,
     )(vp, cuts.astype(jnp.float32), nodes)
     return out[0, :r]
@@ -221,17 +262,19 @@ def _predict_ensemble_pallas(nodes, valuesT, cuts, n_trees: int,
 def predict_ensemble(nodes, valuesT, cuts, *, n_trees: int, kind: str,
                      loss: str = "squared", learning_rate: float = 0.1,
                      max_depth: int, n_bins: int, row_tile: int = 0,
-                     interpret: bool = False):
+                     tree_tile: int = 0, interpret: bool = False):
     """Packed ensemble (`pack_ensemble`) + FusedBins-style raw inputs
     (`gbdt.make_fused_inputs`: valuesT (C, R) f32 NaN-missing, cuts
     (C, K) +inf-padded) → (R,) final scores with `gbdt.predict`
     convert semantics (RF mean; GBT lr·sum, log loss → ±30-clip
-    sigmoid). One kernel launch per row tile — no host binning, no
-    per-level walk dispatches."""
-    if not row_tile:
-        row_tile = _derive_row_tile(nodes.shape[1], valuesT.shape[0],
-                                    cuts.shape[1])
+    sigmoid). One kernel launch for the whole request — no host
+    binning, no per-level walk dispatches; trees beyond one tile's
+    worth accumulate across the inner grid axis."""
+    d_row, d_tree = _derive_tiles(n_trees, nodes.shape[1] // n_trees,
+                                  valuesT.shape[0], cuts.shape[1],
+                                  valuesT.shape[1])
     return _predict_ensemble_pallas(
         nodes, valuesT, cuts, n_trees=n_trees, kind=kind, loss=loss,
         learning_rate=float(learning_rate), max_depth=max_depth,
-        n_bins=n_bins, row_tile=row_tile, interpret=interpret)
+        n_bins=n_bins, row_tile=row_tile or d_row,
+        tree_tile=tree_tile or d_tree, interpret=interpret)

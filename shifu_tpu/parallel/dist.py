@@ -282,24 +282,15 @@ def _multi_process() -> bool:
     means probing — and possibly hanging on — an unreachable
     accelerator the command never needed.
 
-    - a backend is already live (every device-using command) → ask it;
-    - `jax.distributed` client present (explicit SHIFU_TPU_* init) →
-      multi-process;
-    - neither → treat as single-process: a FILE-ONLY command on a
-      TPU pod then writes identical content from every host without
-      the guard (the pre-guard behavior), which beats hanging every
-      laptop/CI `init` on an unreachable accelerator."""
-    try:
-        from jax._src import xla_bridge
-        if getattr(xla_bridge, "_backends", None):
-            return jax.process_count() > 1
-    except Exception as e:
-        absorbed("dist.backend-probe", e)
-    try:
-        from jax._src import distributed
-        return distributed.global_state.client is not None
-    except Exception:  # internal API moved: fall back to the real call
-        return jax.process_count() > 1
+    More than one process exists only after `jax.distributed` was
+    initialized (`initialize()` above does it for every device command
+    of a multi-host run), and `jax.distributed.is_initialized()`
+    answers that without touching a backend. A FILE-ONLY command on a
+    TPU pod (no distributed init) is thus treated as single-process and
+    writes identical content from every host without the guard, which
+    beats hanging every laptop/CI `init` on an unreachable
+    accelerator."""
+    return jax.distributed.is_initialized() and jax.process_count() > 1
 
 
 def is_writer() -> bool:

@@ -240,7 +240,8 @@ def pipeline_nodes(root: str, eval_sets: Sequence[str] = (),
     then every eval set as a sibling node. With ``algorithms`` (e.g.
     ``["NN", "GBT", "WDL"]``) training fans out: the first algorithm
     trains in the model-set workspace, the rest in clone workspaces
-    sharing the parent's normalized data and compile cache."""
+    sharing the parent's normalized data (and, like every command, the
+    one compile cache `profiling.enable_compile_cache` resolves)."""
     res = _resume_enabled(resume)
     stats_nodes, stats_dep = _stats_nodes(root, res)
     nodes = [
@@ -250,17 +251,13 @@ def pipeline_nodes(root: str, eval_sets: Sequence[str] = (),
     ]
     algorithms = list(algorithms)
     if len(algorithms) > 1:
-        cache_env = {"SHIFU_TPU_COMPILE_CACHE_DIR":
-                     os.path.join(root, "tmp", "jax_cache")}
         share = _sibling_demand(len(algorithms))
         primary, train_name = algorithms[0], f"train.{algorithms[0]}"
         nodes.append(_node(root, "train", ["train"], ("norm",), res,
-                           name=train_name, env_extra=cache_env,
-                           devices=share))
+                           name=train_name, devices=share))
         for alg in algorithms[1:]:
             nodes.append(variant_node(root, f"train.{alg}", ("norm",),
                                       algorithm=alg, resume=res,
-                                      env_extra=cache_env,
                                       devices=share))
     else:
         train_name = "train"
@@ -287,13 +284,11 @@ def grid_nodes(root: str, grid_params: Sequence[Dict],
         *stats_nodes,
         _node(root, "norm", ["norm"], (stats_dep,), res),
     ]
-    cache_env = {"SHIFU_TPU_COMPILE_CACHE_DIR":
-                 os.path.join(root, "tmp", "jax_cache")}
     share = _sibling_demand(len(grid_params))
     for i, params in enumerate(grid_params):
         nodes.append(variant_node(root, f"train.grid{i}", ("norm",),
                                   params=params, resume=res,
-                                  env_extra=cache_env, devices=share))
+                                  devices=share))
     return nodes
 
 
@@ -306,8 +301,7 @@ def variant_node(root: str, name: str, deps: Tuple[str, ...],
     """A sibling trainer in a clone workspace under
     ``tmp/dag_models/<name>``: same data, same ColumnConfig, different
     algorithm and/or train params. The clone is prepared lazily inside
-    the node body — after the parent's norm finished — and shares the
-    parent's compile cache via ``env_extra``. ``devices`` declares the
+    the node body — after the parent's norm finished. ``devices`` declares the
     sibling's slice demand (fan-out builders pass the equal split)."""
     clone = variant_dir(root, name)
 

@@ -152,19 +152,17 @@ def _train_sub_node(root: str, sub_dir: str, name: str) -> None:
     module's __main__ hook), so sibling subs scheduled concurrently
     keep their process-global state — abort scope, stage timers, jax
     config — as isolated as the serial loop kept it. All siblings
-    share the combo workspace's persistent compile cache."""
+    resolve the same persistent compile cache
+    (`profiling.enable_compile_cache`)."""
     import subprocess
     import sys
     log_dir = os.path.join(root, "tmp", "dag_logs")
     os.makedirs(log_dir, exist_ok=True)
     log_path = os.path.join(log_dir, f"{name.replace('/', '_')}.log")
-    env = dict(os.environ)
-    env["SHIFU_TPU_COMPILE_CACHE_DIR"] = \
-        os.path.join(root, "tmp", "jax_cache")
     with open(log_path, "w") as lf:  # lint: disable=non-atomic-write -- live-tailed subprocess log; must exist mid-run
         rc = subprocess.call(
             [sys.executable, "-m", "shifu_tpu.processor.combo", sub_dir],
-            stdout=lf, stderr=subprocess.STDOUT, env=env)
+            stdout=lf, stderr=subprocess.STDOUT)
     if rc != 0:
         try:
             with open(log_path, errors="replace") as lf:
@@ -435,4 +433,6 @@ if __name__ == "__main__":
     import sys
     logging.basicConfig(level=logging.INFO,
                         format="%(levelname)s %(name)s %(message)s")
+    from shifu_tpu.profiling import enable_compile_cache
+    enable_compile_cache()   # what cli.main does for a CLI child
     _train_sub(sys.argv[1])

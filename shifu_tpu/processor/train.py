@@ -55,14 +55,6 @@ def run(ctx: ProcessorContext, seed: int = 12306) -> int:
         if not go:
             return 0
 
-        # persistent XLA compile cache under the model workspace: the
-        # supervise/preempt/grid-search re-entry paths below re-trace
-        # the same jits, and every restarted process re-pays the full
-        # compile without it (compile_s / compile_cache_hits in
-        # steps.jsonl show the effect)
-        from shifu_tpu import profiling
-        profiling.enable_compile_cache(ctx.path_finder.root)
-
         def _attempt():
             if alg in (Algorithm.NN, Algorithm.LR, Algorithm.SVM):
                 return _train_dense(ctx, seed)

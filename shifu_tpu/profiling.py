@@ -14,13 +14,16 @@ metrics plus `jax.profiler` traces.
 - `shifu --profile <cmd>` additionally captures a `jax.profiler` trace
   under `tmp/profile/<step>-<timestamp>/` — openable in TensorBoard /
   Perfetto for op-level TPU timing;
-- `enable_compile_cache(root)` points jax's persistent compilation
-  cache under the model workspace (`SHIFU_TPU_COMPILE_CACHE_DIR`
-  overrides; `0`/`off` disables) and registers `jax.monitoring`
-  listeners so per-jit compile time and cache hit/miss counts land in
-  the stage timers (`compile_s`, `compile_cache_hits`,
-  `compile_cache_misses`) and thence in `steps.jsonl` — restart /
-  resume / supervise / grid-search paths stop re-paying XLA compiles;
+- `enable_compile_cache()` (called once by `cli.main` for every
+  device command) turns on jax's persistent compilation cache — at
+  `JAX_COMPILATION_CACHE_DIR` when the environment sets it, else
+  `SHIFU_TPU_COMPILE_CACHE_DIR` (`0`/`off` disables), else the fixed
+  `<checkout>/.jax_cache` — and registers `jax.monitoring` listeners so
+  per-jit compile time and cache hit/miss counts land in the stage
+  timers (`compile_s`, `compile_cache_hits`, `compile_cache_misses` — a
+  miss is a program compiled AND written to the cache) and thence in
+  `steps.jsonl` — restart / resume / supervise / grid-search paths stop
+  re-paying XLA compiles;
 - `SHIFU_TPU_COMPILE_CACHE_SHARED` names a cluster-shared cache dir (a
   mounted path or a `scheme://` URL; a `scheme://`
   SHIFU_TPU_COMPILE_CACHE_DIR auto-routes here too): entries pull into
@@ -42,6 +45,7 @@ from typing import Dict, Optional, Tuple
 log = logging.getLogger("shifu_tpu")
 
 _DISABLED_VALUES = ("0", "off", "none", "disabled", "false", "no")
+_CACHE_MAX_BYTES = 16 << 30   # LRU bound; its real job is the file lock
 _compile_listeners_on = False
 _cache_push_registered: Optional[tuple] = None
 
@@ -169,16 +173,30 @@ def _register_cache_push(local_dir: str, shared_dir: str) -> None:
     _cache_push_registered = (local_dir, shared_dir)
 
 
-def enable_compile_cache(workspace_root: Optional[str] = None) -> \
-        Optional[str]:
+def default_cache_dir() -> str:
+    """The one place the compile cache lives when nobody places it:
+    `<checkout>/.jax_cache` (git-ignored). The directory is part of the
+    cache key's lookup, so it must not move between runs — never under
+    a model set, the temp dir, a pid or a timestamp."""
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache() -> Optional[str]:
     """Turn on jax's persistent compilation cache and the compile-time
-    counters. Resolution order for the cache dir: an explicit
-    `SHIFU_TPU_COMPILE_CACHE_DIR` wins (`0`/`off`/`none` = disabled);
-    unset, an already-configured jax (e.g. `JAX_COMPILATION_CACHE_DIR`
-    in the environment) is left alone; otherwise the cache defaults to
-    `<workspace_root>/tmp/jax_cache`. Returns the active cache dir or
-    None when disabled. Never raises — a cache failure must not take
-    down training."""
+    counters. Where the cache lives, in order:
+
+    1. `JAX_COMPILATION_CACHE_DIR` in the environment places it from
+       outside and nothing in this program overrides it — not
+       `SHIFU_TPU_COMPILE_CACHE_DIR`, not a launcher;
+    2. else `SHIFU_TPU_COMPILE_CACHE_DIR` (`0`/`off`/`none` disables;
+       a `scheme://` value names the SHARED cache, see below);
+    3. else `default_cache_dir()`, one fixed path in the checkout.
+
+    `SHIFU_TPU_COMPILE_CACHE_SHARED` (or a scheme:// value in 2) is
+    mirrored into that local directory at start and published back at
+    exit. Returns the active cache dir or None when disabled. Never
+    raises — a cache failure must not take down a run."""
     try:
         _register_compile_listeners()
     except Exception as e:  # noqa: BLE001 — metrics must never fail a run
@@ -188,34 +206,27 @@ def enable_compile_cache(workspace_root: Optional[str] = None) -> \
         from shifu_tpu.config.environment import knob_float, knob_str
         from shifu_tpu.data import fs as fs_mod
         explicit = knob_str("SHIFU_TPU_COMPILE_CACHE_DIR")
-        if explicit is not None and \
-                explicit.strip().lower() in _DISABLED_VALUES:
-            return None
         shared = knob_str("SHIFU_TPU_COMPILE_CACHE_SHARED")
-        cache_dir = explicit
-        if cache_dir is not None and fs_mod.has_scheme(cache_dir):
-            # a scheme:// cache dir auto-routes to the shared-cache
-            # path: jax compiles against a local staging dir and
-            # entries sync to the URL
-            shared = shared or cache_dir
-            cache_dir = None
-        if cache_dir is None:
-            configured = jax.config.jax_compilation_cache_dir
-            if configured and shared is None:
-                return configured   # respect an externally set cache
-            if configured:
-                cache_dir = configured
-            elif workspace_root is not None:
-                cache_dir = os.path.join(os.path.abspath(workspace_root),
-                                         "tmp", "jax_cache")
-            elif shared is not None:
-                import tempfile
-                cache_dir = os.path.join(tempfile.gettempdir(),
-                                         "shifu_tpu_jax_cache")
-            else:
+        if explicit is not None and fs_mod.has_scheme(explicit):
+            # a scheme:// cache dir is the shared cache: jax compiles
+            # against the local directory and entries sync to the URL
+            shared, explicit = shared or explicit, None
+        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not cache_dir:
+            if explicit is not None and \
+                    explicit.strip().lower() in _DISABLED_VALUES:
                 return None
+            cache_dir = explicit or default_cache_dir()
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # processes share this directory by design (DAG siblings, a
+        # fleet, test workers). jax writes an entry with a plain
+        # write_bytes and reads it unlocked UNLESS the cache is
+        # size-bounded — only then does every get/put take the
+        # directory's file lock. Unbounded, a reader can load a
+        # half-written executable (seen: a worker aborting inside XLA).
+        jax.config.update("jax_compilation_cache_max_size",
+                          _CACHE_MAX_BYTES)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(knob_float("SHIFU_TPU_COMPILE_CACHE_MIN_S")))
         log.info("persistent compilation cache at %s", cache_dir)
@@ -233,27 +244,22 @@ def device_stats() -> Dict:
     """Backend + device count + memory stats (peak HBM) when the
     runtime exposes them (TPU does; CPU returns none).
 
-    Reports only ALREADY-INITIALIZED backends: metrics run after every
-    command, including pure file operations (`init`, `save`), and
-    jax.devices() would lazily initialize every registered platform —
-    probing (and possibly hanging on) an unreachable accelerator the
-    command never used."""
+    Reports only when THIS process enumerated its devices
+    (`mesh.devices_enumerated`): metrics run after every command,
+    including pure file operations (`init`, `save`) and the DAG/combo
+    parents, and asking jax for devices there would create a backend —
+    on one chip that takes it away from the children."""
     out: Dict = {}
     try:
-        import jax
-        from jax._src import xla_bridge
-        cache = getattr(xla_bridge, "_backends", None)
-        if cache is not None and not cache:
-            return out   # nothing initialized — nothing to report
-        # cache is None only if the internal attr moved in a jax
-        # upgrade: fall back to reporting (the old behavior) rather
-        # than silently losing metrics forever
         from shifu_tpu.parallel import mesh as mesh_mod
+        if not mesh_mod.devices_enumerated():
+            return out   # no backend of ours — nothing to report
+        import jax
         devs = mesh_mod.leased_devices()
         out["backend"] = jax.default_backend()
+        out["deviceKind"] = devs[0].device_kind
         out["deviceCount"] = len(devs)
-        st = devs[0].memory_stats() if hasattr(devs[0],
-                                               "memory_stats") else None
+        st = devs[0].memory_stats()
         if st:
             for src, dst in (("peak_bytes_in_use", "peakBytesInUse"),
                              ("bytes_in_use", "bytesInUse"),
@@ -343,13 +349,34 @@ def step_metrics(root: str, step: str, extra: Optional[Dict] = None):
 #
 # Analytic per-row FLOPs and bytes-moved are derived from the model
 # spec alone, so the same numbers describe every backend; utilization
-# estimates divide measured throughput by the single-chip peaks below
-# (TPU v5e: 394 bf16 TFLOP/s; f32 runs through the MXU at about half
-# that; 819 GB/s HBM). bench.py emits one `roofline` block per task
-# and tools/check_steps_schema.py pins README docs to ROOFLINE_FIELDS.
+# divides measured throughput by the peaks of the device the run was
+# on, looked up in DEVICE_PEAKS. bench.py emits one `roofline` block
+# per task and tools/check_steps_schema.py pins README docs to
+# ROOFLINE_FIELDS.
 
-TPU_PEAK_FLOPS = {"bfloat16": 394e12, "float32": 197e12}
-TPU_PEAK_HBM_BPS = 819e9
+# Published per-chip peaks keyed by jax's `device_kind`. Source: Google
+# Cloud TPU documentation, "TPU v5e" system architecture — 197 TFLOP/s
+# bf16 MXU peak, 16 GB HBM at 819 GB/s per chip (v5e reports itself as
+# "TPU v5 lite"). The MXU's only published figure is the bf16 one; f32
+# operands at default precision run as bf16 passes under the same
+# ceiling, so one number serves every compute dtype. A device that is
+# not in this table has NO peak: its utilization fields are null —
+# never another chip's number.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5e": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks(device_kind: Optional[str] = None
+                 ) -> Optional[Dict[str, float]]:
+    """DEVICE_PEAKS entry for `device_kind`, default the kind of the
+    device this run is on (None when the process holds no backend or
+    the kind is not in the table)."""
+    if device_kind is None:
+        device_kind = device_stats().get("deviceKind")
+    return DEVICE_PEAKS.get(device_kind) if device_kind else None
+
 
 ROOFLINE_FIELDS = ("family", "compute_dtype", "flops_per_row",
                    "bytes_per_row", "rows_per_s", "flops_per_s",
@@ -576,34 +603,40 @@ def tree_row_costs(n_cols: int, n_bins: int, max_depth: int,
 
 def roofline(family: str, flops_per_row: float, bytes_per_row: float,
              rows_per_s: float, compute_dtype: str = "float32",
+             device_kind: Optional[str] = None,
              peak_flops: Optional[float] = None,
-             peak_bytes_per_s: float = TPU_PEAK_HBM_BPS) -> Dict:
+             peak_bytes_per_s: Optional[float] = None) -> Dict:
     """Combine analytic per-row costs with a measured rows/s into the
     `roofline` block (steps.jsonl + bench JSON): achieved flops_per_s /
     bytes_per_s, arithmetic intensity vs the ridge point, and MXU/HBM
-    utilization estimates that say whether the shape is compute- or
-    bandwidth-bound."""
-    dtype = str(compute_dtype)
-    if peak_flops is None:
-        peak_flops = TPU_PEAK_FLOPS.get(dtype, TPU_PEAK_FLOPS["float32"])
+    utilization that say whether the shape is compute- or
+    bandwidth-bound. Peaks come from `device_peaks(device_kind)` — the
+    run's own device by default — unless given explicitly; without a
+    peak, `ridge_intensity`, `mxu_util`, `hbm_util` and `bound` are
+    None (a CPU run has no TPU roofline)."""
+    if peak_flops is None or peak_bytes_per_s is None:
+        peaks = device_peaks(device_kind) or {}
+        peak_flops = peak_flops or peaks.get("flops_per_s")
+        peak_bytes_per_s = peak_bytes_per_s or peaks.get("hbm_bytes_per_s")
     rows = max(float(rows_per_s), 0.0)
     fps = float(flops_per_row) * rows
     bps = float(bytes_per_row) * rows
     ai = float(flops_per_row) / bytes_per_row if bytes_per_row else 0.0
-    ridge = peak_flops / peak_bytes_per_s if peak_bytes_per_s else 0.0
+    known = bool(peak_flops and peak_bytes_per_s)
+    ridge = peak_flops / peak_bytes_per_s if known else None
     return {"family": family,
-            "compute_dtype": dtype,
+            "compute_dtype": str(compute_dtype),
             "flops_per_row": float(flops_per_row),
             "bytes_per_row": float(bytes_per_row),
             "rows_per_s": round(rows, 3),
             "flops_per_s": round(fps, 3),
             "bytes_per_s": round(bps, 3),
             "arith_intensity": round(ai, 4),
-            "ridge_intensity": round(ridge, 4),
-            "mxu_util": round(fps / peak_flops, 4) if peak_flops else 0.0,
-            "hbm_util": round(bps / peak_bytes_per_s, 4)
-            if peak_bytes_per_s else 0.0,
-            "bound": "compute" if ai >= ridge else "memory"}
+            "ridge_intensity": round(ridge, 4) if known else None,
+            "mxu_util": round(fps / peak_flops, 4) if known else None,
+            "hbm_util": round(bps / peak_bytes_per_s, 4) if known else None,
+            "bound": (("compute" if ai >= ridge else "memory")
+                      if known else None)}
 
 
 @contextlib.contextmanager

@@ -121,24 +121,20 @@ def eval_pad_enabled() -> bool:
     return env.knob_bool("SHIFU_TPU_EVAL_PAD_BUCKETS")
 
 
-def warm_scores(scorer: Any, proto: Dict[str, Optional[np.ndarray]],
-                ladder: Tuple[int, ...],
-                norm: Optional[Dict[str, Any]] = None) -> int:
-    """Drive one real `scorer.score` call per bucket using rows tiled
-    from the prototype blocks, so every executable steady state needs
-    is built (or read from the persistent compile cache) up front.
-    Returns the number of buckets warmed."""
+def warm_scores(score_fn: Callable[[Dict[str, Optional[np.ndarray]]], Any],
+                proto: Dict[str, Optional[np.ndarray]],
+                ladder: Tuple[int, ...]) -> int:
+    """Drive one real scoring call per bucket using rows tiled from the
+    prototype blocks, so every executable steady state needs is built
+    (or read from the persistent compile cache) up front. `score_fn`
+    takes the padded row blocks and MUST be the very function steady
+    traffic goes through (the service's placement + `scorer.score`):
+    a warm-up that feeds host arrays where traffic feeds pre-placed
+    device arrays leaves the pad/reshard programs of the device path
+    to compile under the first real requests. Returns the number of
+    buckets warmed."""
     for bucket in ladder:
-        padded = pad_blocks(proto, bucket)
-        # tree-only prototypes carry raw blocks but no dense; any
-        # row-aligned block satisfies the positional dense argument
-        # (mirrors service._score_batch)
-        scorer.score(
-            dense=padded.get("dense", padded.get("raw_dense")),
-            index=padded.get("index"),
-            raw_dense=padded.get("raw_dense"),
-            raw_codes=padded.get("raw_codes"),
-            norm=norm)
+        score_fn(pad_blocks(proto, bucket))
     return len(ladder)
 
 

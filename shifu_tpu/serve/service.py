@@ -73,8 +73,10 @@ class ScorerService:
         self._metrics_tags = dict(metrics_tags or {})
         self._workspace_root = workspace_root
         if workspace_root is not None:
+            # a service built through the library (not cli.main) still
+            # gets the persistent cache + compile counters; idempotent
             from shifu_tpu import profiling
-            profiling.enable_compile_cache(workspace_root)
+            profiling.enable_compile_cache()
         if models_dir is not None:
             self.scorer = Scorer.from_dir(models_dir, model_paths,
                                           score_selector=score_selector,
@@ -151,7 +153,7 @@ class ScorerService:
                 aot.aot_selfcheck(self._aot_executables, self._aot_params,
                                   self.scorer, proto)
             self._warmed_buckets = aot.warm_scores(
-                self.scorer, proto, self.ladder, norm=self.norm)
+                self._place_and_score, proto, self.ladder)
             self._warm_s = time.monotonic() - t0
             pipeline.add_stage_time("serve_warm_s", self._warm_s)
         self._batcher.start()
@@ -307,38 +309,31 @@ class ScorerService:
         return True
 
     # -- device consumer (batcher thread) ------------------------------
-    def _score_batch(self, batch: List[Request]) -> None:
-        t0 = time.monotonic()
-        n = sum(r.n for r in batch)
-        keys = sorted(batch[0].blocks)
-        concat = {k: (batch[0].blocks[k] if len(batch) == 1
-                      else np.concatenate([r.blocks[k] for r in batch]))
-                  for k in keys}
-        bucket = aot.bucket_for(n, self.ladder)
-        padded = aot.pad_blocks(concat, bucket)
-        t_pad = time.monotonic()
-
-        t_h2d = t_pad
-        if self._preplace and "dense" in padded:
+    def _place_and_score(self, padded: Dict[str, Optional[np.ndarray]]
+                         ) -> Tuple[Dict[str, np.ndarray], float]:
+        """Place a bucket-padded request on the device and score it →
+        (scores, time the h2d finished). Steady traffic AND the warm-up
+        go through here, so warm-up compiles exactly what traffic runs —
+        including the on-device pad/reshard programs `shard_axis` builds
+        for a pre-placed block."""
+        padded = dict(padded)
+        t_h2d = time.monotonic()
+        # the one block every model reads as-is gets pre-placed: `dense`
+        # for an all-NN ensemble (score_matrix's shard_axis then moves
+        # it onto the data mesh without a host round-trip), `raw_dense`
+        # for an all-tree ensemble on the fused kernel route (binned
+        # in-register; the small host-mapped categorical codes stay
+        # host-side). First leased device — a sliced serving node stays
+        # on its slice. The placement is the request's real h2d.
+        key = "dense" if self._preplace else \
+            "raw_dense" if self._tree_preplace else None
+        if key in padded:
             import jax
             from shifu_tpu.parallel import mesh as mesh_mod
-            # single-device placement: score_matrix's shard_axis moves
-            # it onto the data mesh without a host round-trip (first
-            # leased device — a sliced serving node stays on its slice)
-            padded["dense"] = jax.device_put(
-                np.asarray(padded["dense"], np.float32),
+            padded[key] = jax.device_put(
+                np.asarray(padded[key], np.float32),
                 mesh_mod.leased_devices()[0])
-            jax.block_until_ready(padded["dense"])
-            t_h2d = time.monotonic()
-        elif self._tree_preplace and "raw_dense" in padded:
-            import jax
-            from shifu_tpu.parallel import mesh as mesh_mod
-            # the fused tree kernel bins this block in-register; the
-            # (small, host-mapped) categorical codes stay host-side
-            padded["raw_dense"] = jax.device_put(
-                np.asarray(padded["raw_dense"], np.float32),
-                mesh_mod.leased_devices()[0])
-            jax.block_until_ready(padded["raw_dense"])
+            jax.block_until_ready(padded[key])
             t_h2d = time.monotonic()
 
         # tree ensembles may serve raw blocks only; score_matrix's tree
@@ -350,6 +345,20 @@ class ScorerService:
             raw_dense=padded.get("raw_dense"),
             raw_codes=padded.get("raw_codes"),
             norm=self.norm)
+        return out, t_h2d
+
+    def _score_batch(self, batch: List[Request]) -> None:
+        t0 = time.monotonic()
+        n = sum(r.n for r in batch)
+        keys = sorted(batch[0].blocks)
+        concat = {k: (batch[0].blocks[k] if len(batch) == 1
+                      else np.concatenate([r.blocks[k] for r in batch]))
+                  for k in keys}
+        bucket = aot.bucket_for(n, self.ladder)
+        padded = aot.pad_blocks(concat, bucket)
+        t_pad = time.monotonic()
+
+        out, t_h2d = self._place_and_score(padded)
         t_dev = time.monotonic()
 
         off, t_prev = 0, t_dev

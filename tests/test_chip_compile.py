@@ -1,0 +1,155 @@
+"""Ask the chip's compiler, chip not attached.
+
+Interpret-mode tests cannot see what Mosaic refuses: a block that is
+not (8, 128)-aligned, a primitive without a TPU lowering, a float iota,
+a tile that does not fit VMEM. The TPU compiler is installed here and
+compiles for a DESCRIBED `v5e:2x2` device, so every kernel of the main
+path is compiled at the shapes `chip_smoke.py` and the ROADMAP's cells
+use — about two seconds each, no chip time. A compile that passes is
+not a chip run: it says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture (never at
+import, in a `skipif`, or in `parametrize` arguments): only one process
+may load the TPU library, and under xdist every worker imports every
+test file. The compiles run in this process, with the persistent
+compile cache off around them (an entry compiled for a described chip
+cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+F32, I32 = jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _cache_off():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    return text
+
+
+# (columns, rows, bins, level nodes): the smoke's HIGGS shape at 1M and
+# 11M rows, the root and the widest level, one 600-column and one
+# 256-bin case
+HIST_CASES = [(28, 1_000_000, 64, 1), (28, 1_000_000, 64, 32),
+              (28, 11_000_000, 64, 64), (600, 1_000_000, 64, 64),
+              (28, 1_000_000, 256, 64)]
+
+
+@pytest.mark.parametrize("c,r,b,s", HIST_CASES)
+def test_hist_kernel_compiles(one_chip, c, r, b, s):
+    from shifu_tpu.ops import pallas_hist
+    _compile(lambda bt, sl, g, h: pallas_hist.level_histograms_pallas(
+        bt, sl, g, h, s, b), one_chip,
+        ((c, r), I32), ((r,), I32), ((r,), F32), ((r,), F32))
+
+
+@pytest.mark.parametrize("c,r,b,s", HIST_CASES)
+def test_fused_hist_kernel_compiles(one_chip, c, r, b, s):
+    from shifu_tpu.ops import pallas_hist
+    _compile(lambda v, ct, sl, g, h: pallas_hist.level_histograms_fused(
+        v, ct, sl, g, h, s, b), one_chip,
+        ((c, r), F32), ((c, b - 1), F32), ((r,), I32), ((r,), F32),
+        ((r,), F32))
+
+
+# (nodes, columns, bins): a level of the smoke's trees, the root, a
+# 20-tree lockstep forest level (20·32), 600 columns, 256 bins
+@pytest.mark.parametrize("n,c,b", [(32, 28, 64), (1, 28, 64),
+                                   (640, 28, 64), (64, 600, 64),
+                                   (64, 128, 256), (32, 28, 32)])
+def test_split_kernel_compiles(one_chip, n, c, b):
+    from shifu_tpu.ops import pallas_split
+    _compile(lambda g, h, m: pallas_split.best_splits_pallas(
+        g, h, m, 1.0, 5.0), one_chip,
+        ((n, c, b), F32), ((n, c, b), F32), ((n, c), F32))
+
+
+@pytest.mark.parametrize("rows,c,h", [(1, 28, 64), (512, 28, 64),
+                                      (8192, 28, 64), (64, 600, 512)])
+def test_score_kernel_compiles(one_chip, rows, c, h):
+    from shifu_tpu.ops import pallas_score
+    _compile(lambda x, m, sd, w, b: pallas_score.fused_first_layer(
+        x, m, sd, 4.0, w, b, mode="pallas"), one_chip,
+        ((rows, c), F32), ((c,), F32), ((c,), F32), ((c, h), F32),
+        ((h,), F32))
+
+
+# (trees, depth, row bucket): the SHIFU_TPU_SERVE_BUCKETS ladder ends
+@pytest.mark.parametrize("t,depth,rows", [(100, 6, 512), (20, 6, 1),
+                                          (20, 6, 64), (500, 8, 8),
+                                          (100, 8, 512)])
+def test_trees_kernel_compiles(one_chip, t, depth, rows):
+    from shifu_tpu.ops import pallas_trees
+    n_pad = -(-(2 ** (depth + 1) - 1) // 8) * 8
+    _compile(lambda nd, v, ct: pallas_trees.predict_ensemble(
+        nd, v, ct, n_trees=t, kind="gbt", loss="log", learning_rate=0.1,
+        max_depth=depth, n_bins=64), one_chip,
+        ((8, t * n_pad), F32), ((28, rows), F32), ((28, 63), F32))
+
+
+def test_gbt_level_step_compiles_on_four_chip_mesh(topo):
+    """The data-parallel GBT level step of `gbdt._level_histograms` —
+    `shard_map` around the histogram `pallas_call`, then `psum` — as
+    ONE program over a 4-device mesh: the kernel must be partitionable
+    and the reduction an all-reduce, not a gather of the row-sharded
+    bins. (`jax.default_backend()` is the CPU here, so gbdt's own
+    dispatch would pick interpret mode; the step is rebuilt around the
+    kernel's entry point instead.)"""
+    from shifu_tpu.ops import pallas_hist
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    rows = NamedSharding(mesh, P("data"))
+    c, r, b, s = 28, 1_000_000, 64, 32
+
+    def local(bt, sl, g, h):
+        gh, hh = pallas_hist.level_histograms_pallas(bt, sl, g, h, s, b)
+        return jax.lax.psum(gh, "data"), jax.lax.psum(hh, "data")
+
+    step = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(None, "data"), P("data"), P("data"), P("data")),
+        out_specs=(P(), P()), check_vma=False)
+    text = jax.jit(step).lower(
+        jax.ShapeDtypeStruct((c, r), I32,
+                             sharding=NamedSharding(mesh, P(None, "data"))),
+        jax.ShapeDtypeStruct((r,), I32, sharding=rows),
+        jax.ShapeDtypeStruct((r,), F32, sharding=rows),
+        jax.ShapeDtypeStruct((r,), F32, sharding=rows)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text
+    assert "all-gather" not in text
