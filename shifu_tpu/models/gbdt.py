@@ -327,7 +327,7 @@ def build_forest(cfg: TreeConfig, binsT, grad_T, hess_T, feature_masks,
                                         hess_T, depth, prev_g, prev_h,
                                         trees, mesh, subtract)
         trees = _forest_apply_level(cfg, trees, g, h, feature_masks,
-                                    depth)
+                                    depth, mesh=mesh)
         node_T = jax.vmap(
             lambda t, n: _route_level(cfg, t, binsT, n, depth)
         )(trees, node_T)
@@ -365,7 +365,7 @@ def _forest_child_histograms(cfg: TreeConfig, binsT, node_T, grad_T,
     return _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level)
 
 
-def _best_splits(gh, cfg: TreeConfig, feature_mask):
+def _best_splits(gh, cfg: TreeConfig, feature_mask, mesh=None):
     """Pick the best (feature, bin, missing-direction) per node.
 
     gh: (G, H) each (N, C, B) with the missing bin LAST (index B-1).
@@ -389,10 +389,18 @@ def _best_splits(gh, cfg: TreeConfig, feature_mask):
     if split_fused_mode() == "pallas":
         mask2 = feature_mask if feature_mask.ndim == 2 else \
             jnp.broadcast_to(feature_mask[None, :], g.shape[:2])
-        return best_splits_pallas(
-            g, h, mask2, float(cfg.reg_lambda),
-            float(cfg.min_instances_per_node),
-            interpret=jax.default_backend() != "tpu")
+        search = partial(best_splits_pallas, lam=float(cfg.reg_lambda),
+                         min_inst=float(cfg.min_instances_per_node),
+                         interpret=jax.default_backend() != "tpu")
+        if mesh is not None and mesh.size > 1:
+            # the psum'd histograms are replicated over `mesh`, and a
+            # Mosaic kernel cannot be partitioned automatically: every
+            # device runs the (small) search on its own copy
+            from jax.sharding import PartitionSpec as P
+            search = jax.shard_map(search, mesh=mesh,
+                                   in_specs=(P(), P(), P()),
+                                   out_specs=P(), check_vma=False)
+        return search(g, h, mask2)
     lam = cfg.reg_lambda
     g_miss = g[:, :, -1]
     h_miss = h[:, :, -1]
@@ -446,11 +454,11 @@ def _empty_tree(cfg: TreeConfig):
 
 
 def _apply_level(cfg: TreeConfig, tree, g_hist, h_hist, feature_mask,
-                 depth: int):
+                 depth: int, mesh=None):
     """Fold one level's histograms into the tree state: pick best
     splits, turn no-gain nodes into leaves (value -G/(H+λ)). Shared by
     the resident builder and the out-of-core chunked builder."""
-    s = _best_splits((g_hist, h_hist), cfg, feature_mask)
+    s = _best_splits((g_hist, h_hist), cfg, feature_mask, mesh=mesh)
     return _fold_splits(cfg, tree, s, depth)
 
 
@@ -483,7 +491,7 @@ def _fold_splits(cfg: TreeConfig, tree, s, depth: int):
 
 
 def _forest_apply_level(cfg: TreeConfig, trees, g, h, feature_masks,
-                        depth: int):
+                        depth: int, mesh=None):
     """One split search for ALL T trees of a lockstep level: the
     (T, P, C, B) histograms flatten to T·P nodes so the search — fused
     kernel or XLA chain — launches once per level instead of once per
@@ -493,7 +501,7 @@ def _forest_apply_level(cfg: TreeConfig, trees, g, h, feature_masks,
     t, p, c, b = g.shape
     mask2 = jnp.repeat(feature_masks, p, axis=0)           # (T·P, C)
     s = _best_splits((g.reshape(t * p, c, b), h.reshape(t * p, c, b)),
-                     cfg, mask2)
+                     cfg, mask2, mesh=mesh)
     s_T = jax.tree.map(lambda a: a.reshape((t, p) + a.shape[1:]), s)
     return jax.vmap(lambda tr, sv: _fold_splits(cfg, tr, sv, depth)
                     )(trees, s_T)
@@ -607,7 +615,8 @@ def build_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh=None,
         g_hist, h_hist = _child_level_histograms(
             cfg, binsT, node_of_row, grad, hess, depth, prev_g, prev_h,
             tree["is_leaf"], tree["feature"], mesh, subtract)
-        tree = _apply_level(cfg, tree, g_hist, h_hist, feature_mask, depth)
+        tree = _apply_level(cfg, tree, g_hist, h_hist, feature_mask, depth,
+                            mesh=mesh)
         node_of_row = _route_level(cfg, tree, binsT, node_of_row, depth)
         prev_g, prev_h = g_hist, h_hist
 
@@ -763,7 +772,8 @@ def _grow_tree_scan(cfg: TreeConfig, binsT, grad, hess, feature_mask,
 
     g, h = _level_histograms(binsT, node, grad, hess, 0, n_max,
                              cfg.n_bins, mesh=mesh)
-    tree = _fold_splits_masked(cfg, tree, _best_splits((g, h), cfg, fm),
+    tree = _fold_splits_masked(cfg, tree,
+                               _best_splits((g, h), cfg, fm, mesh=mesh),
                                0, 1, n_max)
     node = _route_level_at(cfg, tree, binsT, node, 0, 1)
 
@@ -784,7 +794,7 @@ def _grow_tree_scan(cfg: TreeConfig, binsT, grad, hess, feature_mask,
         else:
             g, h = _level_histograms(binsT, node, grad, hess, offset,
                                      n_max, cfg.n_bins, mesh=mesh)
-        s = _best_splits((g, h), cfg, fm)
+        s = _best_splits((g, h), cfg, fm, mesh=mesh)
         tree = _fold_splits_masked(cfg, tree, s, offset, width, n_max)
         node = _route_level_at(cfg, tree, binsT, node, offset, width)
         return tree, node, g, h
@@ -804,13 +814,14 @@ def _grow_tree_scan(cfg: TreeConfig, binsT, grad, hess, feature_mask,
 
 
 def _forest_apply_level_masked(cfg: TreeConfig, trees, g, h,
-                               feature_masks, offset, width, n_max: int):
+                               feature_masks, offset, width, n_max: int,
+                               mesh=None):
     """_forest_apply_level at the fixed scan width (one split search
     over T·n_max slots; dead slots drop out of the masked fold)."""
     t, p, c, b = g.shape
     mask2 = jnp.repeat(feature_masks, p, axis=0)           # (T·P, C)
     s = _best_splits((g.reshape(t * p, c, b), h.reshape(t * p, c, b)),
-                     cfg, mask2)
+                     cfg, mask2, mesh=mesh)
     s_T = jax.tree.map(lambda a: a.reshape((t, p) + a.shape[1:]), s)
     return jax.vmap(lambda tr, sv: _fold_splits_masked(
         cfg, tr, sv, offset, width, n_max))(trees, s_T)
@@ -833,7 +844,7 @@ def _grow_forest_scan(cfg: TreeConfig, binsT, grad_T, hess_T,
     g, h = _forest_level_histograms(binsT, node_T, grad_T, hess_T, 0,
                                     n_max, cfg.n_bins, mesh=mesh)
     trees = _forest_apply_level_masked(cfg, trees, g, h, feature_masks,
-                                       0, 1, n_max)
+                                       0, 1, n_max, mesh=mesh)
     node_T = jax.vmap(lambda t, n: _route_level_at(
         cfg, t, binsT, n, 0, 1))(trees, node_T)
 
@@ -858,7 +869,7 @@ def _grow_forest_scan(cfg: TreeConfig, binsT, grad_T, hess_T,
                                             cfg.n_bins, mesh=mesh)
         trees = _forest_apply_level_masked(cfg, trees, g, h,
                                            feature_masks, offset, width,
-                                           n_max)
+                                           n_max, mesh=mesh)
         node_T = jax.vmap(lambda t, n: _route_level_at(
             cfg, t, binsT, n, offset, width))(trees, node_T)
         return trees, node_T, g, h
@@ -1475,7 +1486,8 @@ def _build_tree_streaming(cfg: TreeConfig, bins_mm, grad_of_chunk,
         # memory-scarce path this builder exists for
         prev_g, prev_h = (g_acc, h_acc) if subtract else (None, None)
         if depth < cfg.max_depth:
-            tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth)
+            tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth,
+                                mesh=hist_mesh)
         else:
             tree = _final_leaves(cfg, tree, g_acc, h_acc)
     return tree
@@ -1517,7 +1529,8 @@ def _build_tree_streaming_device(cfg: TreeConfig, bins_put, n_chunks: int,
                                               h_acc, split, 2 ** depth)
         prev_g, prev_h = (g_acc, h_acc) if subtract else (None, None)
         if depth < cfg.max_depth:
-            tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth)
+            tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth,
+                                mesh=hist_mesh)
         else:
             tree = _final_leaves(cfg, tree, g_acc, h_acc)
     return tree

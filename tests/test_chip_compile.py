@@ -153,3 +153,29 @@ def test_gbt_level_step_compiles_on_four_chip_mesh(topo):
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
     assert "all-gather" not in text
+
+
+def test_whole_gbt_round_compiles_on_four_chip_mesh(topo, monkeypatch):
+    """One whole boosting round (`gbdt._gbt_round`: histograms, split
+    search, routing) as the product builds it for a 4-device data mesh.
+    The first four-chip run died here: the split kernel sat outside any
+    `shard_map`, and a Mosaic kernel cannot be partitioned
+    automatically. `jax.default_backend()` is steered to "tpu" so the
+    product's own dispatch takes its chip branch (compiled kernels)."""
+    from shifu_tpu.models import gbdt
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+
+    def shape(dims, dtype, *spec):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    r = 900_000
+    cfg = gbdt.TreeConfig(max_depth=6, n_bins=64, loss="log",
+                          learning_rate=0.2, min_instances_per_node=5)
+    text = gbdt._gbt_round.lower(
+        cfg, shape((28, r), I32, None, "data"), shape((r,), F32, "data"),
+        shape((r,), F32, "data"), shape((r,), F32, "data"),
+        shape((28,), F32), mesh=mesh, subtract=None).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2      # histogram AND split
+    assert "all-reduce" in text and "all-gather" not in text
