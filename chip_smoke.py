@@ -27,29 +27,46 @@ import time
 import urllib.error
 import urllib.request
 
-# what the checks hold the run to, with the reason for each tolerance
+# What the checks hold the run to. Each bound sits one to two orders of
+# magnitude above what the v5e showed (PERF.md, PR 21) and well below
+# what a wrong kernel would give: a first layer computed in bf16 moves a
+# score by ~1e-3..1e-2, a mis-routed row or a wrong split by far more.
 AUC_FLOOR = 0.75        # seeded data: the Bayes-optimal AUC is ~0.85
-# NN: `shifu eval` scores through the fused Pallas first layer, the
-# reference and the server through XLA's matmul. On the MXU an f32
-# matmul at default precision is one bf16 pass: every operand keeps 8
-# bits of mantissa, and the two routes round the z-scored input at
-# different points (or not at all). A 28-term pre-activation then
-# differs by ~1e-2 relative; through tanh, the 64-term output layer and
-# the sigmoid's slope (<= 1/4) the score of the worst row in 100k moves
-# by up to ~1e-2, the mean row by ~1e-3. Not ulp — and not more.
-NN_SCORE_TOL = 2e-2
-NN_MEAN_TOL = 2e-3
-# GBT: routing is integer-exact on every route, so each row lands in the
-# same leaves; only the f32 order of the per-tree sum and exp() differ.
+# eval (fused Pallas kernels) vs the plain XLA route on the same 100k
+# rows. NN: `_score_kernel` against `nn.forward` — measured 6.4e-7, both
+# are f32-accurate. GBT: routing is integer-exact on every route, each
+# row lands in the same leaves; only the f32 order of the per-tree sum
+# and exp() differ — measured 1.2e-6.
+NN_REF_TOL = 1e-4
 GBT_SCORE_TOL = 1e-5
-AUC_TOL = 2e-3          # AUC of scores that agree to the tolerances above
+AUC_TOL = 5e-4          # AUCs measured equal to 5 digits
+# served vs eval on the same rows. GBT serves through the same fused
+# kernel as eval — measured 2.1e-7, same bound as above. NN: the server
+# scores the normalized block through `nn.forward` at its bucket shape
+# (<= 512 rows), eval the raw block through the fused kernel at 100k
+# rows — measured 1.65e-3 (see NN_SERVE_TOL's note in PERF.md).
+NN_SERVE_TOL = 5e-3
 # 4-device vs 1-device training: the gradient mean / histogram sum is a
-# psum whose f32 order differs, nothing else (tests/test_parallel.py
-# holds the 8-vs-1 CPU runs to rtol 2e-3 on weights, 1e-3 on val error)
-MESH_AUC_TOL = 5e-3
-MESH_WEIGHT_TOL = 5e-2
+# psum whose f32 order differs, nothing else. tests/test_parallel.py
+# holds the 8-vs-1 CPU runs to rtol 2e-3 on weights and 1e-3 on
+# validation error; here max |Δweight| measured 2.7e-4, ΔAUC 1e-6.
+MESH_AUC_TOL = 1e-3
+MESH_WEIGHT_TOL = 2e-3
+# GBT trees: per-shard histograms psum to the one-device histogram up to
+# f32 order, so the same splits win except where two gains tie at that
+# precision. Measured: 0 of 2540 decisions differ. The issue asks for
+# identical trees; a couple of exact-tie flips is what
+# tests/test_parallel.py allows the 8-vs-1 CPU build, and no more.
+MESH_MAX_SPLIT_FLIPS = 2
+# the size of the run is part of what it proves, so it is not an option:
+# only `--rehearse` (never on a TPU, never reporting one) runs smaller
+N_COLS = 28                                  # HIGGS-shaped
+ROWS, EVAL_ROWS = 1_000_000, 100_000
+REHEARSE_ROWS, REHEARSE_EVAL_ROWS = 4_000, 1_500
+EPOCHS, TREES, DEPTH = 20, 20, 6
+BINS = 64                                    # 63 value bins + missing
 SERVE_SIZES = (1, 3, 8, 13, 64, 100, 512)
-N_COLS = 28
+SERVE_PASSES = 4                             # steady passes after warm-up
 
 
 def emit(**rec) -> None:
@@ -82,8 +99,8 @@ def _write_part(path: str, x, y, miss) -> None:
               float_format="%.5f", na_rep="?")
 
 
-def write_model_set(root: str, seed: int, n_train: int, n_eval: int,
-                    epochs: int) -> None:
+def write_model_set(root: str, seed: int, n_train: int,
+                    n_eval: int) -> None:
     import numpy as np
     rng = np.random.default_rng(seed)
     header = "|".join([f"f{j}" for j in range(N_COLS)] + ["target"])
@@ -122,7 +139,7 @@ def write_model_set(root: str, seed: int, n_train: int, n_eval: int,
                   "postTrainOn": False, "customPaths": {}},
         "dataSet": ds,
         # 63 value bins + the shared missing bin = the 64-bin histograms
-        "stats": {"maxNumBin": 63, "binningMethod": "EqualPositive",
+        "stats": {"maxNumBin": BINS - 1, "binningMethod": "EqualPositive",
                   "sampleRate": 1.0, "sampleNegOnly": False,
                   "binningAlgorithm": "SPDTI", "psiColumnName": ""},
         "varSelect": {"forceEnable": False, "forceSelectColumnNameFile": "",
@@ -136,7 +153,7 @@ def write_model_set(root: str, seed: int, n_train: int, n_eval: int,
                       "sampleNegOnly": False, "normType": "ZSCALE"},
         "train": {"baggingNum": 1, "baggingWithReplacement": False,
                   "baggingSampleRate": 1.0, "validSetRate": 0.1,
-                  "numTrainEpochs": epochs, "epochsPerIteration": 1,
+                  "numTrainEpochs": EPOCHS, "epochsPerIteration": 1,
                   "trainOnDisk": False, "isContinuous": False,
                   "workerThreadCount": 4, "algorithm": "NN",
                   "multiClassifyMethod": "NATIVE", "params": NN_PARAMS,
@@ -156,10 +173,9 @@ NN_PARAMS = {"NumHiddenLayers": 1, "ActivationFunc": ["tanh"],
              "LearningRate": 0.05, "Propagation": "ADAM"}
 
 
-def gbt_params(trees: int, depth: int) -> dict:
-    return {"TreeNum": trees, "MaxDepth": depth, "LearningRate": 0.2,
-            "Loss": "log", "FeatureSubsetStrategy": "ALL",
-            "MinInstancesPerNode": 5, "Impurity": "variance"}
+GBT_PARAMS = {"TreeNum": TREES, "MaxDepth": DEPTH, "LearningRate": 0.2,
+              "Loss": "log", "FeatureSubsetStrategy": "ALL",
+              "MinInstancesPerNode": 5, "Impurity": "variance"}
 
 
 def set_algorithm(root: str, algorithm: str, params: dict) -> None:
@@ -345,7 +361,7 @@ def check_against_reference(kind: str, ev: dict) -> dict:
         ref = nn_mod.forward(nn_mod.MLPSpec(**sd),
                              jax.tree.map(jnp.asarray, params),
                              jnp.asarray(ev["dense"]))
-        tol = NN_SCORE_TOL
+        tol = NN_REF_TOL
     else:
         ref = gbdt.predict(meta, params, ev["raw_dense"], None, route="xla")
         from shifu_tpu.eval.scorer import convert_tree_score
@@ -366,15 +382,14 @@ def check_against_reference(kind: str, ev: dict) -> dict:
            "auc_floor": AUC_FLOOR, "auc_tol": AUC_TOL}
     if ev["auc"] < AUC_FLOOR:
         raise SystemExit(f"chip_smoke: {kind} AUC below floor: {out}")
-    if abs(ev["auc"] - auc_ref) > AUC_TOL or diff > tol or \
-            mean_diff > min(tol, NN_MEAN_TOL):
+    if abs(ev["auc"] - auc_ref) > AUC_TOL or diff > tol:
         raise SystemExit(f"chip_smoke: {kind} disagrees with the XLA "
                          f"reference: {out}")
     return out
 
 
-def serve_and_check(kind: str, root: str, models_dir: str, ev: dict,
-                    passes: int) -> dict:
+def serve_and_check(kind: str, root: str, models_dir: str,
+                    ev: dict) -> dict:
     """The scorer `cmd_serve` starts (ScorerService + HttpFrontEnd), in
     this process; ragged requests over HTTP; served == eval scores."""
     import numpy as np
@@ -382,7 +397,7 @@ def serve_and_check(kind: str, root: str, models_dir: str, ev: dict,
     from shifu_tpu.serve.http import HttpFrontEnd
     from shifu_tpu.serve.service import ScorerService
     block = "dense" if kind == "nn" else "raw_dense"
-    tol = NN_SCORE_TOL if kind == "nn" else GBT_SCORE_TOL
+    tol = NN_SERVE_TOL if kind == "nn" else GBT_SCORE_TOL
     owner = ScorerService(models_dir=models_dir, workspace_root=root)
     owner.start()
     front = HttpFrontEnd(owner, port=0).start()
@@ -415,7 +430,7 @@ def serve_and_check(kind: str, root: str, models_dir: str, ev: dict,
             worst = max(worst, one(n, off % span))
             off, answered = off + n, answered + 1
         warm = pipeline.drain_stage_timers()
-        for _ in range(passes):               # the steady window
+        for _ in range(SERVE_PASSES):         # the steady window
             for n in SERVE_SIZES:
                 worst = max(worst, one(n, off % span))
                 off, answered = off + n, answered + 1
@@ -448,6 +463,7 @@ def serve_and_check(kind: str, root: str, models_dir: str, ev: dict,
 # ---------------------------------------------------------------------------
 
 def run_one_chip(args, root: str, device: dict, cache_dir: str) -> None:
+    import numpy as np
     from shifu_tpu import native
     from shifu_tpu.processor.base import ProcessorContext
     on_chip = device["platform"] == "tpu"
@@ -457,7 +473,7 @@ def run_one_chip(args, root: str, device: dict, cache_dir: str) -> None:
     t0 = time.time()
     so = os.path.join(os.path.dirname(native.__file__), "_fast_reader.so")
     so_was_there = os.path.exists(so)
-    write_model_set(root, args.seed, args.rows, args.eval_rows, args.epochs)
+    write_model_set(root, args.seed, args.rows, args.eval_rows)
     reader = "native" if native.get_reader_lib() is not None else "pandas"
     emit(phase="data", wall_s=round(time.time() - t0, 2), seed=args.seed,
          rows=args.rows, eval_rows=args.eval_rows, columns=N_COLS,
@@ -474,7 +490,7 @@ def run_one_chip(args, root: str, device: dict, cache_dir: str) -> None:
     nn_check = check_against_reference("nn", ev_nn)
     emit(phase="nn", wall_s=round(time.time() - t0, 2), rows=args.rows,
          eval_rows=int(ev_nn["scores"].shape[0]), hidden=64,
-         epochs=args.epochs, cache_dir=cache_dir, reader=reader,
+         epochs=EPOCHS, cache_dir=cache_dir, reader=reader,
          routes=klog.routes({"score": "_score_kernel"}, on_chip),
          **rec, **nn_check)
     ctx = ProcessorContext.load(root)
@@ -484,26 +500,31 @@ def run_one_chip(args, root: str, device: dict, cache_dir: str) -> None:
 
     # -- gbt: train, eval on the same data ---------------------------------
     t0 = time.time()
-    set_algorithm(root, "GBT", gbt_params(args.trees, args.depth))
+    set_algorithm(root, "GBT", GBT_PARAMS)
     for cmd in ("train", "eval"):
         steps.run(cmd)
     rec = steps.fold()
     ev_gbt = eval_blocks(root)
     gbt_check = check_against_reference("gbt", ev_gbt)
-    meta = ev_gbt["scorer"].models[0][1]
+    _, meta, forest = ev_gbt["scorer"].models[0]
+    built = {"trees": int(np.asarray(forest["trees"]["feature"]).shape[0]),
+             "depth": int(meta["treeConfig"]["max_depth"]),
+             "bins": int(meta["treeConfig"]["n_bins"])}
+    if built != {"trees": TREES, "depth": DEPTH, "bins": BINS}:
+        raise SystemExit(f"chip_smoke: the GBT that was built is not the "
+                         f"one asked for: {built}")
     emit(phase="gbt", wall_s=round(time.time() - t0, 2), rows=args.rows,
-         eval_rows=int(ev_gbt["scores"].shape[0]),
-         trees=args.trees, depth=int(meta["treeConfig"]["max_depth"]),
-         bins=int(meta["treeConfig"]["n_bins"]), cache_dir=cache_dir,
+         eval_rows=int(ev_gbt["scores"].shape[0]), **built,
+         cache_dir=cache_dir,
          routes=klog.routes({"hist": "_hist_kernel",
                              "split": "_split_kernel",
                              "trees": "_tree_kernel"}, on_chip),
          **rec, **gbt_check)
 
     # -- serve: NN then GBT, over HTTP --------------------------------------
-    emit(**serve_and_check("nn", root, nn_models, ev_nn, args.serve_passes),
+    emit(**serve_and_check("nn", root, nn_models, ev_nn),
          cache_dir=cache_dir)
-    emit(**serve_and_check("gbt", root, models, ev_gbt, args.serve_passes),
+    emit(**serve_and_check("gbt", root, models, ev_gbt),
          cache_dir=cache_dir)
     klog.close()
     if on_chip:
@@ -567,7 +588,7 @@ def run_mesh_phase(args, root: str, device: dict, cache_dir: str) -> None:
     klog = KernelLog()
     steps = Steps(root)
     t0 = time.time()
-    write_model_set(root, args.seed, args.rows, args.eval_rows, args.epochs)
+    write_model_set(root, args.seed, args.rows, args.eval_rows)
     for cmd in ("init", "stats", "norm"):
         steps.run(cmd)
     emit(phase="mesh.data", wall_s=round(time.time() - t0, 2),
@@ -598,7 +619,7 @@ def run_mesh_phase(args, root: str, device: dict, cache_dir: str) -> None:
 
     results = {}
     for alg, params in (("NN", NN_PARAMS),
-                        ("GBT", gbt_params(args.trees, args.depth))):
+                        ("GBT", GBT_PARAMS)):
         set_algorithm(root, alg, params)
         for n in (n_dev, 1):
             r = train_eval(f"{alg.lower()}{n}", n)
@@ -616,12 +637,7 @@ def run_mesh_phase(args, root: str, device: dict, cache_dir: str) -> None:
     nn_cmp = {"auc_many": many["auc"], "auc_one": one["auc"],
               "max_abs_weight_diff": w_diff, "auc_tol": MESH_AUC_TOL,
               "weight_tol": MESH_WEIGHT_TOL}
-    # GBT: per-shard histograms psum to the one-device histogram up to
-    # f32 order, so the chosen splits are the same except where two
-    # gains tie at that precision (tests/test_parallel.py holds the
-    # 8-vs-1 CPU build to "a couple of flips, agreeing predictions");
-    # a flip early in boosting nudges later trees, so the bound here is
-    # a share of all decisions plus agreeing eval AUC
+    # GBT: the trees themselves, decision by decision
     tm = Scorer.from_dir(results[f"gbt{n_dev}"]["models"]).models[0][2]
     t1 = Scorer.from_dir(results["gbt1"]["models"]).models[0][2]
     decisions = int(np.asarray(t1["trees"]["feature"]).size)
@@ -631,7 +647,12 @@ def run_mesh_phase(args, root: str, device: dict, cache_dir: str) -> None:
     gbt_cmp = {"auc_many": results[f"gbt{n_dev}"]["auc"],
                "auc_one": results["gbt1"]["auc"],
                "split_decisions": decisions, "split_flips": flips,
-               "max_flips": max(2, decisions // 20)}
+               # the rehearsal's 4,000 rows leave a depth-6 tree's deep
+               # nodes a handful of rows each: gains tie at f32 order
+               # and one early flip moves every later tree (6 flips
+               # seen). It walks the path; the chip run holds the trees.
+               "max_flips": decisions // 20 if args.rehearse
+               else MESH_MAX_SPLIT_FLIPS}
 
     # serve: where the live scorer places a request (no HTTP needed to
     # see it — the front end adds no device work)
@@ -669,14 +690,6 @@ def run_mesh_phase(args, root: str, device: dict, cache_dir: str) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=20260926)
-    ap.add_argument("--rows", type=int, default=None,
-                    help="training rows (default 1,000,000; 4,000 "
-                         "under --rehearse)")
-    ap.add_argument("--eval-rows", type=int, default=None)
-    ap.add_argument("--epochs", type=int, default=20)
-    ap.add_argument("--trees", type=int, default=20)
-    ap.add_argument("--depth", type=int, default=6)
-    ap.add_argument("--serve-passes", type=int, default=4)
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 = run ONLY the 4-device-mesh phase and the "
                          "1-device run it is compared with")
@@ -707,9 +720,8 @@ def main() -> int:
         for knob in ("SHIFU_TPU_HIST", "SHIFU_TPU_SPLIT_FUSED",
                      "SHIFU_TPU_TREE_FUSED", "SHIFU_TPU_SCORE_FUSED"):
             os.environ.setdefault(knob, "pallas")
-    args.rows = args.rows or (4_000 if args.rehearse else 1_000_000)
-    args.eval_rows = args.eval_rows or (1_500 if args.rehearse
-                                        else 100_000)
+    args.rows, args.eval_rows = (REHEARSE_ROWS, REHEARSE_EVAL_ROWS) \
+        if args.rehearse else (ROWS, EVAL_ROWS)
 
     here = os.path.dirname(os.path.abspath(__file__))
     # absolute: ModelConfig paths are resolved against the model set

@@ -974,6 +974,16 @@ def main(argv: Optional[List[str]] = None) -> int:
                 trace_run(root, args.command), \
                 maybe_profile(root, args.command,
                               getattr(args, "profile", False)):
+            if args.command in ("stats", "norm", "normalize", "varsel",
+                                "varselect", "train", "eval", "serve"):
+                # these do their device work in THIS process: take the
+                # devices through the lease seam here, once, so the
+                # step record carries backend/deviceKind whichever
+                # route the command then takes (a fused kernel on the
+                # default device never builds a mesh). Never for a
+                # launcher (`run`, `combo`): it must not hold the chip.
+                from shifu_tpu.parallel import mesh
+                mesh.leased_devices()
             rc = args.fn(args)
             rec["rc"] = int(rc or 0)
     except resilience.Preempted as e:

@@ -52,7 +52,13 @@ item 1.
 Every phase is span-traced (``refresh.run`` / ``refresh.guardrail`` /
 ``refresh.rollback`` + the fleet's ``fleet.swap``) and stage-timed
 (``refresh_train_s`` / ``refresh_guardrail_s`` / ``refresh_promote_s``),
-so `shifu top` shows drift → retrain → guardrail → promote live.
+so `shifu top` shows drift → retrain → guardrail → promote live. What
+the retrain and the guardrail compile counts as
+``background_compile_s`` / ``background_compile_cache_hits`` /
+``background_compile_cache_misses`` (`profiling.background_compiles`);
+the bare compile counters stay the serving
+path's — the swap runs outside the scope, so a swap that recompiles
+still trips the zero-recompile gate.
 
 HYSTERESIS: breaches arriving while a refresh is in flight or within
 ``SHIFU_TPU_REFRESH_COOLDOWN_S`` of the last run are COALESCED — one
@@ -68,6 +74,7 @@ import shutil
 import time
 from typing import Any, Dict, List, Optional
 
+from shifu_tpu import profiling
 from shifu_tpu.config.environment import knob_float, knob_int
 from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.obs.health import store as health_store
@@ -256,8 +263,13 @@ class RefreshController:
                 self.ingest_log.commit(REFRESH_CONSUMER, win.end)
 
             # -- train: warm-start incremental epochs --------------------
+            # the retrain and the guardrail eval compile their own
+            # programs at this window's row counts; counted under
+            # background_compile_* so the serving gate (bare counters:
+            # the batchers, the swap below) reads only the serving path
             t0 = time.monotonic()
-            self._train_challenger(clone)
+            with profiling.background_compiles():
+                self._train_challenger(clone)
             data_pipeline.add_stage_time("refresh_train_s",
                                          time.monotonic() - t0)
             if self.post_train is not None:
@@ -271,7 +283,8 @@ class RefreshController:
 
             # -- guardrail: challenger vs incumbent on held-out eval -----
             t0 = time.monotonic()
-            verdict = self.guardrail(os.path.join(clone, "models"))
+            with profiling.background_compiles():
+                verdict = self.guardrail(os.path.join(clone, "models"))
             data_pipeline.add_stage_time("refresh_guardrail_s",
                                          time.monotonic() - t0)
             st.emit("refresh.guardrail_delta", verdict["delta"],

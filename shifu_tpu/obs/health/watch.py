@@ -38,6 +38,7 @@ import os
 import time
 from typing import Dict, Iterable, Optional
 
+from shifu_tpu import profiling
 from shifu_tpu.config.environment import knob_float
 from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.obs.health import store as health_store
@@ -174,7 +175,12 @@ def run_monitor(ctx, interval_s: Optional[float] = None,
                 try:
                     with obs_trace.span("watch.window", rows=len(df)):
                         resilience.fault_point("watch.window")
-                        snap = drift.observe(df)
+                        # the drift pass compiles its binning program
+                        # the first time a window has this many rows —
+                        # the monitor's own build, not a serving
+                        # recompile (profiling.background_compiles)
+                        with profiling.background_compiles():
+                            snap = drift.observe(df)
                     _emit_drift(st, snap)
                     if refresh is not None:
                         refresh.note_window(df)

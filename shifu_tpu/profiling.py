@@ -23,7 +23,11 @@ metrics plus `jax.profiler` traces.
   timers (`compile_s`, `compile_cache_hits`, `compile_cache_misses` — a
   miss is a program compiled AND written to the cache) and thence in
   `steps.jsonl` — restart / resume / supervise / grid-search paths stop
-  re-paying XLA compiles;
+  re-paying XLA compiles. A thread inside `background_compiles()`
+  counts under `background_compile_*` instead: a build hosted next to
+  a serving fleet (the refresh retrain, the watch loop's drift pass)
+  compiles its own programs for the first time, and those are not the
+  serving path recompiling;
 - `SHIFU_TPU_COMPILE_CACHE_SHARED` names a cluster-shared cache dir (a
   mounted path or a `scheme://` URL; a `scheme://`
   SHIFU_TPU_COMPILE_CACHE_DIR auto-routes here too): entries pull into
@@ -36,6 +40,7 @@ metrics plus `jax.profiler` traces.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import json
 import logging
 import os
@@ -61,6 +66,32 @@ def set_step_extra(key: str, value) -> None:
     _step_extras[key] = value
 
 
+# whether the current thread / context is a background build
+_background: contextvars.ContextVar = contextvars.ContextVar(
+    "shifu_tpu_background_compiles", default=False)
+
+
+@contextlib.contextmanager
+def background_compiles():
+    """Count the compile events THIS thread raises inside the block
+    under `background_compile_s` / `background_compile_cache_hits` /
+    `background_compile_cache_misses` instead of the bare counters.
+
+    The bare counters are what the zero-recompile gates read
+    (`compile_cache_misses` over a steady serving window must be 0). A
+    process that serves AND builds — `shifu watch` with a refresh
+    controller next to a live fleet — compiles the build's programs the
+    first time it runs them at a window's row count; that is not the
+    serving path recompiling, and other threads (the batchers, a swap
+    outside the block) keep counting bare. jax raises its monitoring
+    events on the compiling thread, so a context variable is enough."""
+    token = _background.set(True)
+    try:
+        yield
+    finally:
+        _background.reset(token)
+
+
 def _register_compile_listeners() -> None:
     """Route jax's compile-time monitoring events into the pipeline
     stage timers (idempotent; safe on jax builds without the events)."""
@@ -71,14 +102,24 @@ def _register_compile_listeners() -> None:
     from shifu_tpu.data import pipeline as pipe
 
     def _on_event(event: str, **kw) -> None:  # noqa: ARG001 — jax API
+        # literal keys: tools/check_steps_schema.py enumerates them
         if event.endswith("/cache_hits"):
-            pipe.add_stage_count("compile_cache_hits", 1)
+            if _background.get():
+                pipe.add_stage_count("background_compile_cache_hits", 1)
+            else:
+                pipe.add_stage_count("compile_cache_hits", 1)
         elif event.endswith("/cache_misses"):
-            pipe.add_stage_count("compile_cache_misses", 1)
+            if _background.get():
+                pipe.add_stage_count("background_compile_cache_misses", 1)
+            else:
+                pipe.add_stage_count("compile_cache_misses", 1)
 
     def _on_duration(event: str, secs: float, **kw) -> None:  # noqa: ARG001
         if event.endswith("/backend_compile_duration"):
-            pipe.add_stage_time("compile_s", secs)
+            if _background.get():
+                pipe.add_stage_time("background_compile_s", secs)
+            else:
+                pipe.add_stage_time("compile_s", secs)
 
     jax.monitoring.register_event_listener(_on_event)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
@@ -188,7 +229,7 @@ def enable_compile_cache() -> Optional[str]:
 
     1. `JAX_COMPILATION_CACHE_DIR` in the environment places it from
        outside and nothing in this program overrides it — not
-       `SHIFU_TPU_COMPILE_CACHE_DIR`, not a launcher;
+       `SHIFU_TPU_COMPILE_CACHE_DIR`, not a launcher — nor bounds it;
     2. else `SHIFU_TPU_COMPILE_CACHE_DIR` (`0`/`off`/`none` disables;
        a `scheme://` value names the SHARED cache, see below);
     3. else `default_cache_dir()`, one fixed path in the checkout.
@@ -212,21 +253,27 @@ def enable_compile_cache() -> Optional[str]:
             # against the local directory and entries sync to the URL
             shared, explicit = shared or explicit, None
         cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        if not cache_dir:
+        placed = bool(cache_dir)
+        if not placed:
             if explicit is not None and \
                     explicit.strip().lower() in _DISABLED_VALUES:
                 return None
             cache_dir = explicit or default_cache_dir()
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # processes share this directory by design (DAG siblings, a
-        # fleet, test workers). jax writes an entry with a plain
-        # write_bytes and reads it unlocked UNLESS the cache is
-        # size-bounded — only then does every get/put take the
-        # directory's file lock. Unbounded, a reader can load a
-        # half-written executable (seen: a worker aborting inside XLA).
-        jax.config.update("jax_compilation_cache_max_size",
-                          _CACHE_MAX_BYTES)
+        if not placed and \
+                "JAX_COMPILATION_CACHE_MAX_SIZE" not in os.environ:
+            # a directory this program chose is shared by its own
+            # processes (DAG siblings, a fleet). jax writes an entry
+            # with a plain write_bytes and reads it unlocked UNLESS the
+            # cache is size-bounded — only then does every get/put take
+            # the directory's file lock. Unbounded, a reader can load a
+            # half-written executable (seen: a worker aborting inside
+            # XLA). A directory placed from outside is not ours to
+            # bound, evict from or lock: whoever placed it sets
+            # JAX_COMPILATION_CACHE_MAX_SIZE too if processes share it.
+            jax.config.update("jax_compilation_cache_max_size",
+                              _CACHE_MAX_BYTES)
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(knob_float("SHIFU_TPU_COMPILE_CACHE_MIN_S")))
         log.info("persistent compilation cache at %s", cache_dir)
@@ -371,11 +418,15 @@ DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
 def device_peaks(device_kind: Optional[str] = None
                  ) -> Optional[Dict[str, float]]:
     """DEVICE_PEAKS entry for `device_kind`, default the kind of the
-    device this run is on (None when the process holds no backend or
-    the kind is not in the table)."""
+    first device this process may use — asked of the runtime through
+    the lease seam, so a run on a chip in the table cannot lose its
+    peaks to a path that never enumerated devices. Only a process that
+    did device work asks for a roofline; a scheduler parent never
+    does. None when the kind is not in the table."""
     if device_kind is None:
-        device_kind = device_stats().get("deviceKind")
-    return DEVICE_PEAKS.get(device_kind) if device_kind else None
+        from shifu_tpu.parallel import mesh as mesh_mod
+        device_kind = mesh_mod.leased_devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
 
 
 ROOFLINE_FIELDS = ("family", "compute_dtype", "flops_per_row",

@@ -73,6 +73,40 @@ def test_rehearsal_runs_every_phase_and_places_the_cache(tmp_path):
     assert strays == []
 
 
+def test_four_device_rehearsal_runs_only_the_mesh_phase(tmp_path):
+    """`--chips 4` (the builder's run on a four-chip host) compares a
+    4-device data mesh with a 1-device one and runs no one-chip phase;
+    its last line counts four devices."""
+    r = _run(tmp_path, "--rehearse", "--chips", "4",
+             XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert r.returncode == 0, r.stderr[-3000:]
+    lines = [json.loads(ln) for ln in r.stdout.splitlines() if ln.strip()]
+    assert [(ln["phase"], ln.get("mesh_devices")) for ln in lines[:-1]] \
+        == [("mesh.data", None), ("mesh.nn", 4), ("mesh.nn", 1),
+            ("mesh.gbt", 4), ("mesh.gbt", 1), ("mesh.compare", None),
+            ("total", None)]
+    assert lines[-1] == {"ok": True, "device": {
+        "platform": "cpu", "kind": "cpu", "count": 4}}
+    cmp = lines[-3]
+    assert abs(cmp["nn"]["auc_many"] - cmp["nn"]["auc_one"]) \
+        <= cmp["nn"]["auc_tol"]
+    assert cmp["nn"]["max_abs_weight_diff"] <= cmp["nn"]["weight_tol"]
+    assert cmp["gbt"]["split_flips"] <= cmp["gbt"]["max_flips"]
+    # a wrong device count is refused, not run on what is there
+    r = _run(tmp_path, "--rehearse", "--chips", "4")
+    assert r.returncode != 0 and '"ok"' not in r.stdout
+
+
+def test_the_size_of_the_run_is_not_an_option(tmp_path):
+    """No flag shrinks the run: rows, epochs, trees, depth are what the
+    script proves, so a toy-sized run can never print the ok line."""
+    for flag in ("--rows", "--eval-rows", "--epochs", "--trees",
+                 "--depth", "--serve-passes"):
+        r = _run(tmp_path, "--rehearse", flag, "1")
+        assert r.returncode == 2 and r.stdout.strip() == "", flag
+        assert "unrecognized arguments" in r.stderr
+
+
 def test_no_tpu_and_no_rehearse_fails_before_the_first_phase(tmp_path):
     r = _run(tmp_path)
     assert r.returncode != 0

@@ -39,12 +39,13 @@ def test_outside_placement_wins_over_the_knob(tmp_path, monkeypatch,
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
     monkeypatch.setenv("SHIFU_TPU_COMPILE_CACHE_DIR",
                        str(tmp_path / "knob"))
+    size_was = jax.config.jax_compilation_cache_max_size
     assert profiling.enable_compile_cache() == placed
     assert jax.config.jax_compilation_cache_dir == placed
     assert not (tmp_path / "knob").exists()
-    # bounded, because only a bounded jax cache takes the directory's
-    # file lock around every read and write — processes share it
-    assert jax.config.jax_compilation_cache_max_size > 0
+    # a directory placed from outside gets the directory and nothing
+    # else: no size bound, so no eviction from what is not ours
+    assert jax.config.jax_compilation_cache_max_size == size_was
     # not even the disable value un-places it
     monkeypatch.setenv("SHIFU_TPU_COMPILE_CACHE_DIR", "off")
     assert profiling.enable_compile_cache() == placed
@@ -58,11 +59,17 @@ def test_default_is_one_fixed_dir_in_the_checkout(tmp_path, monkeypatch,
     monkeypatch.delenv("SHIFU_TPU_COMPILE_CACHE_DIR", raising=False)
     want = os.path.join(REPO, ".jax_cache")
     assert profiling.default_cache_dir() == want
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_MAX_SIZE", raising=False)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     for cwd in (tmp_path, tmp_path / "ModelSetA", tmp_path / "ModelSetB"):
         cwd.mkdir(exist_ok=True)
         monkeypatch.chdir(cwd)             # wherever the model set is
         assert profiling.enable_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
+    # the program's own directory is shared by its processes, and only
+    # a bounded jax cache takes the directory's file lock around every
+    # read and write
+    assert jax.config.jax_compilation_cache_max_size > 0
     assert not any("jax_cache" in n for _, names, _ in os.walk(tmp_path)
                    for n in names)
 
@@ -82,6 +89,71 @@ def test_knob_places_the_cache_when_nothing_outside_does(
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv("SHIFU_TPU_COMPILE_CACHE_DIR", str(tmp_path / "k"))
     assert profiling.enable_compile_cache() == str(tmp_path / "k")
+
+
+def test_background_compiles_move_this_threads_events_and_no_others():
+    """Inside `background_compiles()` a compile counts under
+    `background_compile_*`; another thread compiling at the same time, and
+    this thread once it left the block, count under the bare names the
+    zero-recompile gates read."""
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+    from shifu_tpu import profiling
+    from shifu_tpu.data import pipeline
+    profiling.enable_compile_cache()
+
+    def compile_fresh(k):
+        # a new program every call: nothing in-process or on disk has it
+        return jax.jit(lambda v: v * k + (k + 0.5))(jnp.ones(3 + k))
+
+    def requests(stages, prefix=""):
+        return stages.get(prefix + "compile_cache_hits", 0) + \
+            stages.get(prefix + "compile_cache_misses", 0)
+
+    base = int.from_bytes(os.urandom(2), "big")   # fresh constants
+    pipeline.drain_stage_timers()
+    with profiling.background_compiles():
+        compile_fresh(base + 10)
+        inside = pipeline.drain_stage_timers()
+        other = threading.Thread(target=compile_fresh, args=(base + 11,))
+        other.start()
+        other.join()
+        from_other_thread = pipeline.drain_stage_timers()
+    compile_fresh(base + 12)
+    after = pipeline.drain_stage_timers()
+
+    assert requests(inside, "background_") >= 1 and requests(inside) == 0
+    assert inside.get("background_compile_s", 0) > 0
+    assert "compile_s" not in inside
+    assert requests(from_other_thread) >= 1
+    assert requests(from_other_thread, "background_") == 0
+    assert requests(after) >= 1 and requests(after, "background_") == 0
+
+
+def test_device_command_records_its_device_whatever_route_it_takes(
+        model_set):
+    """`cli.main` takes the devices through the lease seam for every
+    command that does device work in its own process, so the step
+    record names the backend and device kind even where the command's
+    route never builds a mesh — and a roofline asks the runtime, not a
+    flag some other path may have set."""
+    from shifu_tpu import profiling
+    from shifu_tpu.cli import main
+    from shifu_tpu.parallel import mesh
+    assert main(["--dir", model_set, "init"]) == 0
+    mesh._devices_enumerated = False
+    assert main(["--dir", model_set, "stats"]) == 0
+    with open(os.path.join(model_set, "tmp", "metrics",
+                           "steps.jsonl")) as f:
+        by = {r["step"]: r for r in map(json.loads, f)}
+    assert by["stats"]["backend"] == "cpu"
+    assert by["stats"]["deviceKind"] and by["stats"]["deviceCount"] >= 1
+    mesh._devices_enumerated = False
+    assert profiling.device_peaks() is None        # a CPU: not in the table
+    assert mesh.devices_enumerated()               # it asked the runtime
+    assert profiling.device_peaks("TPU v5 lite")["flops_per_s"] == 197e12
 
 
 PARENT = textwrap.dedent("""

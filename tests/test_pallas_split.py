@@ -182,3 +182,19 @@ def test_build_tree_via_fused_route_matches_xla(rng, monkeypatch):
     for k in ref:
         np.testing.assert_array_equal(np.asarray(ref[k]),
                                       np.asarray(got[k]), err_msg=k)
+
+
+@pytest.mark.parametrize("node_tile", [1, 2, 3])
+def test_node_tiles_search_each_node_on_its_own(rng, node_tile):
+    """A flattened lockstep-forest level (T·N nodes) is more than one
+    block holds, so the grid tiles the NODE axis too, outside the
+    column axis the running argmax walks. Several node tiles — with a
+    node count that does not divide into them, so the last tile is
+    padding — give each node the one-tile answer exactly."""
+    g, h = _hists(rng, 5, 6)
+    fm = jnp.asarray([1, 1, 0, 1, 1, 1], jnp.float32)
+    ref = _xla_ref(g, h, fm)
+    got = pallas_split.best_splits_pallas(
+        g, h, jnp.broadcast_to(fm[None, :], (5, 6)), 1.0, 2.0,
+        col_tile=2, node_tile=node_tile, interpret=True)
+    _assert_split_parity(ref, got)

@@ -244,3 +244,35 @@ def test_padding_and_row_tile_invariance(rng):
         jnp.asarray(packed), jnp.asarray(fb.valuesT),
         jnp.asarray(fb.cuts), row_tile=512, **kw)
     np.testing.assert_array_equal(np.asarray(t128), np.asarray(t512))
+
+
+@pytest.mark.parametrize("tree_tile", [1, 2])
+def test_tree_tiles_accumulate_to_the_one_tile_score(rng, tree_tile):
+    """An ensemble too large for one block (T=500 at depth 8) is tiled
+    over TREES: the inner grid axis accumulates each tile's leaf sums
+    before the convert. Forcing several tiles on a small forest — 3
+    trees in tiles of 2 leaves a padded, empty tree slot — must give
+    the one-tile score up to the f32 order of that sum."""
+    n_bins = 16
+    dense, codes, tables, y = _dataset(rng, n=150, n_bins=n_bins)
+    bins = gbdt.bin_dataset(tables, dense, codes, n_bins)
+    cfg = TreeConfig(max_depth=3, n_bins=n_bins)
+    trees, _ = gbdt.build_gbt(cfg, bins, y, np.ones_like(y), 3)
+    meta, params = _spec("gbt", cfg, trees, tables)
+    fb = gbdt.make_fused_inputs(tables, dense, codes, n_bins)
+    import jax
+    packed, _ = pallas_trees.pack_ensemble(
+        jax.tree.map(np.asarray, params["trees"]))
+    args = (jnp.asarray(packed), jnp.asarray(fb.valuesT),
+            jnp.asarray(fb.cuts))
+    kw = dict(n_trees=3, kind="gbt", loss=cfg.loss,
+              learning_rate=cfg.learning_rate, max_depth=cfg.max_depth,
+              n_bins=n_bins, interpret=jax.default_backend() != "tpu")
+    one_tile = pallas_trees.predict_ensemble(*args, **kw)
+    tiled = pallas_trees.predict_ensemble(*args, tree_tile=tree_tile, **kw)
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(one_tile),
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(
+        np.asarray(tiled)[:len(dense)],
+        gbdt.predict(meta, params, dense, codes, route="xla"),
+        rtol=1e-5, atol=1e-6)

@@ -197,6 +197,11 @@ def test_refresh_drill_end_to_end(trained_set, tmp_path, monkeypatch):
         assert fleet._entries["m"].service is svc_before
         assert fleet.stats()["fleet"]["swaps"] == 1
         assert stages.get("compile_cache_misses", 0) == 0, stages
+        # ... while the retrain and the guardrail, which DO compile
+        # their programs at this window's row counts, were seen doing
+        # so under their own name — moved, not lost
+        assert stages.get("background_compile_cache_misses", 0) + \
+            stages.get("background_compile_cache_hits", 0) > 0, stages
         assert stages.get("refresh_train_s", 0) > 0
         assert stages.get("fleet_swap_s", 0) > 0
         # the live client never saw a failed request, and the swap
