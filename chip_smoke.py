@@ -28,24 +28,34 @@ import urllib.error
 import urllib.request
 
 # What the checks hold the run to. Each bound sits one to two orders of
-# magnitude above what the v5e showed (PERF.md, PR 21) and well below
-# what a wrong kernel would give: a first layer computed in bf16 moves a
-# score by ~1e-3..1e-2, a mis-routed row or a wrong split by far more.
+# magnitude above what the v5e showed (PERF.md, PR 21), and below what a
+# kernel that rounds or routes differently from its reference gives.
 AUC_FLOOR = 0.75        # seeded data: the Bayes-optimal AUC is ~0.85
 # eval (fused Pallas kernels) vs the plain XLA route on the same 100k
-# rows. NN: `_score_kernel` against `nn.forward` — measured 6.4e-7, both
-# are f32-accurate. GBT: routing is integer-exact on every route, each
-# row lands in the same leaves; only the f32 order of the per-tree sum
-# and exp() differ — measured 1.2e-6.
+# rows. NN: on the v5e a default-precision f32 matmul is ONE bf16 MXU
+# pass, in XLA and in `_score_kernel` alike: both sit ~2e-3 from a
+# float64 forward and 6.4e-7 from each other (measured), because they
+# round the same operands at the same points. The bound holds the
+# kernel to that: same rounding as XLA, not merely the same precision
+# class. GBT: routing is integer-exact on every route, each row lands
+# in the same leaves; only the f32 order of the per-tree sum and exp()
+# differ — measured 1.2e-6.
 NN_REF_TOL = 1e-4
 GBT_SCORE_TOL = 1e-5
 AUC_TOL = 5e-4          # AUCs measured equal to 5 digits
 # served vs eval on the same rows. GBT serves through the same fused
 # kernel as eval — measured 2.1e-7, same bound as above. NN: the server
-# scores the normalized block through `nn.forward` at its bucket shape
-# (<= 512 rows), eval the raw block through the fused kernel at 100k
-# rows — measured 1.65e-3 (see NN_SERVE_TOL's note in PERF.md).
-NN_SERVE_TOL = 5e-3
+# scores the normalized block through `nn.forward` at its bucket shape.
+# A bucket of 8 rows or more goes through the same bf16 MXU pass as
+# eval and gives the same rows bit for bit whatever the shape
+# (measured: 0.0 between 8/64/512-row and 100k-row batches), so those
+# requests are held to the reference bound. The ONE-row bucket is not
+# an MXU matmul: XLA computes it in exact f32 (1e-6 from float64,
+# measured), so it sits a bf16 pass's error away from eval — the
+# 1.65e-3 measured over all sizes; that bucket alone gets the class
+# bound.
+NN_SERVE_TOL = NN_REF_TOL
+NN_SERVE_ONE_ROW_TOL = 5e-3
 # 4-device vs 1-device training: the gradient mean / histogram sum is a
 # psum whose f32 order differs, nothing else. tests/test_parallel.py
 # holds the 8-vs-1 CPU runs to rtol 2e-3 on weights and 1e-3 on
@@ -397,12 +407,15 @@ def serve_and_check(kind: str, root: str, models_dir: str,
     from shifu_tpu.serve.http import HttpFrontEnd
     from shifu_tpu.serve.service import ScorerService
     block = "dense" if kind == "nn" else "raw_dense"
-    tol = NN_SERVE_TOL if kind == "nn" else GBT_SCORE_TOL
+    tols = {n: GBT_SCORE_TOL if kind == "gbt" else
+            NN_SERVE_ONE_ROW_TOL if n == 1 else NN_SERVE_TOL
+            for n in SERVE_SIZES}
     owner = ScorerService(models_dir=models_dir, workspace_root=root)
     owner.start()
     front = HttpFrontEnd(owner, port=0).start()
     url = "http://%s:%d/score" % tuple(front.address)
-    answered, worst, off = 0, 0.0, 0
+    answered, off = 0, 0
+    worst = dict.fromkeys(SERVE_SIZES, 0.0)   # by request size
     t0 = time.time()
 
     def one(n: int, off: int) -> float:
@@ -427,12 +440,12 @@ def serve_and_check(kind: str, root: str, models_dir: str,
     try:
         span = len(ev["scores"]) - max(SERVE_SIZES)   # wrap the offsets
         for n in SERVE_SIZES:                 # warm-up: every bucket once
-            worst = max(worst, one(n, off % span))
+            worst[n] = max(worst[n], one(n, off % span))
             off, answered = off + n, answered + 1
         warm = pipeline.drain_stage_timers()
         for _ in range(SERVE_PASSES):         # the steady window
             for n in SERVE_SIZES:
-                worst = max(worst, one(n, off % span))
+                worst[n] = max(worst[n], one(n, off % span))
                 off, answered = off + n, answered + 1
         steady = pipeline.drain_stage_timers()
         stats = owner.stats()
@@ -441,7 +454,9 @@ def serve_and_check(kind: str, root: str, models_dir: str,
         owner.close()
     out = {"phase": f"serve.{kind}", "wall_s": round(time.time() - t0, 2),
            "requests": answered, "sizes": list(SERVE_SIZES),
-           "rows": off, "max_abs_served_vs_eval": worst, "score_tol": tol,
+           "rows": off, "max_abs_served_vs_eval": max(worst.values()),
+           "served_vs_eval_by_size": {str(n): worst[n] for n in worst},
+           "score_tol_by_size": {str(n): tols[n] for n in tols},
            "warm_compile_s": round(warm.get("compile_s", 0.0), 3),
            "warm_cache_hits": int(warm.get("compile_cache_hits", 0)),
            "warm_cache_misses": int(warm.get("compile_cache_misses", 0)),
@@ -452,7 +467,7 @@ def serve_and_check(kind: str, root: str, models_dir: str,
            "latency": stats.get("latency", {})}
     if out["steady_compile_cache_misses"] or out["steady_compile_s"]:
         raise SystemExit(f"chip_smoke: steady traffic compiled: {out}")
-    if worst > tol:
+    if any(worst[n] > tols[n] for n in SERVE_SIZES):
         raise SystemExit(f"chip_smoke: served scores differ from eval's: "
                          f"{out}")
     return out
