@@ -47,13 +47,12 @@ AUC_TOL = 5e-4          # AUCs measured equal to 5 digits
 # kernel as eval — measured 2.1e-7, same bound as above. NN: the server
 # scores the normalized block through `nn.forward` at its bucket shape.
 # A bucket of 8 rows or more goes through the same bf16 MXU pass as
-# eval and gives the same rows bit for bit whatever the shape
-# (measured: 0.0 between 8/64/512-row and 100k-row batches), so those
-# requests are held to the reference bound. The ONE-row bucket is not
-# an MXU matmul: XLA computes it in exact f32 (1e-6 from float64,
-# measured), so it sits a bf16 pass's error away from eval — the
-# 1.65e-3 measured over all sizes; that bucket alone gets the class
-# bound.
+# eval and gives the same rows bit for bit whatever the shape, so those
+# requests are held to the reference bound (measured: <= 1.8e-7 for the
+# 3..512-row requests). The ONE-row bucket is not an MXU matmul: XLA
+# computes it in exact f32 (1e-6 from float64, measured), so it sits a
+# bf16 pass's error away from eval — measured 1.65e-3, on that size
+# alone; that bucket alone gets the class bound.
 NN_SERVE_TOL = NN_REF_TOL
 NN_SERVE_ONE_ROW_TOL = 5e-3
 # 4-device vs 1-device training: the gradient mean / histogram sum is a

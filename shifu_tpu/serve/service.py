@@ -7,8 +7,12 @@ code path batch eval uses, including the fused normalize+score Pallas
 kernel and bf16 spec metadata — so a served request scored at the
 same bucket batch eval lands on is bit-identical to batch eval by
 construction; across DIFFERENT buckets XLA's shape-dependent
-scheduling bounds the difference at ~1 ulp (see serve/aot.py).  Two
-standing caveats: batch-GLOBAL tree-score conversions like MAXMIN are
+scheduling bounds the difference at ~1 ulp (see serve/aot.py) — with
+one exception on a TPU at default matmul precision: the ONE-row bucket.
+Every larger shape takes one bf16 MXU pass and gives the same rows bit
+for bit; a one-row contraction is computed in exact f32, so a one-row
+request sits a bf16 pass (~1e-3; 1.65e-3 measured on a v5e) from its
+batch-eval score.  Two standing caveats: batch-GLOBAL tree-score conversions like MAXMIN are
 batch-defined and therefore applied per micro-batch (the default RAW
 conversion has no such dependence), and which requests share a
 micro-batch depends on arrival timing.
