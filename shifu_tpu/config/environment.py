@@ -338,8 +338,10 @@ _declare("SHIFU_TPU_UME_EXPORTER", "str", None,
          "pkg.module:Class hook for `export -t ume` bundles")
 # --- observability / trace plane ---
 _declare("SHIFU_TPU_TRACE", "flag", "0",
-         "1 = record host spans (obs.trace) and export a merged "
-         "Chrome-trace JSON per step; unset/0 = zero-cost no-op")
+         "1 = also record host spans (obs.trace) into the ring buffer "
+         "and export a merged Chrome-trace JSON per step; unset/0 = a "
+         "span is a jax.profiler annotation only (seen by an open "
+         "profiler session, ~1 us otherwise)")
 _declare("SHIFU_TPU_TRACE_BUF", "int", 4096,
          "span ring-buffer capacity per process; overflow drops the "
          "oldest span and counts it in the steps.jsonl trace block")

@@ -154,7 +154,8 @@ def host_fetch(x):
     the cost measured."""
     import numpy as np
     t0 = time.perf_counter()
-    out = np.asarray(x)
+    with obs_trace.span("host.sync"):
+        out = np.asarray(x)
     add_stage_time("host_sync_s", time.perf_counter() - t0)
     add_stage_count("host_syncs")
     return out

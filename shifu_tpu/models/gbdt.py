@@ -42,6 +42,7 @@ import numpy as np
 
 from shifu_tpu.config.environment import knob_bool, knob_int, knob_str
 from shifu_tpu.data.pipeline import add_stage_count, host_fetch
+from shifu_tpu.obs import trace as obs_trace
 
 @dataclass(frozen=True)
 class TreeConfig:
@@ -214,6 +215,7 @@ def _local_level_histograms(binsT, slot, grad, hess, n_level_nodes, n_bins):
     return scatter(grad), scatter(hess)
 
 
+@jax.named_scope("hist")
 def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
                       n_level_nodes, n_bins, mesh=None):
     """Per-level G/H histograms.
@@ -257,6 +259,7 @@ def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
                                    n_bins)
 
 
+@jax.named_scope("hist")
 def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
                              n_level_nodes, n_bins, mesh=None):
     """Per-level G/H histograms for T trees grown in LOCKSTEP.
@@ -365,6 +368,7 @@ def _forest_child_histograms(cfg: TreeConfig, binsT, node_T, grad_T,
     return _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level)
 
 
+@jax.named_scope("split")
 def _best_splits(gh, cfg: TreeConfig, feature_mask, mesh=None):
     """Pick the best (feature, bin, missing-direction) per node.
 
@@ -462,6 +466,7 @@ def _apply_level(cfg: TreeConfig, tree, g_hist, h_hist, feature_mask,
     return _fold_splits(cfg, tree, s, depth)
 
 
+@jax.named_scope("split")
 def _fold_splits(cfg: TreeConfig, tree, s, depth: int):
     """Write one level's chosen splits (a `_best_splits` dict) into the
     flat tree arrays. Split off from _apply_level so the lockstep
@@ -507,6 +512,7 @@ def _forest_apply_level(cfg: TreeConfig, trees, g, h, feature_masks,
                     )(trees, s_T)
 
 
+@jax.named_scope("leaf")
 def _final_leaves(cfg: TreeConfig, tree, g_hist, h_hist):
     """Everything alive at the last level becomes a leaf."""
     level_offset = 2 ** cfg.max_depth - 1
@@ -539,6 +545,7 @@ def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int):
                            2 ** depth - 1, 2 ** depth)
 
 
+@jax.named_scope("route")
 def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
                     level_offset, n_level):
     """_route_level core with level_offset/n_level as values rather
@@ -661,6 +668,7 @@ def _child_level_histograms(cfg: TreeConfig, binsT, node_of_row, grad,
     return _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level)
 
 
+@jax.named_scope("hist")
 def _left_half_nodes(node, level_offset, n_level):
     """Map rows at LEFT children (even level-local slots) to their
     parent's slot id for the half-width kernel; everything else → -1
@@ -671,6 +679,7 @@ def _left_half_nodes(node, level_offset, n_level):
     return jnp.where(left, level_offset + local // 2, -1)
 
 
+@jax.named_scope("hist")
 def _parent_split_mask(is_leaf, feature, depth):
     """(... , P) bool: which previous-level parents actually split
     (their children exist). is_leaf/feature index node arrays with an
@@ -679,6 +688,7 @@ def _parent_split_mask(is_leaf, feature, depth):
     return (~is_leaf[..., parent_ids]) & (feature[..., parent_ids] >= 0)
 
 
+@jax.named_scope("hist")
 def _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level):
     """Shared sibling-subtraction core (single tree (P, C, B) or
     lockstep forest (T, P, C, B) — `split` carries the matching leading
@@ -709,6 +719,7 @@ def tree_scan_enabled() -> bool:
     return knob_bool("SHIFU_TPU_TREE_SCAN")
 
 
+@jax.named_scope("split")
 def _fold_splits_masked(cfg: TreeConfig, tree, s, level_offset, n_level,
                         n_max: int):
     """_fold_splits at a FIXED n_max slot width with traced
@@ -739,6 +750,7 @@ def _fold_splits_masked(cfg: TreeConfig, tree, s, level_offset, n_level,
     return tree
 
 
+@jax.named_scope("hist")
 def _parent_split_mask_at(is_leaf, feature, prev_offset, n_slots: int):
     """_parent_split_mask at a fixed n_slots width with a traced
     prev_offset. Slots past the real parent level read ids that spill
@@ -936,6 +948,7 @@ def leaf_indices(trees, binsT, max_depth: int, n_bins: int):
 # Forest builders
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("gradients")
 def gbt_gradients(y, pred_raw, weights, loss: str):
     """First/second-order gradients (dt/Loss.java squared/log).
     Elementwise, so broadcasting y (R,) or (1, R) against (T, R)
@@ -982,8 +995,9 @@ def _gbt_round_core(cfg: TreeConfig, binsT, y, weights, pred_raw,
     tree, node_of_row = build_tree(cfg, binsT, grad, hess, feature_mask,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
-    contrib = tree["leaf_value"][node_of_row]
-    return tree, pred_raw + cfg.learning_rate * contrib
+    with jax.named_scope("leaf"):
+        contrib = tree["leaf_value"][node_of_row]
+        return tree, pred_raw + cfg.learning_rate * contrib
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract"))
@@ -1028,111 +1042,132 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     gradients/hessians (and hence histograms and leaf values) exact.
     """
     from shifu_tpu.parallel import mesh as mesh_mod
-    mesh = mesh_mod.default_mesh()
-    hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
-    # device bins are TRANSPOSED (C, R): rows on the lane axis, so a
-    # narrow feature matrix doesn't lane-pad to 128 columns in HBM.
-    # jax.Array inputs are taken as ALREADY transposed + placed (lets
-    # device-resident data skip the host round-trip entirely).
-    if isinstance(bins, jax.Array):
-        jb, jy, jw = bins, jnp.asarray(y), jnp.asarray(weights)
-    elif isinstance(bins, FusedBins):
-        # fused path (SHIFU_TPU_HIST_FUSED): raw values shard like the
-        # bin matrix would (NaN pad rows land in the missing bin with
-        # zero weight); the small cut table replicates
-        jb = FusedBins(
-            mesh_mod.shard_axis(
-                mesh,
-                np.ascontiguousarray(np.asarray(bins.valuesT, np.float32)),
-                1, pad_value=np.nan),
-            jnp.asarray(np.asarray(bins.cuts, np.float32)))
-        jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
-                                     np.asarray(weights, np.float32))
-    else:
-        jb = mesh_mod.shard_axis(
-            mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1,
-            pad_value=0)
-        jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
-                                     np.asarray(weights, np.float32))
-    # feature count: axis 0 of the (C, R) device layout, axis 1 row-major
-    fm = jnp.asarray(feature_mask if feature_mask is not None
-                     else np.ones(int(jb.shape[0]), np.float32))
-    # env resolved HERE, outside jit: subtract is a static jit arg, so
-    # an env flip after first compile must produce a fresh trace, not a
-    # silent cache hit on whatever was compiled first
-    subtract = _use_hist_subtract()
-    trees: List[Any] = []
-    pred = jnp.zeros(jb.shape[1], jnp.float32)
-    if init_trees is not None:
-        n_prev = init_trees["feature"].shape[0]
-        trees = [jax.tree.map(lambda a, i=i: a[i], init_trees)
-                 for i in range(n_prev)]
-        pred = cfg.learning_rate * jnp.sum(predict_trees(
-            init_trees, jb, cfg.max_depth, cfg.n_bins), axis=0)
-    val_errs = []
-    best_val, bad = np.inf, 0
-    vraw = None
-    if val_data is not None:
-        vb, vy = val_data
-        n_val = vb.shape[0]
-        vb = mesh_mod.shard_axis(
-            mesh, np.ascontiguousarray(np.asarray(vb, np.int32).T), 1)
-        vy, vw = mesh_mod.shard_rows(
-            mesh, np.asarray(vy, np.float32), np.ones(n_val, np.float32))
-        vraw = jnp.zeros(vb.shape[1], jnp.float32)
-        if init_trees is not None:
-            vraw = cfg.learning_rate * jnp.sum(predict_trees(
-                init_trees, vb, cfg.max_depth, cfg.n_bins), axis=0)
-    if val_data is None and n_trees > 0:
-        # no per-round host decision to make → scan rounds device-side
-        # (see _gbt_rounds), in groups of SHIFU_TPU_GBT_SCAN_GROUP
-        # rounds per dispatch (0/unset = all rounds in one). Grouping
-        # bounds how long a single execute runs; equal-size groups
-        # reuse one compiled program, and a scalar FETCH between groups
-        # (_pace_dispatch) keeps exactly one long execute in flight.
-        # Whether a directly attached chip needs either is ROADMAP D2.
-        group = knob_int("SHIFU_TPU_GBT_SCAN_GROUP")
-        group = n_trees if group <= 0 else min(group, n_trees)
-        parts = []
-        for start in range(0, n_trees, group):
-            k = min(group, n_trees - start)
-            part, pred = _gbt_rounds(cfg, jb, jy, jw, pred, fm,
-                                     k, mesh=hist_mesh,
-                                     subtract=subtract)
-            if start + k < n_trees:
-                _pace_dispatch(pred)
-            parts.append(part)
-        new_stacked = parts[0] if len(parts) == 1 else jax.tree.map(
-            lambda *a: jnp.concatenate(a), *parts)
-        if init_trees is not None:
-            # continuous-training resume: prepend the old ensemble
-            # (init_trees IS the stacked pytree already)
-            new_stacked = jax.tree.map(
-                lambda p, n: jnp.concatenate([jnp.asarray(p), n]),
-                init_trees, new_stacked)
-        return jax.tree.map(np.asarray, new_stacked), []
-    for t in range(n_trees):
-        tree, pred = _gbt_round(cfg, jb, jy, jw, pred, fm, mesh=hist_mesh,
-                                subtract=subtract)
-        trees.append(tree)
-        if val_data is not None:
-            vraw = vraw + cfg.learning_rate * predict_trees(
-                jax.tree.map(lambda a: a[None], tree), vb,
-                cfg.max_depth, cfg.n_bins)[0]
-            # weighted mean (_val_error) so zero-weight padding rows
-            # don't bias it; the early-stop decision is a per-round
-            # host branch, so this sync is intentional — host_fetch
-            # times and counts it
-            err = float(host_fetch(_val_error(vraw, vy, vw, cfg.loss)))
-            val_errs.append(err)
-            if err < best_val - 1e-9:
-                best_val, bad = err, 0
+    with obs_trace.span("train.job", family="gbt", rows=int(y.shape[0]),
+                        steps=n_trees, bags=1):
+        with obs_trace.span("train.prepare"):
+            mesh = mesh_mod.default_mesh()
+            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
+        with obs_trace.span("train.place"):
+            # device bins are TRANSPOSED (C, R): rows on the lane axis, so a
+            # narrow feature matrix doesn't lane-pad to 128 columns in HBM.
+            # jax.Array inputs are taken as ALREADY transposed + placed (lets
+            # device-resident data skip the host round-trip entirely).
+            if isinstance(bins, jax.Array):
+                jb, jy, jw = bins, jnp.asarray(y), jnp.asarray(weights)
+            elif isinstance(bins, FusedBins):
+                # fused path (SHIFU_TPU_HIST_FUSED): raw values shard like the
+                # bin matrix would (NaN pad rows land in the missing bin with
+                # zero weight); the small cut table replicates
+                jb = FusedBins(
+                    mesh_mod.shard_axis(
+                        mesh,
+                        np.ascontiguousarray(
+                            np.asarray(bins.valuesT, np.float32)),
+                        1, pad_value=np.nan),
+                    jnp.asarray(np.asarray(bins.cuts, np.float32)))
+                jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
+                                             np.asarray(weights, np.float32))
             else:
-                bad += 1
-                if early_stop_window and bad >= early_stop_window:
-                    break
-    stacked = jax.tree.map(lambda *a: jnp.stack(a), *trees)
-    return jax.tree.map(np.asarray, stacked), val_errs
+                jb = mesh_mod.shard_axis(
+                    mesh,
+                    np.ascontiguousarray(np.asarray(bins, np.int32).T), 1,
+                    pad_value=0)
+                jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
+                                             np.asarray(weights, np.float32))
+            # feature count: axis 0 of the (C, R) device layout, axis 1
+            # row-major
+            fm = jnp.asarray(feature_mask if feature_mask is not None
+                             else np.ones(int(jb.shape[0]), np.float32))
+            # env resolved HERE, outside jit: subtract is a static jit arg, so
+            # an env flip after first compile must produce a fresh trace, not a
+            # silent cache hit on whatever was compiled first
+            subtract = _use_hist_subtract()
+            trees: List[Any] = []
+            pred = jnp.zeros(jb.shape[1], jnp.float32)
+            if init_trees is not None:
+                n_prev = init_trees["feature"].shape[0]
+                trees = [jax.tree.map(lambda a, i=i: a[i], init_trees)
+                         for i in range(n_prev)]
+                pred = cfg.learning_rate * jnp.sum(predict_trees(
+                    init_trees, jb, cfg.max_depth, cfg.n_bins), axis=0)
+            val_errs = []
+            best_val, bad = np.inf, 0
+            vraw = None
+            if val_data is not None:
+                vb, vy = val_data
+                n_val = vb.shape[0]
+                vb = mesh_mod.shard_axis(
+                    mesh, np.ascontiguousarray(np.asarray(vb, np.int32).T), 1)
+                vy, vw = mesh_mod.shard_rows(
+                    mesh, np.asarray(vy, np.float32),
+                    np.ones(n_val, np.float32))
+                vraw = jnp.zeros(vb.shape[1], jnp.float32)
+                if init_trees is not None:
+                    vraw = cfg.learning_rate * jnp.sum(predict_trees(
+                        init_trees, vb, cfg.max_depth, cfg.n_bins), axis=0)
+        if val_data is None and n_trees > 0:
+            # no per-round host decision to make → scan rounds device-side
+            # (see _gbt_rounds), in groups of SHIFU_TPU_GBT_SCAN_GROUP
+            # rounds per dispatch (0/unset = all rounds in one). Grouping
+            # bounds how long a single execute runs; equal-size groups
+            # reuse one compiled program, and a scalar FETCH between groups
+            # (_pace_dispatch) keeps exactly one long execute in flight.
+            # Whether a directly attached chip needs either is ROADMAP D2.
+            group = knob_int("SHIFU_TPU_GBT_SCAN_GROUP")
+            group = n_trees if group <= 0 else min(group, n_trees)
+            parts = []
+            for start in range(0, n_trees, group):
+                k = min(group, n_trees - start)
+                with obs_trace.span("train.program", steps=k):
+                    part, pred = _gbt_rounds(cfg, jb, jy, jw, pred, fm,
+                                             k, mesh=hist_mesh,
+                                             subtract=subtract)
+                if start + k < n_trees:
+                    with obs_trace.span("train.wait"):
+                        _pace_dispatch(pred)
+                parts.append(part)
+            # the host waiting on the device, apart from the copies
+            # and the assembly it then makes
+            with obs_trace.span("train.wait"):
+                jax.block_until_ready(parts)
+            with obs_trace.span("train.fetch"):
+                new_stacked = parts[0] if len(parts) == 1 else \
+                    jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+                if init_trees is not None:
+                    # continuous-training resume: prepend the old
+                    # ensemble (init_trees IS the stacked pytree already)
+                    new_stacked = jax.tree.map(
+                        lambda p, n: jnp.concatenate([jnp.asarray(p), n]),
+                        init_trees, new_stacked)
+                return jax.tree.map(np.asarray, new_stacked), []
+        for t in range(n_trees):
+            with obs_trace.span("train.program", steps=1):
+                tree, pred = _gbt_round(cfg, jb, jy, jw, pred, fm,
+                                        mesh=hist_mesh, subtract=subtract)
+                if val_data is not None:
+                    vraw = vraw + cfg.learning_rate * predict_trees(
+                        jax.tree.map(lambda a: a[None], tree), vb,
+                        cfg.max_depth, cfg.n_bins)[0]
+            trees.append(tree)
+            if val_data is not None:
+                # weighted mean (_val_error) so zero-weight padding rows
+                # don't bias it; the early-stop decision is a per-round
+                # host branch, so this sync is intentional — host_fetch
+                # times and counts it
+                with obs_trace.span("train.wait"):
+                    err = float(host_fetch(
+                        _val_error(vraw, vy, vw, cfg.loss)))
+                val_errs.append(err)
+                if err < best_val - 1e-9:
+                    best_val, bad = err, 0
+                else:
+                    bad += 1
+                    if early_stop_window and bad >= early_stop_window:
+                        break
+        with obs_trace.span("train.fetch"):
+            stacked = jax.tree.map(lambda *a: jnp.stack(a), *trees)
+            stacked = jax.tree.map(np.asarray, stacked)
+        return stacked, val_errs
 
 
 def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w_T, pred_T,
@@ -1141,9 +1176,10 @@ def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w_T, pred_T,
     trees_T, node_T = build_forest(cfg, binsT, grad_T, hess_T, fm_T,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
-    contrib_T = jax.vmap(lambda tr, n: tr["leaf_value"][n]
-                         )(trees_T, node_T)
-    return trees_T, pred_T + cfg.learning_rate * contrib_T
+    with jax.named_scope("leaf"):
+        contrib_T = jax.vmap(lambda tr, n: tr["leaf_value"][n]
+                             )(trees_T, node_T)
+        return trees_T, pred_T + cfg.learning_rate * contrib_T
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract"))
@@ -1185,85 +1221,104 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     would have kept. Returns a list of (stacked trees pytree,
     val_errs) per bag."""
     from shifu_tpu.parallel import mesh as mesh_mod
-    mesh = mesh_mod.default_mesh()
-    hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
     n_bags = int(weights_T.shape[0])
-    if isinstance(bins, jax.Array):
-        jb, jy = bins, jnp.asarray(y)
-        jw_T = jnp.asarray(weights_T)
-    else:
-        jb = mesh_mod.shard_axis(
-            mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1,
-            pad_value=0)
-        jy = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32))
-        jw_T = mesh_mod.shard_axis(
-            mesh, np.asarray(weights_T, np.float32), 1)
-    fm = np.asarray(feature_mask if feature_mask is not None
-                    else np.ones(int(jb.shape[0]), np.float32),
-                    np.float32)
-    fm_T = jnp.asarray(np.broadcast_to(fm[None, :], (n_bags, fm.size)))
-    subtract = _use_hist_subtract()
-    pred_T = jnp.zeros((n_bags, jb.shape[1]), jnp.float32)
-
-    if val_data is None and n_trees > 0:
-        # no per-round host decision → scan rounds device-side in
-        # SHIFU_TPU_GBT_SCAN_GROUP-sized dispatches (see build_gbt)
-        group = knob_int("SHIFU_TPU_GBT_SCAN_GROUP")
-        group = n_trees if group <= 0 else min(group, n_trees)
-        parts = []
-        for start in range(0, n_trees, group):
-            k = min(group, n_trees - start)
-            part, pred_T = _gbt_bagged_rounds(
-                cfg, jb, jy, jw_T, pred_T, fm_T, k, mesh=hist_mesh,
-                subtract=subtract)
-            if start + k < n_trees:
-                _pace_dispatch(pred_T)
-            parts.append(part)
-        rounds_T = parts[0] if len(parts) == 1 else jax.tree.map(
-            lambda *a: jnp.concatenate(a), *parts)   # (rounds, T, nodes)
-        rounds_np = jax.tree.map(np.asarray, rounds_T)
-        return [(jax.tree.map(lambda a, b=b: a[:, b], rounds_np), [])
-                for b in range(n_bags)]
-
-    vb, vy = val_data
-    n_val = vb.shape[0]
-    vb = mesh_mod.shard_axis(
-        mesh, np.ascontiguousarray(np.asarray(vb, np.int32).T), 1)
-    vy, vw = mesh_mod.shard_rows(
-        mesh, np.asarray(vy, np.float32), np.ones(n_val, np.float32))
-    vraw_T = jnp.zeros((n_bags, vb.shape[1]), jnp.float32)
-    round_trees: List[Any] = []
-    val_errs = [[] for _ in range(n_bags)]
-    best_val = np.full(n_bags, np.inf)
-    bad = np.zeros(n_bags, np.int64)
-    stop_round = np.full(n_bags, 0)
-    for t in range(n_trees):
-        trees_T, pred_T = _gbt_bagged_round(
-            cfg, jb, jy, jw_T, pred_T, fm_T, mesh=hist_mesh,
-            subtract=subtract)
-        round_trees.append(trees_T)
-        vraw_T = vraw_T + cfg.learning_rate * predict_trees(
-            trees_T, vb, cfg.max_depth, cfg.n_bins)
-        # ONE fetch decides every bag's round: (T,) error vector
-        errs = host_fetch(_val_error(vraw_T, vy, vw, cfg.loss))
-        for b in range(n_bags):
-            if stop_round[b]:
-                continue
-            err = float(errs[b])
-            val_errs[b].append(err)
-            if err < best_val[b] - 1e-9:
-                best_val[b], bad[b] = err, 0
+    with obs_trace.span("train.job", family="gbt", rows=int(y.shape[0]),
+                        steps=n_trees, bags=n_bags):
+        with obs_trace.span("train.prepare"):
+            mesh = mesh_mod.default_mesh()
+            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
+        with obs_trace.span("train.place"):
+            if isinstance(bins, jax.Array):
+                jb, jy = bins, jnp.asarray(y)
+                jw_T = jnp.asarray(weights_T)
             else:
-                bad[b] += 1
-                if early_stop_window and bad[b] >= early_stop_window:
-                    stop_round[b] = t + 1
-        if early_stop_window and stop_round.all():
-            break
-    stop_round[stop_round == 0] = len(round_trees)
-    stacked = jax.tree.map(lambda *a: jnp.stack(a), *round_trees)
-    stacked = jax.tree.map(np.asarray, stacked)  # (rounds, T, nodes)
-    return [(jax.tree.map(lambda a, b=b: a[:stop_round[b], b], stacked),
-             val_errs[b]) for b in range(n_bags)]
+                jb = mesh_mod.shard_axis(
+                    mesh,
+                    np.ascontiguousarray(np.asarray(bins, np.int32).T), 1,
+                    pad_value=0)
+                jy = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32))
+                jw_T = mesh_mod.shard_axis(
+                    mesh, np.asarray(weights_T, np.float32), 1)
+            fm = np.asarray(feature_mask if feature_mask is not None
+                            else np.ones(int(jb.shape[0]), np.float32),
+                            np.float32)
+            fm_T = jnp.asarray(np.broadcast_to(fm[None, :],
+                                               (n_bags, fm.size)))
+            subtract = _use_hist_subtract()
+            pred_T = jnp.zeros((n_bags, jb.shape[1]), jnp.float32)
+
+        if val_data is None and n_trees > 0:
+            # no per-round host decision → scan rounds device-side in
+            # SHIFU_TPU_GBT_SCAN_GROUP-sized dispatches (see build_gbt)
+            group = knob_int("SHIFU_TPU_GBT_SCAN_GROUP")
+            group = n_trees if group <= 0 else min(group, n_trees)
+            parts = []
+            for start in range(0, n_trees, group):
+                k = min(group, n_trees - start)
+                with obs_trace.span("train.program", steps=k):
+                    part, pred_T = _gbt_bagged_rounds(
+                        cfg, jb, jy, jw_T, pred_T, fm_T, k, mesh=hist_mesh,
+                        subtract=subtract)
+                if start + k < n_trees:
+                    with obs_trace.span("train.wait"):
+                        _pace_dispatch(pred_T)
+                parts.append(part)
+            with obs_trace.span("train.wait"):
+                jax.block_until_ready(parts)
+            with obs_trace.span("train.fetch"):
+                rounds_T = parts[0] if len(parts) == 1 else jax.tree.map(
+                    lambda *a: jnp.concatenate(a), *parts)
+                rounds_np = jax.tree.map(np.asarray,
+                                         rounds_T)   # (rounds, T, nodes)
+                return [(jax.tree.map(lambda a, b=b: a[:, b], rounds_np),
+                         []) for b in range(n_bags)]
+
+        with obs_trace.span("train.place"):
+            vb, vy = val_data
+            n_val = vb.shape[0]
+            vb = mesh_mod.shard_axis(
+                mesh, np.ascontiguousarray(np.asarray(vb, np.int32).T), 1)
+            vy, vw = mesh_mod.shard_rows(
+                mesh, np.asarray(vy, np.float32),
+                np.ones(n_val, np.float32))
+            vraw_T = jnp.zeros((n_bags, vb.shape[1]), jnp.float32)
+        round_trees: List[Any] = []
+        val_errs = [[] for _ in range(n_bags)]
+        best_val = np.full(n_bags, np.inf)
+        bad = np.zeros(n_bags, np.int64)
+        stop_round = np.full(n_bags, 0)
+        for t in range(n_trees):
+            with obs_trace.span("train.program", steps=1):
+                trees_T, pred_T = _gbt_bagged_round(
+                    cfg, jb, jy, jw_T, pred_T, fm_T, mesh=hist_mesh,
+                    subtract=subtract)
+                vraw_T = vraw_T + cfg.learning_rate * predict_trees(
+                    trees_T, vb, cfg.max_depth, cfg.n_bins)
+            round_trees.append(trees_T)
+            # ONE fetch decides every bag's round: (T,) error vector
+            with obs_trace.span("train.wait"):
+                errs = host_fetch(_val_error(vraw_T, vy, vw, cfg.loss))
+            for b in range(n_bags):
+                if stop_round[b]:
+                    continue
+                err = float(errs[b])
+                val_errs[b].append(err)
+                if err < best_val[b] - 1e-9:
+                    best_val[b], bad[b] = err, 0
+                else:
+                    bad[b] += 1
+                    if early_stop_window and bad[b] >= early_stop_window:
+                        stop_round[b] = t + 1
+            if early_stop_window and stop_round.all():
+                break
+        with obs_trace.span("train.fetch"):
+            stop_round[stop_round == 0] = len(round_trees)
+            stacked = jax.tree.map(lambda *a: jnp.stack(a), *round_trees)
+            stacked = jax.tree.map(np.asarray,
+                                   stacked)  # (rounds, T, nodes)
+            return [(jax.tree.map(lambda a, b=b: a[:stop_round[b], b],
+                                  stacked),
+                     val_errs[b]) for b in range(n_bags)]
 
 
 def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
@@ -1282,38 +1337,47 @@ def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     RF (`dt/DTWorker.java:530,660,1390,1550`); per-class balancing
     reuses the NN path's bagging_weights semantics."""
     from shifu_tpu.parallel import mesh as mesh_mod
-    rng = np.random.default_rng(seed)
     r, c = bins.shape
-    if stratified or neg_only:
-        from shifu_tpu.train.trainer import bagging_weights
-        inst_w = bagging_weights(r, n_trees, bagging_rate,
-                                 with_replacement=True, seed=seed,
-                                 labels=np.asarray(y, np.float32),
-                                 stratified=stratified, neg_only=neg_only)
-    else:
-        inst_w = rng.poisson(max(bagging_rate, 1e-6),
-                             size=(n_trees, r)).astype(np.float32)
-    inst_w[inst_w.sum(axis=1) == 0] = 1.0
-    k = feature_subset_count(subset_strategy, c)
-    masks = np.zeros((n_trees, c), np.float32)
-    for t in range(n_trees):
-        masks[t, rng.choice(c, size=k, replace=False)] = 1.0
+    with obs_trace.span("train.job", family="rf", rows=r, steps=n_trees,
+                        bags=1):
+        with obs_trace.span("train.prepare"):
+            rng = np.random.default_rng(seed)
+            if stratified or neg_only:
+                from shifu_tpu.train.trainer import bagging_weights
+                inst_w = bagging_weights(
+                    r, n_trees, bagging_rate, with_replacement=True,
+                    seed=seed, labels=np.asarray(y, np.float32),
+                    stratified=stratified, neg_only=neg_only)
+            else:
+                inst_w = rng.poisson(max(bagging_rate, 1e-6),
+                                     size=(n_trees, r)).astype(np.float32)
+            inst_w[inst_w.sum(axis=1) == 0] = 1.0
+            k = feature_subset_count(subset_strategy, c)
+            masks = np.zeros((n_trees, c), np.float32)
+            for t in range(n_trees):
+                masks[t, rng.choice(c, size=k, replace=False)] = 1.0
 
-    mesh = mesh_mod.default_mesh()
-    hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
-    jb = mesh_mod.shard_axis(
-        mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1)
-    jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
-                                 np.asarray(weights, np.float32))
-    d_inst_w = mesh_mod.shard_axis(mesh, inst_w, axis=1)
+        with obs_trace.span("train.place"):
+            mesh = mesh_mod.default_mesh()
+            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
+            jb = mesh_mod.shard_axis(
+                mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1)
+            jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
+                                         np.asarray(weights, np.float32))
+            d_inst_w = mesh_mod.shard_axis(mesh, inst_w, axis=1)
 
-    # leaf value = weighted mean label: grad = -y·w·iw, hess = w·iw
-    grad_T = -(jy * jw * d_inst_w)
-    hess_T = jw * d_inst_w
-    stacked = build_forest(cfg, jb, grad_T, hess_T, jnp.asarray(masks),
-                           subtract=_use_hist_subtract(),
-                           mesh=hist_mesh)
-    return jax.tree.map(np.asarray, stacked)
+        with obs_trace.span("train.program", steps=n_trees):
+            # leaf value = weighted mean label: grad = -y·w·iw, hess = w·iw
+            grad_T = -(jy * jw * d_inst_w)
+            hess_T = jw * d_inst_w
+            stacked = build_forest(cfg, jb, grad_T, hess_T,
+                                   jnp.asarray(masks),
+                                   subtract=_use_hist_subtract(),
+                                   mesh=hist_mesh)
+        with obs_trace.span("train.wait"):
+            jax.block_until_ready(stacked)
+        with obs_trace.span("train.fetch"):
+            return jax.tree.map(np.asarray, stacked)
 
 
 # ---------------------------------------------------------------------------

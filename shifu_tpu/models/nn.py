@@ -277,23 +277,28 @@ def forward(spec: MLPSpec, params: Params, x: jax.Array,
     bf16 = spec.compute_dtype == "bfloat16"
     cast = (lambda a: a.astype(jnp.bfloat16)) if bf16 else (lambda a: a)
     h = cast(x)
+    # one named scope a layer (metadata only): a profiler trace then
+    # names each device op by its layer, forward and backward
     for i, layer in enumerate(params[:-1]):
-        h = mm_f32(h, cast(layer["w"])) + layer["b"]
-        h = activation(spec.activations[i])(h)
-        if dropout_key is not None and spec.dropout_rate > 0.0:
-            dropout_key, sub = jax.random.split(dropout_key)
-            keep = jax.random.bernoulli(sub, 1.0 - spec.dropout_rate, h.shape)
-            h = jnp.where(keep, h / (1.0 - spec.dropout_rate),
-                          jnp.zeros((), h.dtype))
-        h = cast(h)
-    out = mm_f32(h, cast(params[-1]["w"])) + params[-1]["b"]
-    if spec.output_activation == "softmax":
-        # multi-class NATIVE head: one unit per flattened tag
-        # (train#multiClassifyMethod NATIVE — the reference builds an
-        # Encog net with tags.size() output neurons)
-        return jax.nn.softmax(out, axis=-1)
-    out = activation(spec.output_activation)(out)
-    return out[..., 0] if spec.output_dim == 1 else out
+        with jax.named_scope(f"layer{i}"):
+            h = mm_f32(h, cast(layer["w"])) + layer["b"]
+            h = activation(spec.activations[i])(h)
+            if dropout_key is not None and spec.dropout_rate > 0.0:
+                dropout_key, sub = jax.random.split(dropout_key)
+                keep = jax.random.bernoulli(sub, 1.0 - spec.dropout_rate,
+                                            h.shape)
+                h = jnp.where(keep, h / (1.0 - spec.dropout_rate),
+                              jnp.zeros((), h.dtype))
+            h = cast(h)
+    with jax.named_scope(f"layer{len(params) - 1}"):
+        out = mm_f32(h, cast(params[-1]["w"])) + params[-1]["b"]
+        if spec.output_activation == "softmax":
+            # multi-class NATIVE head: one unit per flattened tag
+            # (train#multiClassifyMethod NATIVE — the reference builds an
+            # Encog net with tags.size() output neurons)
+            return jax.nn.softmax(out, axis=-1)
+        out = activation(spec.output_activation)(out)
+        return out[..., 0] if spec.output_dim == 1 else out
 
 
 def loss_fn(spec: MLPSpec, params: Params, x: jax.Array, y: jax.Array,

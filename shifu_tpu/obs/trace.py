@@ -1,19 +1,31 @@
-"""Span-based flight recorder: the host half of the trace plane.
+"""Program spans: one span, two sinks.
 
-`span("family.stage", **attrs)` is a context manager that records one
-host span (wall start, duration, thread, parentage via a thread-local
-stack) into a per-process bounded ring buffer; `record_span` backfills
-a span from timestamps a layer already measured (the scheduler's
-`ready_t`/`start_t`, the serving plane's batch splits). With
-`SHIFU_TPU_TRACE` unset both are zero-cost no-ops — `span()` returns a
-shared inert object without touching a lock or the clock.
+`span("family.stage", **attrs)` is a context manager around a piece of
+host work. It is always a `jax.profiler.TraceAnnotation` named
+`shifu:family.stage`: whenever a profiler session is open (`shifu
+--profile`, `benchmark/run.py --trace 1`, an operator's
+`jax.profiler.start_trace`) the span lands in that session's
+`.xplane.pb`, on the calling thread's line of the host plane, on the
+profiler's own clock beside the device planes, its attrs as stats of
+the event. With no session open the annotation costs about a
+microsecond and records nothing. Neither importing this module nor
+entering a span initialises a jax backend.
+
+With `SHIFU_TPU_TRACE=1` the same enter/exit also records the span
+(wall start, duration, thread, parentage via a thread-local stack)
+into a per-process bounded ring buffer; with the knob unset the ring,
+its lock and the clock are never touched and no file is written.
+`record_span` backfills a span from timestamps a layer already
+measured (the scheduler's `ready_t`/`start_t`, the serving plane's
+batch splits, the `input.*` stage timers); a profiler session cannot
+take an event after the fact, so those reach the ring buffer only.
 
 Per step, `trace_run` (entered by `cli.main` around every command):
 
 - generates the run_id that also names the `maybe_profile` device
-  trace (`tmp/profile/<run_id>/`), so host spans and XLA ops for one
-  step are sibling, discoverable artifacts (`shifu trace ls` pairs
-  them);
+  trace (`tmp/profile/<run_id>/`), which holds the step's `span()`s
+  itself; `shifu trace ls` pairs it with the ring buffer's export,
+  the only place the `record_span` families show;
 - exports this process's spans to `<trace_dir>/spans.<pid>.jsonl` via
   `resilience.atomic_write` (first line is a clock record carrying the
   host's offset to the coordinator clock);
@@ -44,6 +56,7 @@ import glob
 import json
 import logging
 import os
+import re
 import threading
 import time
 import zlib
@@ -95,7 +108,55 @@ SPAN_FAMILIES: Dict[str, Tuple[str, ...]] = {
     # shadow plane: one score span per mirrored request the side
     # thread replays against the challenger arm (discarded response)
     "shadow": ("score",),
+    # the trainers' host side, one job span per call of a public
+    # training entry (train_nn, the WDL/MTL resident trainers,
+    # build_gbt, build_gbt_bagged, build_rf) with its phases nested
+    # inside on the calling thread: prepare (host work before anything
+    # is placed), place (uploads and the fresh carry), program (the
+    # call into the jitted program until it returns to Python: trace,
+    # lower, cache read or compile, dispatch), wait (the first
+    # blocking read of its results: the host waiting on the device),
+    # fetch (the remaining device→host copies and result assembly)
+    "train": ("job", "prepare", "place", "program", "wait", "fetch"),
+    # the one sanctioned device→host sync, data/pipeline.host_fetch
+    "host": ("sync",),
 }
+
+# every span's name in a profiler trace starts with this
+ANNOTATION_PREFIX = "shifu:"
+
+# the `jax.named_scope`s inside the device programs (metadata only, no
+# run-time cost): a compiled op's `op_name` is its path of scopes, and
+# a profiler trace keeps it with every device event. The trainers'
+# epoch step (`train_bags_carry`): forward_loss (the value_and_grad of
+# the loss; jax marks its backward ops `transpose(jvp(..))` itself),
+# update (optimizer update and apply), validate (the validation
+# metric), select (best-epoch and early-stop bookkeeping), and inside
+# them one `layer<i>` a layer of `models/nn.forward`. A boosting round
+# (`models/gbdt.py`): gradients, hist (level histograms and sibling
+# subtraction), split (best splits and their fold into the tree),
+# route (rows to their child nodes), leaf (final leaf values, the
+# per-row leaf gather and the prediction update).
+DEVICE_SCOPES = ("forward_loss", "update", "validate", "select",
+                 "gradients", "hist", "split", "route", "leaf")
+_LAYER_SCOPE = re.compile(r"layer\d+")
+_WORD = re.compile(r"[A-Za-z_]\w*")
+
+
+def device_scopes(op_name: str) -> Tuple[str, ...]:
+    """The program scopes of an HLO `op_name`, outermost first:
+    `jit(train_bags_carry)/vmap()/while/body/forward_loss/
+    transpose(jvp(layer1))/dot_general` → `("forward_loss", "layer1")`;
+    `()` where none is registered. The last component is the primitive;
+    jitted helpers are skipped, and what jax or XLA wraps around a scope
+    is looked through (`vmap(route)`, `reshape;split`)."""
+    found = []
+    for part in op_name.split("/")[:-1]:
+        if part.startswith(("jit(", "pjit(")):
+            continue
+        found += [w for w in _WORD.findall(part)
+                  if w in DEVICE_SCOPES or _LAYER_SCOPE.fullmatch(w)]
+    return tuple(found)
 
 
 def span_registered(name: str) -> bool:
@@ -229,25 +290,22 @@ class Tracer:
         return out
 
 
-class _Noop:
-    """The disabled-path span: a shared inert context manager."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, et, ev, tb):
-        return False
-
-
-_NOOP = _Noop()
+def _annotation(name: str, attrs: Dict):
+    """The span as the profiler sees it. jax is imported by the first
+    span, not with this module (`shifu top` and the DAG parent open
+    none and stay light); a later call pays a dictionary lookup. No
+    backend is touched either way."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(ANNOTATION_PREFIX + name, **attrs)
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "attrs", "id", "parent", "_t0")
+    """A span that also records into the ring buffer."""
+    __slots__ = ("_tr", "_ann", "name", "attrs", "id", "parent", "_t0")
 
     def __init__(self, tr: Tracer, name: str, attrs: Dict):
         self._tr = tr
+        self._ann = _annotation(name, attrs)
         self.name = name
         self.attrs = attrs
         self.id = ""
@@ -259,12 +317,14 @@ class _Span:
         self.parent = st[-1] if st else tr.root_id
         self.id = tr.new_id()
         st.append(self.id)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         tr.opened(self.id, self.name, self._t0)
         return self
 
     def __exit__(self, et, ev, tb):
         t1 = time.monotonic()
+        self._ann.__exit__(et, ev, tb)
         st = _stack()
         if st and st[-1] == self.id:
             st.pop()
@@ -297,11 +357,13 @@ def active() -> bool:
 
 
 def span(name: str, **attrs):
-    """Record a span around a `with` block. Zero-cost no-op unless a
-    `trace_run` with `SHIFU_TPU_TRACE=1` is active."""
+    """A span around a `with` block: always a profiler annotation
+    (`shifu:<name>`, seen by whatever profiler session is open), and a
+    ring-buffer record besides while a `trace_run` with
+    `SHIFU_TPU_TRACE=1` is active."""
     run = _RUN
     if run is None or not run.enabled:
-        return _NOOP
+        return _annotation(name, attrs)
     return _Span(run.tracer, name, attrs)
 
 
@@ -309,10 +371,11 @@ def record_span(name: str, t0_mono: float, t1_mono: float,
                 parent: Optional[str] = None,
                 track: Optional[str] = None, **attrs) -> Optional[str]:
     """Backfill one span from monotonic timestamps a layer already
-    measured. `parent` defaults to the calling thread's open span (or
-    the run root); `track` groups the event onto a named synthetic
-    Perfetto track instead of the recording thread's. Returns the span
-    id (for parenting children), or None when tracing is off."""
+    measured, into the ring buffer only (a profiler session takes no
+    event after the fact). `parent` defaults to the calling thread's
+    open span (or the run root); `track` groups the event onto a named
+    synthetic Perfetto track instead of the recording thread's. Returns
+    the span id (for parenting children), or None when tracing is off."""
     run = _RUN
     if run is None or not run.enabled:
         return None
@@ -336,8 +399,8 @@ def open_spans() -> List[dict]:
 
 def current_run_id(step: Optional[str] = None) -> str:
     """The active trace run's id, or a fresh one for an untraced step —
-    either way the id `maybe_profile` should name its output after so
-    device and host traces pair up under tmp/."""
+    either way the id `maybe_profile` names its output after, so the
+    profiler trace and the ring buffer's export pair up under tmp/."""
     run = _RUN
     if run is not None:
         return run.run_id

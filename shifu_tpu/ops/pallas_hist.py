@@ -273,6 +273,7 @@ def _level_histograms_pallas(binsT, slot, grad, hess,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_budget()),
         interpret=interpret,
+        name="shifu_level_histograms",
     )(binsT.astype(jnp.int32), packed)
 
     def reassemble(a):
@@ -363,6 +364,7 @@ def _level_histograms_fused(valuesT, cutsT, slot, grad, hess,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_budget()),
         interpret=interpret,
+        name="shifu_level_histograms_fused",
     )(valuesT.astype(jnp.float32), cutsT.astype(jnp.float32), packed)
 
     def reassemble(a):

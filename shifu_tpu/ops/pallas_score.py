@@ -119,6 +119,7 @@ def _fused_first_layer_pallas(values, mean, std, w, cutoff: float,
         out_specs=pl.BlockSpec((row_tile, hp), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, hp), jnp.float32),
         interpret=interpret,
+        name="shifu_first_layer",
     )(x, packed, wp)
     return out[:n, :h]
 

@@ -233,6 +233,7 @@ def best_splits_pallas(g, h, feature_mask, lam: float, min_inst: float,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="shifu_best_splits",
     )(gp, hp, mp)
 
     out = out[:n, 0, :]

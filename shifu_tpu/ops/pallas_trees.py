@@ -255,6 +255,7 @@ def _predict_ensemble_pallas(nodes, valuesT, cuts, n_trees: int,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=knob_int("SHIFU_TPU_TREE_VMEM_MB") << 20),
         interpret=interpret,
+        name="shifu_predict_ensemble",
     )(vp, cuts.astype(jnp.float32), nodes)
     return out[0, :r]
 

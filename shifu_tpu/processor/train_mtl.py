@@ -23,6 +23,7 @@ from shifu_tpu.data.purifier import DataPurifier
 from shifu_tpu.data.reader import read_raw_table, simple_column_name
 from shifu_tpu.models import mtl
 from shifu_tpu.models.spec import save_model
+from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import optimizer_from_params
@@ -81,57 +82,62 @@ def run_mtl(ctx: ProcessorContext, seed: int = 12306):
     spec = mtl.MTLSpec.from_train_params(mc.train.params, dense.shape[1],
                                          len(names))
 
-    tr_mask, val_mask = split_validation(len(y), mc.train.validSetRate, seed)
     n_bags = max(mc.train.baggingNum, 1)
-    # stratify/neg-sample on the primary task's label (task 0 — the
-    # same label upSampleWeight keys on above)
-    bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
-                            mc.train.baggingSampleRate,
-                            mc.train.baggingWithReplacement, seed,
-                            labels=np.asarray(y[tr_mask][:, 0]),
-                            stratified=mc.train.stratifiedSample,
-                            neg_only=mc.train.sampleNegOnly) \
-        * w[tr_mask][None, :]
+    with obs_trace.span("train.job", family="mtl", rows=len(y),
+                        steps=mc.train.numTrainEpochs, bags=n_bags):
+        with obs_trace.span("train.prepare"):
+            tr_mask, val_mask = split_validation(
+                len(y), mc.train.validSetRate, seed)
+            # stratify/neg-sample on the primary task's label (task 0 — the
+            # same label upSampleWeight keys on above)
+            bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
+                                    mc.train.baggingSampleRate,
+                                    mc.train.baggingWithReplacement, seed,
+                                    labels=np.asarray(y[tr_mask][:, 0]),
+                                    stratified=mc.train.stratifiedSample,
+                                    neg_only=mc.train.sampleNegOnly) \
+                * w[tr_mask][None, :]
 
-    key = jax.random.PRNGKey(seed)
-    bag_keys = jax.random.split(key, n_bags)
-    stacked = jax.vmap(lambda k: mtl.init_params(spec, k))(bag_keys)
-    grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
+            key = jax.random.PRNGKey(seed)
+            bag_keys = jax.random.split(key, n_bags)
+            stacked = jax.vmap(lambda k: mtl.init_params(spec, k))(bag_keys)
+            grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
 
-    def loss(params, inputs, w_, key_):
-        x_, y_ = inputs
-        return mtl.loss_fn(spec, params, x_, y_, w_)
+            def loss(params, inputs, w_, key_):
+                x_, y_ = inputs
+                return mtl.loss_fn(spec, params, x_, y_, w_)
 
-    def metric(params, inputs, w_):
-        x_, y_ = inputs
-        return mtl.mse(spec, params, x_, y_, w_)
+            def metric(params, inputs, w_):
+                x_, y_ = inputs
+                return mtl.mse(spec, params, x_, y_, w_)
 
-    optimizer = optimizer_from_params(mc.train.params)
-    ew = mc.train.earlyStoppingRounds
-    # train_bags shards rows / replicates params over the default mesh
-    # with SHIFU_TPU_MESH_MODEL > 1, per-task head rows shard over
-    # 'model' (tasks are independent); the shared trunk replicates
-    from shifu_tpu.parallel import mesh as mesh_mod
-    mesh = mesh_mod.default_mesh()
-    shardings = None
-    if mesh.shape.get("model", 1) > 1:
-        one = jax.tree.map(lambda l: l[0], stacked)
-        shardings = mesh_mod.mtl_train_shardings(mesh, one)
-    best_params, _, _, best_val, _ = train_bags(
-        loss, metric, optimizer, mc.train.numTrainEpochs,
-        ew if ew and ew > 0 else 0,
-        float(mc.train.convergenceThreshold or 0.0),
-        stacked, (dense[tr_mask], y[tr_mask]),
-        bag_w,
-        (dense[val_mask], y[val_mask]),
-        w[val_mask], bag_keys, grad_mask, param_shardings=shardings)
+            optimizer = optimizer_from_params(mc.train.params)
+            ew = mc.train.earlyStoppingRounds
+            # train_bags shards rows / replicates params over the default mesh
+            # with SHIFU_TPU_MESH_MODEL > 1, per-task head rows shard over
+            # 'model' (tasks are independent); the shared trunk replicates
+            from shifu_tpu.parallel import mesh as mesh_mod
+            mesh = mesh_mod.default_mesh()
+            shardings = None
+            if mesh.shape.get("model", 1) > 1:
+                one = jax.tree.map(lambda l: l[0], stacked)
+                shardings = mesh_mod.mtl_train_shardings(mesh, one)
+        best_params, _, _, best_val, _ = train_bags(
+            loss, metric, optimizer, mc.train.numTrainEpochs,
+            ew if ew and ew > 0 else 0,
+            float(mc.train.convergenceThreshold or 0.0),
+            stacked, (dense[tr_mask], y[tr_mask]),
+            bag_w,
+            (dense[val_mask], y[val_mask]),
+            w[val_mask], bag_keys, grad_mask, param_shardings=shardings)
 
-    spec_meta = _mtl_spec_meta(mc, spec, names, meta)
-    for i in range(n_bags):
-        p = jax.tree.map(lambda a, i=i: np.asarray(a[i]), best_params)
-        mpath = ctx.path_finder.model_path(i, "mtl")
-        ctx.path_finder.ensure(mpath)
-        save_model(mpath, "mtl", spec_meta, p)
+        with obs_trace.span("train.fetch"):
+            spec_meta = _mtl_spec_meta(mc, spec, names, meta)
+            for i in range(n_bags):
+                p = jax.tree.map(lambda a, i=i: np.asarray(a[i]), best_params)
+                mpath = ctx.path_finder.model_path(i, "mtl")
+                ctx.path_finder.ensure(mpath)
+                save_model(mpath, "mtl", spec_meta, p)
     log.info("train[MTL]: %d tasks, %d bag(s), best val %s in %.2fs",
              len(names), n_bags, np.round(np.asarray(best_val), 6).tolist(),
              time.time() - t0)

@@ -14,6 +14,7 @@ import numpy as np
 
 from shifu_tpu.models import wdl
 from shifu_tpu.models.spec import save_model
+from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import optimizer_from_params
@@ -50,56 +51,61 @@ def run_wdl(ctx: ProcessorContext, seed: int = 12306):
     spec = wdl.WDLSpec.from_train_params(mc.train.params, dense.shape[1],
                                          idx.shape[1], vocab)
 
-    tr_mask, val_mask = split_validation(len(y), mc.train.validSetRate, seed)
     n_bags = max(mc.train.baggingNum, 1)
-    bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
-                            mc.train.baggingSampleRate,
-                            mc.train.baggingWithReplacement, seed,
-                            labels=np.asarray(y[tr_mask]),
-                            stratified=mc.train.stratifiedSample,
-                            neg_only=mc.train.sampleNegOnly) \
-        * w[tr_mask][None, :]
+    with obs_trace.span("train.job", family="wdl", rows=len(y),
+                        steps=mc.train.numTrainEpochs, bags=n_bags):
+        with obs_trace.span("train.prepare"):
+            tr_mask, val_mask = split_validation(
+                len(y), mc.train.validSetRate, seed)
+            bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
+                                    mc.train.baggingSampleRate,
+                                    mc.train.baggingWithReplacement, seed,
+                                    labels=np.asarray(y[tr_mask]),
+                                    stratified=mc.train.stratifiedSample,
+                                    neg_only=mc.train.sampleNegOnly) \
+                * w[tr_mask][None, :]
 
-    key = jax.random.PRNGKey(seed)
-    bag_keys = jax.random.split(key, n_bags)
-    stacked = jax.vmap(lambda k: wdl.init_params(spec, k))(bag_keys)
-    grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
+            key = jax.random.PRNGKey(seed)
+            bag_keys = jax.random.split(key, n_bags)
+            stacked = jax.vmap(lambda k: wdl.init_params(spec, k))(bag_keys)
+            grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
 
-    def loss(params, inputs, w_, key_):
-        d_, i_, y_ = inputs
-        return wdl.loss_fn(spec, params, d_, i_, y_, w_)
+            def loss(params, inputs, w_, key_):
+                d_, i_, y_ = inputs
+                return wdl.loss_fn(spec, params, d_, i_, y_, w_)
 
-    def metric(params, inputs, w_):
-        d_, i_, y_ = inputs
-        return wdl.mse(spec, params, d_, i_, y_, w_)
+            def metric(params, inputs, w_):
+                d_, i_, y_ = inputs
+                return wdl.mse(spec, params, d_, i_, y_, w_)
 
-    optimizer = optimizer_from_params(mc.train.params)
-    ew = mc.train.earlyStoppingRounds
-    # rows shard over 'data'; with SHIFU_TPU_MESH_MODEL > 1 the
-    # embedding + wide tables additionally shard over 'model' (the
-    # vocab-heavy leaves that data-parallel would replicate per chip)
-    from shifu_tpu.parallel import mesh as mesh_mod
-    mesh = mesh_mod.default_mesh()
-    shardings = None
-    if mesh.shape.get("model", 1) > 1:
-        one = jax.tree.map(lambda l: l[0], stacked)
-        shardings = mesh_mod.wdl_train_shardings(mesh, one)
-    best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
-        loss, metric, optimizer, mc.train.numTrainEpochs,
-        ew if ew and ew > 0 else 0,
-        float(mc.train.convergenceThreshold or 0.0),
-        stacked,
-        (dense[tr_mask], idx[tr_mask], y[tr_mask]),
-        bag_w,
-        (dense[val_mask], idx[val_mask], y[val_mask]),
-        w[val_mask], bag_keys, grad_mask, param_shardings=shardings)
+            optimizer = optimizer_from_params(mc.train.params)
+            ew = mc.train.earlyStoppingRounds
+            # rows shard over 'data'; with SHIFU_TPU_MESH_MODEL > 1 the
+            # embedding + wide tables additionally shard over 'model' (the
+            # vocab-heavy leaves that data-parallel would replicate per chip)
+            from shifu_tpu.parallel import mesh as mesh_mod
+            mesh = mesh_mod.default_mesh()
+            shardings = None
+            if mesh.shape.get("model", 1) > 1:
+                one = jax.tree.map(lambda l: l[0], stacked)
+                shardings = mesh_mod.wdl_train_shardings(mesh, one)
+        best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
+            loss, metric, optimizer, mc.train.numTrainEpochs,
+            ew if ew and ew > 0 else 0,
+            float(mc.train.convergenceThreshold or 0.0),
+            stacked,
+            (dense[tr_mask], idx[tr_mask], y[tr_mask]),
+            bag_w,
+            (dense[val_mask], idx[val_mask], y[val_mask]),
+            w[val_mask], bag_keys, grad_mask, param_shardings=shardings)
 
-    spec_meta = _wdl_spec_meta(mc, spec, meta)
-    for i in range(n_bags):
-        p = jax.tree.map(lambda a, i=i: np.asarray(a[i]), best_params)
-        path = ctx.path_finder.model_path(i, "wdl")
-        ctx.path_finder.ensure(path)
-        save_model(path, "wdl", spec_meta, p)
+        with obs_trace.span("train.fetch"):
+            spec_meta = _wdl_spec_meta(mc, spec, meta)
+            for i in range(n_bags):
+                p = jax.tree.map(lambda a, i=i: np.asarray(a[i]), best_params)
+                path = ctx.path_finder.model_path(i, "wdl")
+                ctx.path_finder.ensure(path)
+                save_model(path, "wdl", spec_meta, p)
     log.info("train[WDL]: %d bag(s), best val %s in %.2fs", n_bags,
              np.round(np.asarray(best_val), 6).tolist(), time.time() - t0)
     return None

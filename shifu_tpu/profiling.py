@@ -693,10 +693,13 @@ def roofline(family: str, flops_per_row: float, bytes_per_row: float,
 @contextlib.contextmanager
 def maybe_profile(root: str, step: str, enabled: bool):
     """jax.profiler trace around a step when --profile is set. The
-    output dir is named by the tracer's run_id, so the device trace
-    (`tmp/profile/<run_id>/`) and the host span trace
-    (`tmp/trace/<run_id>.trace.json`) of one step are siblings that
-    `shifu trace ls` can pair."""
+    trace (`tmp/profile/<run_id>/`) contains the step's program spans
+    itself: every `obs.trace.span` is a profiler annotation
+    (`shifu:train.program`, ...) on the host plane, on the same clock
+    as the device planes. The output dir is named by the tracer's
+    run_id, so `shifu trace ls` pairs it with the ring buffer's export
+    (`tmp/trace/<run_id>.trace.json`, written under
+    `SHIFU_TPU_TRACE=1`), which adds the `record_span` families."""
     if not enabled:
         yield None
         return

@@ -40,6 +40,7 @@ from shifu_tpu import resilience
 from shifu_tpu.config.model_config import ModelTrainConf
 from shifu_tpu.data import pipeline as pipe
 from shifu_tpu.models import nn as nn_mod
+from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.parallel import mesh as mesh_mod
 from shifu_tpu.train.optimizers import optimizer_from_params
 
@@ -218,13 +219,15 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                     p, o, k = bc
                     k, bkey = jax.random.split(k)
                     inp_b = jax.tree.map(lambda t: t[bi], train_inputs)
-                    loss_b, grads_b = jax.value_and_grad(loss_fn)(
-                        p, inp_b, w_train[bi], bkey)
-                    grads_b = jax.tree.map(lambda g, m: g * m, grads_b,
-                                           grad_mask)
-                    upd, o2 = optimizer.update(grads_b, o, p)
-                    return (optax.apply_updates(p, upd), o2, k), \
-                        (loss_b, jnp.sum(w_train[bi]))
+                    with jax.named_scope("forward_loss"):
+                        loss_b, grads_b = jax.value_and_grad(loss_fn)(
+                            p, inp_b, w_train[bi], bkey)
+                    with jax.named_scope("update"):
+                        grads_b = jax.tree.map(lambda g, m: g * m, grads_b,
+                                               grad_mask)
+                        upd, o2 = optimizer.update(grads_b, o, p)
+                        p2 = optax.apply_updates(p, upd)
+                    return (p2, o2, k), (loss_b, jnp.sum(w_train[bi]))
 
                 key, pkey = jax.random.split(key)
                 perm = jax.random.permutation(pkey, n_batches)
@@ -238,31 +241,40 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                 train_err = jnp.sum(losses * wsums) / \
                     jnp.maximum(jnp.sum(wsums), 1e-12)
             else:
-                train_err, grads = jax.value_and_grad(loss_fn)(
-                    params, train_inputs, w_train, sub)
-                grads = jax.tree.map(lambda g, m: g * m, grads, grad_mask)
-                updates, new_opt_state = optimizer.update(grads, opt_state,
-                                                          params)
-                new_params = optax.apply_updates(params, updates)
-            # freeze when stopped (scan must run to fixed length)
-            keep = lambda new, old: jax.tree.map(  # noqa: E731
-                lambda a, b: jnp.where(stopped, b, a), new, old)
-            params2 = keep(new_params, params)
-            opt_state2 = jax.tree.map(
-                lambda a, b: jnp.where(stopped, b, a) if a.shape == b.shape else a,
-                new_opt_state, opt_state)
-            val_err = metric_fn(params2, val_inputs, w_val)
-            improved = val_err < best_val
-            best_params2 = jax.tree.map(
-                lambda bp, p: jnp.where(improved & ~stopped, p, bp),
-                best_params, params2)
-            best_val2 = jnp.where(improved & ~stopped, val_err, best_val)
-            bad2 = jnp.where(stopped, bad_count,
-                             jnp.where(improved, 0, bad_count + 1))
-            window_stop = (early_stop_window > 0) & (bad2 >= early_stop_window)
-            converge_stop = (convergence_threshold > 0.0) & \
-                (train_err <= convergence_threshold)
-            stopped2 = stopped | window_stop | converge_stop
+                with jax.named_scope("forward_loss"):
+                    train_err, grads = jax.value_and_grad(loss_fn)(
+                        params, train_inputs, w_train, sub)
+                with jax.named_scope("update"):
+                    grads = jax.tree.map(lambda g, m: g * m, grads,
+                                         grad_mask)
+                    updates, new_opt_state = optimizer.update(
+                        grads, opt_state, params)
+                    new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("update"):
+                # freeze when stopped (scan must run to fixed length)
+                keep = lambda new, old: jax.tree.map(  # noqa: E731
+                    lambda a, b: jnp.where(stopped, b, a), new, old)
+                params2 = keep(new_params, params)
+                opt_state2 = jax.tree.map(
+                    lambda a, b: jnp.where(stopped, b, a)
+                    if a.shape == b.shape else a,
+                    new_opt_state, opt_state)
+            with jax.named_scope("validate"):
+                val_err = metric_fn(params2, val_inputs, w_val)
+            with jax.named_scope("select"):
+                improved = val_err < best_val
+                best_params2 = jax.tree.map(
+                    lambda bp, p: jnp.where(improved & ~stopped, p, bp),
+                    best_params, params2)
+                best_val2 = jnp.where(improved & ~stopped, val_err,
+                                      best_val)
+                bad2 = jnp.where(stopped, bad_count,
+                                 jnp.where(improved, 0, bad_count + 1))
+                window_stop = (early_stop_window > 0) & \
+                    (bad2 >= early_stop_window)
+                converge_stop = (convergence_threshold > 0.0) & \
+                    (train_err <= convergence_threshold)
+                stopped2 = stopped | window_stop | converge_stop
             carry2 = (params2, opt_state2,
                       {"params": best_params2, "val": best_val2},
                       {"bad": bad2, "stopped": stopped2}, key)
@@ -343,82 +355,84 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
     shards over the mesh, and the epoch becomes an in-graph scan over
     shuffled batches (see train_bags_carry) — activation memory scales
     with batch_rows × bags instead of rows × bags."""
-    mesh = mesh_mod.default_mesh()
-    # .shape, not np.asarray(...).shape: the inputs can be device
-    # arrays (on-device data generation), and asarray would pull the
-    # whole array back to host just to read a dimension
-    n_rows = int(train_inputs[0].shape[0])
-    n_batches = 1
-    if batch_rows and 0 < batch_rows < n_rows:
-        n_batches = -(-n_rows // batch_rows)
-        # break any on-disk row ordering (sorted/grouped data would
-        # otherwise make every mini-batch class-homogeneous): rows are
-        # permuted once here, and the in-graph scan additionally
-        # shuffles BATCH order every epoch. The seed derives from the
-        # caller's train seed so bags/runs don't all share one order.
-        perm = np.random.default_rng(
-            np.uint64(0xB47C4) ^ np.uint64(perm_seed)).permutation(n_rows)
-        if any(isinstance(t, jax.Array) for t in train_inputs):
-            # to_batches permutes on the HOST (single-allocation
-            # permute+pad — mini-batch mode exists to bound host
-            # memory): device inputs get pulled back first — the very
-            # transfer a caller placing them on device was avoiding
-            log.warning("mini-batch mode with device-array inputs: "
-                        "rows are permuted on host, forcing a "
-                        "device->host readback of the full dataset")
+    with obs_trace.span("train.place"):
+        mesh = mesh_mod.default_mesh()
+        # .shape, not np.asarray(...).shape: the inputs can be device
+        # arrays (on-device data generation), and asarray would pull the
+        # whole array back to host just to read a dimension
+        n_rows = int(train_inputs[0].shape[0])
+        n_batches = 1
+        if batch_rows and 0 < batch_rows < n_rows:
+            n_batches = -(-n_rows // batch_rows)
+            # break any on-disk row ordering (sorted/grouped data would
+            # otherwise make every mini-batch class-homogeneous): rows are
+            # permuted once here, and the in-graph scan additionally
+            # shuffles BATCH order every epoch. The seed derives from the
+            # caller's train seed so bags/runs don't all share one order.
+            perm = np.random.default_rng(
+                np.uint64(0xB47C4) ^ np.uint64(perm_seed)).permutation(n_rows)
+            if any(isinstance(t, jax.Array) for t in train_inputs):
+                # to_batches permutes on the HOST (single-allocation
+                # permute+pad — mini-batch mode exists to bound host
+                # memory): device inputs get pulled back first — the very
+                # transfer a caller placing them on device was avoiding
+                log.warning("mini-batch mode with device-array inputs: "
+                            "rows are permuted on host, forcing a "
+                            "device->host readback of the full dataset")
 
-        def to_batches(a, axis_rows=0):
-            # permute + pad + reshape in ONE allocation (a permuted
-            # intermediate copy would double host RAM exactly when
-            # MiniBatchRows is in use for memory reasons)
-            a = np.asarray(a)
-            padded = a.shape[:axis_rows] + (n_batches * batch_rows,) \
-                + a.shape[axis_rows + 1:]
-            out = np.zeros(padded, a.dtype)  # zero weight ⇒ pad is inert
-            sel = [slice(None)] * a.ndim
-            sel[axis_rows] = slice(0, a.shape[axis_rows])
-            # mode='clip' (a no-op: perm is a permutation) lets take
-            # write straight into the out view — the default
-            # mode='raise' always buffers a full temporary copy
-            np.take(a, perm, axis=axis_rows, out=out[tuple(sel)],
-                    mode="clip")
-            shape = (a.shape[:axis_rows] + (n_batches, batch_rows)
-                     + a.shape[axis_rows + 1:])
-            return out.reshape(shape)
+            def to_batches(a, axis_rows=0):
+                # permute + pad + reshape in ONE allocation (a permuted
+                # intermediate copy would double host RAM exactly when
+                # MiniBatchRows is in use for memory reasons)
+                a = np.asarray(a)
+                padded = a.shape[:axis_rows] + (n_batches * batch_rows,) \
+                    + a.shape[axis_rows + 1:]
+                out = np.zeros(padded, a.dtype)  # zero weight ⇒ pad is inert
+                sel = [slice(None)] * a.ndim
+                sel[axis_rows] = slice(0, a.shape[axis_rows])
+                # mode='clip' (a no-op: perm is a permutation) lets take
+                # write straight into the out view — the default
+                # mode='raise' always buffers a full temporary copy
+                np.take(a, perm, axis=axis_rows, out=out[tuple(sel)],
+                        mode="clip")
+                shape = (a.shape[:axis_rows] + (n_batches, batch_rows)
+                         + a.shape[axis_rows + 1:])
+                return out.reshape(shape)
 
-        train_inputs = tuple(to_batches(t) for t in train_inputs)
-        w_train_bags = to_batches(w_train_bags, axis_rows=1)
-        train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 1)
-                             for t in train_inputs)
-        w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=2)
-    else:
-        train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 0)
-                             for t in train_inputs)
-        w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=1)
-    val_inputs = tuple(mesh_mod.shard_axis(mesh, t, 0) for t in val_inputs)
-    w_val = mesh_mod.shard_axis(mesh, w_val, 0)
-    if param_shardings is not None and mesh.shape.get("model", 1) > 1:
-        # model-axis layout (SHIFU_TPU_MESH_MODEL > 1): vocab-heavy
-        # leaves (WDL embedding/wide tables, MTL head rows) shard over
-        # 'model' instead of replicating per chip; optimizer moments
-        # get the same layout via _init_opt_state's out_shardings
-        stacked_params = mesh_mod.place_stacked(stacked_params,
-                                                param_shardings)
-        # grad_mask is UNSTACKED (applied per-bag inside the vmap)
-        grad_mask = mesh_mod.place(grad_mask, param_shardings)
-    else:
-        if mesh.shape.get("model", 1) > 1:
-            log.warning(
-                "SHIFU_TPU_MESH_MODEL=%d but this trainer has no "
-                "model-axis layout — params replicate and rows shard "
-                "over only the %d-device data axis (the model axis "
-                "helps only resident WDL/MTL)",
-                mesh.shape["model"], mesh.shape["data"])
-        stacked_params = mesh_mod.place_replicated(mesh, stacked_params)
-        grad_mask = mesh_mod.place_replicated(mesh, grad_mask)
-    dropout_keys = mesh_mod.place_replicated(mesh, jnp.asarray(dropout_keys))
+            train_inputs = tuple(to_batches(t) for t in train_inputs)
+            w_train_bags = to_batches(w_train_bags, axis_rows=1)
+            train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 1)
+                                 for t in train_inputs)
+            w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=2)
+        else:
+            train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 0)
+                                 for t in train_inputs)
+            w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=1)
+        val_inputs = tuple(mesh_mod.shard_axis(mesh, t, 0) for t in val_inputs)
+        w_val = mesh_mod.shard_axis(mesh, w_val, 0)
+        if param_shardings is not None and mesh.shape.get("model", 1) > 1:
+            # model-axis layout (SHIFU_TPU_MESH_MODEL > 1): vocab-heavy
+            # leaves (WDL embedding/wide tables, MTL head rows) shard over
+            # 'model' instead of replicating per chip; optimizer moments
+            # get the same layout via _init_opt_state's out_shardings
+            stacked_params = mesh_mod.place_stacked(stacked_params,
+                                                    param_shardings)
+            # grad_mask is UNSTACKED (applied per-bag inside the vmap)
+            grad_mask = mesh_mod.place(grad_mask, param_shardings)
+        else:
+            if mesh.shape.get("model", 1) > 1:
+                log.warning(
+                    "SHIFU_TPU_MESH_MODEL=%d but this trainer has no "
+                    "model-axis layout — params replicate and rows shard "
+                    "over only the %d-device data axis (the model axis "
+                    "helps only resident WDL/MTL)",
+                    mesh.shape["model"], mesh.shape["data"])
+            stacked_params = mesh_mod.place_replicated(mesh, stacked_params)
+            grad_mask = mesh_mod.place_replicated(mesh, grad_mask)
+        dropout_keys = mesh_mod.place_replicated(
+            mesh, jnp.asarray(dropout_keys))
 
-    carry = init_train_carry(optimizer, stacked_params, dropout_keys)
+        carry = init_train_carry(optimizer, stacked_params, dropout_keys)
     done = 0
     tr_chunks, va_chunks = [], []
     if checkpoint_dir and checkpoint_interval > 0:
@@ -441,11 +455,12 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
             try:
                 while done < n_epochs:
                     chunk = min(checkpoint_interval, n_epochs - done)
-                    carry, tr, va = train_bags_carry(
-                        loss_fn, metric_fn, optimizer, chunk,
-                        early_stop_window, convergence_threshold, carry,
-                        train_inputs, w_train_bags, val_inputs, w_val,
-                        grad_mask, n_batches)
+                    with obs_trace.span("train.program", steps=chunk):
+                        carry, tr, va = train_bags_carry(
+                            loss_fn, metric_fn, optimizer, chunk,
+                            early_stop_window, convergence_threshold,
+                            carry, train_inputs, w_train_bags, val_inputs,
+                            w_val, grad_mask, n_batches)
                     # keep the per-chunk error curves ON DEVICE — the
                     # host sync happens once after the loop, so chunk
                     # k+1 dispatches while k's errors are still in
@@ -466,23 +481,30 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
                 ckpt.flush_saves(reraise=False)
                 raise
         if tr_chunks:
-            train_errs = np.concatenate(
-                [pipe.host_fetch(t) for t in tr_chunks], axis=1)
-            val_errs = np.concatenate(
-                [pipe.host_fetch(v) for v in va_chunks], axis=1)
+            with obs_trace.span("train.wait"):
+                train_errs = np.concatenate(
+                    [pipe.host_fetch(t) for t in tr_chunks], axis=1)
+            with obs_trace.span("train.fetch"):
+                val_errs = np.concatenate(
+                    [pipe.host_fetch(v) for v in va_chunks], axis=1)
         else:  # resumed an already-finished run
             n_bags = w_train_bags.shape[0]
             train_errs = np.zeros((n_bags, 0), np.float32)
             val_errs = np.asarray(carry[2]["val"], np.float32).reshape(-1, 1)
     else:
-        carry, train_errs, val_errs = train_bags_carry(
-            loss_fn, metric_fn, optimizer, n_epochs, early_stop_window,
-            convergence_threshold, carry, train_inputs, w_train_bags,
-            val_inputs, w_val, grad_mask, n_batches)
-        train_errs = np.asarray(train_errs)
+        with obs_trace.span("train.program", steps=n_epochs):
+            carry, train_errs, val_errs = train_bags_carry(
+                loss_fn, metric_fn, optimizer, n_epochs, early_stop_window,
+                convergence_threshold, carry, train_inputs, w_train_bags,
+                val_inputs, w_val, grad_mask, n_batches)
+        # the first read of a result blocks until the program is done:
+        # the host waiting on the device, not host work
+        with obs_trace.span("train.wait"):
+            train_errs = np.asarray(train_errs)
+    with obs_trace.span("train.fetch"):
         val_errs = np.asarray(val_errs)
-    best = carry[2]
-    best_epoch = jnp.argmin(jnp.asarray(val_errs), axis=1)
+        best = carry[2]
+        best_epoch = jnp.argmin(jnp.asarray(val_errs), axis=1)
     return best["params"], train_errs, val_errs, best["val"], best_epoch
 
 
@@ -510,99 +532,107 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
         train_conf.params, input_dim=x.shape[1])
     n_bags = max(train_conf.baggingNum, 1)
 
-    if val_data is not None:
-        x_tr, y_tr, w_tr = x, y, w
-        x_v, y_v, w_v = val_data
-    else:
-        tr_mask, val_mask = split_validation(len(y), train_conf.validSetRate,
-                                             seed)
-        x_tr, y_tr, w_tr = x[tr_mask], y[tr_mask], w[tr_mask]
-        x_v, y_v, w_v = x[val_mask], y[val_mask], w[val_mask]
+    with obs_trace.span("train.job", family="nn", rows=int(x.shape[0]),
+                        steps=train_conf.numTrainEpochs, bags=n_bags):
+        with obs_trace.span("train.prepare"):
+            if val_data is not None:
+                x_tr, y_tr, w_tr = x, y, w
+                x_v, y_v, w_v = val_data
+            else:
+                tr_mask, val_mask = split_validation(
+                    len(y), train_conf.validSetRate, seed)
+                x_tr, y_tr, w_tr = x[tr_mask], y[tr_mask], w[tr_mask]
+                x_v, y_v, w_v = x[val_mask], y[val_mask], w[val_mask]
 
-    if spec.compute_dtype == "bfloat16":
-        # store the feature matrix itself in bf16: forward would cast
-        # on-chip anyway, but a bf16-resident x halves the HBM bytes
-        # every epoch actually streams (labels/weights stay f32 — they
-        # feed the f32 loss reduction)
-        x_tr = x_tr.astype(jnp.bfloat16)
-        x_v = x_v.astype(jnp.bfloat16)
+            if spec.compute_dtype == "bfloat16":
+                # store the feature matrix itself in bf16: forward would cast
+                # on-chip anyway, but a bf16-resident x halves the HBM bytes
+                # every epoch actually streams (labels/weights stay f32 — they
+                # feed the f32 loss reduction)
+                x_tr = x_tr.astype(jnp.bfloat16)
+                x_v = x_v.astype(jnp.bfloat16)
 
-    neg_only = train_conf.sampleNegOnly
-    if neg_only and spec.output_dim > 1:
-        # native multi-class y holds CLASS INDICES — "negative" (< 0.5)
-        # would mean class 0 only; the reference's sampleNegOnly is a
-        # binary/one-vs-all semantics (WDLWorker.sampleNegOnly checks
-        # isRegression/isOneVsAll), so warn-and-ignore like
-        # upSampleWeight does for multi-class
-        log.warning("sampleNegOnly ignored for native multi-class "
-                    "training (binary/one-vs-all semantics only)")
-        neg_only = False
-    bag_w = bagging_weights(len(y_tr), n_bags, train_conf.baggingSampleRate,
-                            train_conf.baggingWithReplacement, seed,
-                            labels=np.asarray(y_tr),
-                            stratified=train_conf.stratifiedSample,
-                            neg_only=neg_only) \
-        * w_tr[None, :]
+            neg_only = train_conf.sampleNegOnly
+            if neg_only and spec.output_dim > 1:
+                # native multi-class y holds CLASS INDICES — "negative" (< 0.5)
+                # would mean class 0 only; the reference's sampleNegOnly is a
+                # binary/one-vs-all semantics (WDLWorker.sampleNegOnly checks
+                # isRegression/isOneVsAll), so warn-and-ignore like
+                # upSampleWeight does for multi-class
+                log.warning("sampleNegOnly ignored for native multi-class "
+                            "training (binary/one-vs-all semantics only)")
+                neg_only = False
+            bag_w = bagging_weights(
+                len(y_tr), n_bags, train_conf.baggingSampleRate,
+                train_conf.baggingWithReplacement, seed,
+                labels=np.asarray(y_tr),
+                stratified=train_conf.stratifiedSample,
+                neg_only=neg_only) * w_tr[None, :]
 
-    key = jax.random.PRNGKey(seed)
-    bag_keys = jax.random.split(key, n_bags + 1)
-    if init_params is not None:
-        stacked = jax.tree.map(
-            lambda p: jnp.broadcast_to(p, (n_bags,) + p.shape), init_params)
-    else:
-        stacked = jax.vmap(lambda k: nn_mod.init_params(spec, k))(bag_keys[:-1])
+            key = jax.random.PRNGKey(seed)
+            bag_keys = jax.random.split(key, n_bags + 1)
+            if init_params is not None:
+                stacked = jax.tree.map(
+                    lambda p: jnp.broadcast_to(p, (n_bags,) + p.shape),
+                    init_params)
+            else:
+                stacked = jax.vmap(
+                    lambda k: nn_mod.init_params(spec, k))(bag_keys[:-1])
 
-    if grad_mask is None:
-        grad_mask = jax.tree.map(jnp.ones_like,
-                                 jax.tree.map(lambda l: l[0], stacked)
-                                 if init_params is None else init_params)
-        if fixed_layers:
-            # 1-based like the reference's FixedLayers: 1 freezes the
-            # input→hidden1 weight matrix (NNMaster.getFixedWights)
-            mask_list = []
-            for i, layer in enumerate(grad_mask):
-                z = 0.0 if (i + 1) in fixed_layers else 1.0
-                mask_list.append({k: jnp.full_like(v, z)
-                                  for k, v in layer.items()})
-            grad_mask = mask_list
-    else:
-        grad_mask = jax.tree.map(jnp.asarray, grad_mask)
+            if grad_mask is None:
+                grad_mask = jax.tree.map(
+                    jnp.ones_like, jax.tree.map(lambda l: l[0], stacked)
+                    if init_params is None else init_params)
+                if fixed_layers:
+                    # 1-based like the reference's FixedLayers: 1 freezes the
+                    # input→hidden1 weight matrix (NNMaster.getFixedWights)
+                    mask_list = []
+                    for i, layer in enumerate(grad_mask):
+                        z = 0.0 if (i + 1) in fixed_layers else 1.0
+                        mask_list.append({k: jnp.full_like(v, z)
+                                          for k, v in layer.items()})
+                    grad_mask = mask_list
+            else:
+                grad_mask = jax.tree.map(jnp.asarray, grad_mask)
 
-    optimizer = optimizer_from_params(train_conf.params)
-    early_window = train_conf.earlyStoppingRounds
+            optimizer = optimizer_from_params(train_conf.params)
+            early_window = train_conf.earlyStoppingRounds
 
-    def nn_loss(params, inputs, w, key):
-        x_, y_ = inputs
-        dkey = key if spec.dropout_rate > 0 else None
-        return nn_mod.loss_fn(spec, params, x_, y_, w, dkey)
+            def nn_loss(params, inputs, w, key):
+                x_, y_ = inputs
+                dkey = key if spec.dropout_rate > 0 else None
+                return nn_mod.loss_fn(spec, params, x_, y_, w, dkey)
 
-    def nn_metric(params, inputs, w):
-        x_, y_ = inputs
-        return nn_mod.mse(spec, params, x_, y_, w)
+            def nn_metric(params, inputs, w):
+                x_, y_ = inputs
+                return nn_mod.mse(spec, params, x_, y_, w)
 
-    # train#params MiniBatchRows: mini-batch SGD for data whose
-    # bags × activations exceed HBM full-batch (0 = full batch)
-    batch_rows = int(train_conf.get_param("MiniBatchRows", 0) or 0)
+            # train#params MiniBatchRows: mini-batch SGD for data whose
+            # bags × activations exceed HBM full-batch (0 = full batch)
+            batch_rows = int(train_conf.get_param("MiniBatchRows", 0) or 0)
 
-    best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
-        nn_loss, nn_metric, optimizer, train_conf.numTrainEpochs,
-        early_window if early_window and early_window > 0 else 0,
-        float(train_conf.convergenceThreshold or 0.0),
-        stacked, (x_tr, y_tr), bag_w,
-        (x_v, y_v), w_v,
-        bag_keys[:-1], grad_mask,
-        checkpoint_dir=checkpoint_dir,
-        checkpoint_interval=checkpoint_interval,
-        batch_rows=batch_rows, perm_seed=seed)
+        best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
+            nn_loss, nn_metric, optimizer, train_conf.numTrainEpochs,
+            early_window if early_window and early_window > 0 else 0,
+            float(train_conf.convergenceThreshold or 0.0),
+            stacked, (x_tr, y_tr), bag_w,
+            (x_v, y_v), w_v,
+            bag_keys[:-1], grad_mask,
+            checkpoint_dir=checkpoint_dir,
+            checkpoint_interval=checkpoint_interval,
+            batch_rows=batch_rows, perm_seed=seed)
 
-    params_per_bag = [
-        jax.tree.map(lambda p, i=i: np.asarray(p[i]), best_params)
-        for i in range(n_bags)]
-    res = TrainResult(
-        spec=spec, params_per_bag=params_per_bag,
-        train_errors=np.asarray(train_errs), val_errors=np.asarray(val_errs),
-        best_val=np.asarray(best_val), best_epoch=np.asarray(best_epoch),
-        wall_seconds=time.time() - t0)
+        with obs_trace.span("train.fetch"):
+            params_per_bag = [
+                jax.tree.map(lambda p, i=i: np.asarray(p[i]), best_params)
+                for i in range(n_bags)]
+            res = TrainResult(
+                spec=spec, params_per_bag=params_per_bag,
+                train_errors=np.asarray(train_errs),
+                val_errors=np.asarray(val_errs),
+                best_val=np.asarray(best_val),
+                best_epoch=np.asarray(best_epoch),
+                wall_seconds=time.time() - t0)
     log.info("train: %d bag(s), %d epochs, best val err %s in %.2fs",
              n_bags, train_conf.numTrainEpochs,
              np.round(res.best_val, 6).tolist(), res.wall_seconds)

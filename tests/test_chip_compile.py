@@ -79,6 +79,39 @@ def test_hist_kernel_compiles(one_chip, c, r, b, s):
         ((c, r), I32), ((r,), I32), ((r,), F32), ((r,), F32))
 
 
+def test_kernel_instructions_take_the_kernels_names(one_chip):
+    """A profiler trace names a device event by its HLO instruction, and
+    the chip's compiler names a Mosaic custom call after the innermost
+    scope of its `op_name`: the `pallas_call`'s `name=`. The benchmark's
+    kernel readers find the histogram kernels by `_level_histograms` in
+    that name and take the other custom calls of a tree build for the
+    split search (`benchmark/trace_reduce.tree_build_kernels`)."""
+    import re
+    from shifu_tpu.ops import pallas_hist, pallas_split
+    c, r, b, s = 28, 1_000_000, 64, 32
+
+    def level(bt, sl, g, h, m):
+        with jax.named_scope("hist"):
+            gh, hh = pallas_hist.level_histograms_pallas(bt, sl, g, h, s, b)
+        with jax.named_scope("split"):
+            return pallas_split.best_splits_pallas(gh, hh, m, 1.0, 5.0)
+
+    text = _compile(level, one_chip, ((c, r), I32), ((r,), I32), ((r,), F32),
+                    ((r,), F32), ((s, c), F32))
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)}
+    assert len(calls) == 2, calls
+    hist = [n for n in calls if "_level_histograms" in n]
+    assert len(hist) == 1 and hist[0].startswith("shifu_level_histograms.")
+    assert calls[hist[0]].endswith(
+        "hist/jit(_level_histograms_pallas)/shifu_level_histograms/"
+        "pallas_call")
+    other = [n for n in calls if n not in hist]
+    assert other[0].startswith("shifu_best_splits.")
+    assert calls[other[0]].endswith("split/shifu_best_splits/pallas_call")
+
+
 @pytest.mark.parametrize("c,r,b,s", HIST_CASES)
 def test_fused_hist_kernel_compiles(one_chip, c, r, b, s):
     from shifu_tpu.ops import pallas_hist

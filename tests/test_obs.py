@@ -114,7 +114,11 @@ def test_disabled_records_nothing_and_writes_no_files(tmp_path):
     with obs_trace.trace_run(str(tmp_path), "train") as run:
         assert run is None
         assert not obs_trace.active()
-        assert obs_trace.span("input.h2d") is obs_trace._NOOP
+        # disabled = a bare profiler annotation, never the ring's span
+        sp = obs_trace.span("input.h2d")
+        assert not isinstance(sp, obs_trace._Span)
+        with sp:
+            pass
         assert obs_trace.record_span("input.h2d", 0.0, 1.0) is None
         assert obs_trace.open_spans() == []
     assert not os.path.exists(os.path.join(str(tmp_path), "tmp", "trace"))
