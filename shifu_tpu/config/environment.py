@@ -246,8 +246,6 @@ _declare("SHIFU_TPU_HIST_VMEM_MB", "int", 64,
          "VMEM budget for pallas histogram tiling — the tiles are "
          "derived from it AND the kernels are compiled with it as "
          "their VMEM limit")
-_declare("SHIFU_TPU_GBT_ROUTE", "str", "gather",
-         "GBT split-feature routing: gather | onehot")
 _declare("SHIFU_TPU_GBT_SCAN_GROUP", "int", 0,
          "trees per lax.scan group in GBT build; 0 = no grouping")
 _declare("SHIFU_TPU_NN_COMPUTE", "str", "float32",

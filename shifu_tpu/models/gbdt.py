@@ -527,15 +527,38 @@ def _final_leaves(cfg: TreeConfig, tree, g_hist, h_hist):
     return tree
 
 
-def _route_mode() -> str:
-    """SHIFU_TPU_GBT_ROUTE = gather | onehot. The per-row split-feature
-    lookup can lower as a cross-sublane gather (take_along_axis) or as
-    a one-hot multiply-reduce over the feature axis (C·R f32 FMA on
-    the VPU, fusable, no gather). tools/profile_gbt.py A/Bs both on
-    the real backend. Read at TRACE time — set it before the first
-    build in a process (an env flip later hits the jit cache)."""
-    import os
-    return knob_str("SHIFU_TPU_GBT_ROUTE").lower()
+def _select(hit, values):
+    """The value that `hit` marks along axis 0, bit for bit; 0 (False)
+    where it marks none. A select and an integer sum over that axis,
+    which XLA fuses into one streamed reduce: no gather op, and float32
+    travels as its bits (a float sum against zeros would turn -0.0
+    into 0.0)."""
+    is_f32 = values.dtype == jnp.float32
+    bits = (jax.lax.bitcast_convert_type(values, jnp.int32) if is_f32
+            else values.astype(jnp.int32))
+    picked = jnp.sum(jnp.where(hit, bits, 0), axis=0)
+    if is_f32:
+        return jax.lax.bitcast_convert_type(picked, jnp.float32)
+    return picked.astype(values.dtype)
+
+
+def _lookup(table, idx):
+    """table[idx] for a small (S,) or (S, K) table and (R,) ids, by a
+    compare of every id against the S slots (a per-row gather from a
+    511-entry table ran at ~100 M rows/s on the v5e; PERF.md, PR 25).
+    An id outside [0, S) reads 0. Returns (R,), or (K, R) for a (S, K)
+    table: rows stay on the lane axis."""
+    s = table.shape[0]
+    slots = jnp.arange(s, dtype=jnp.int32).reshape((s,) + (1,) * table.ndim)
+    return _select(idx == slots, table[..., None])
+
+
+def _pick_row(matT, idx):
+    """matT[idx[r], r] for a (C, R) matrix and (R,) row ids, the
+    gather-free twin of take_along_axis over the C axis: one read of
+    the matrix. An id outside [0, C) reads 0."""
+    rows = jnp.arange(matT.shape[0], dtype=jnp.int32)
+    return _select(idx[None, :] == rows[:, None], matT)
 
 
 def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int):
@@ -549,40 +572,44 @@ def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int):
 def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
                     level_offset, n_level):
     """_route_level core with level_offset/n_level as values rather
-    than a static depth — the same arithmetic op-for-op, so the
-    fori_loop scan builder (which traces them) routes bitwise like
-    the per-level builder."""
-    node_feat = tree["feature"][node_of_row]               # (R,)
-    node_bin = tree["bin"][node_of_row]
-    node_dl = tree["default_left"][node_of_row]
-    feat_idx = jnp.maximum(node_feat, 0)
+    than a static depth: the scan builder traces them, and then the
+    level's slots are the n_max = 2^max_depth it could hold at most.
+    Both per-row lookups (the split of the row's node, the row's bin in
+    that split's feature) are selects (`_lookup`, `_pick_row`), all in
+    integers: exact, so every builder routes bitwise alike. Rows
+    outside the level (parked at a leaf, -1 pad rows) match no slot,
+    read feature -1 and stay where they are."""
+    n_slots = n_level if isinstance(n_level, int) else 2 ** cfg.max_depth
+    slots = jnp.arange(n_slots, dtype=jnp.int32)
+    level_ids = jnp.where(slots < n_level, level_offset + slots, -1)
+
+    def of_node(table):
+        # the level's slots of the table first (a dynamic_slice batches
+        # into a gather under vmap; a slot past the level reads 0),
+        # then each row's slot of those
+        return _lookup(_lookup(table, level_ids), node_of_row - level_offset)
+
+    # feature + 1, so that a row no slot matched reads feature -1
+    node_feat = of_node(tree["feature"] + 1) - 1
+    node_bin = of_node(tree["bin"])
+    node_dl = of_node(tree["default_left"])
     if isinstance(binsT, FusedBins):
-        # bin the routed feature's raw value on the fly: one (R,)
-        # gather of values + an (R, K) boundary compare — no (C, R)
-        # bin matrix exists on the fused path
-        vals = jnp.take_along_axis(binsT.valuesT, feat_idx[None, :],
-                                   axis=0)[0]              # (R,)
-        cuts = binsT.cuts[feat_idx]                        # (R, K)
-        row_bin = jnp.sum(vals[:, None] >= cuts,
-                          axis=1).astype(jnp.int32)
+        # bin the routed feature's raw value on the fly: the row's
+        # value and its feature's K cuts, then a boundary compare; no
+        # (C, R) bin matrix exists on the fused path
+        vals = _pick_row(binsT.valuesT, node_feat)         # (R,)
+        cuts = _lookup(binsT.cuts, node_feat)              # (K, R)
+        row_bin = jnp.sum(vals[None, :] >= cuts,
+                          axis=0).astype(jnp.int32)
         row_bin = jnp.minimum(row_bin, cfg.n_bins - 2)
         row_bin = jnp.where(jnp.isnan(vals), cfg.n_bins - 1, row_bin)
-    elif _route_mode() == "onehot":
-        # (C, R) one-hot × bins, reduced over C: bin ids ≤ 2^24 are
-        # exact in f32, and XLA fuses the product into the reduction
-        sel = jax.nn.one_hot(feat_idx, binsT.shape[0],
-                             dtype=jnp.float32, axis=0)
-        row_bin = jnp.sum(sel * binsT.astype(jnp.float32),
-                          axis=0).astype(jnp.int32)
     else:
-        row_bin = jnp.take_along_axis(binsT, feat_idx[None, :],
-                                      axis=0)[0]
+        row_bin = _pick_row(binsT, node_feat)
     miss = row_bin == (cfg.n_bins - 1)
     go_left = jnp.where(miss, node_dl, row_bin <= node_bin)
-    active = (node_feat >= 0) & (node_of_row >= level_offset) & \
-             (node_of_row < level_offset + n_level)
-    return jnp.where(
-        active, 2 * node_of_row + jnp.where(go_left, 1, 2), node_of_row)
+    return jnp.where(node_feat >= 0,
+                     2 * node_of_row + jnp.where(go_left, 1, 2),
+                     node_of_row)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract",
@@ -989,14 +1016,14 @@ def _pace_dispatch(x) -> None:
 def _gbt_round_core(cfg: TreeConfig, binsT, y, weights, pred_raw,
                     feature_mask, mesh=None, subtract=None):
     grad, hess = gbt_gradients(y, pred_raw, weights, cfg.loss)
-    # growth already landed every row on its leaf: one (R,) gather of
+    # growth already landed every row on its leaf: one (R,) lookup of
     # leaf_value replaces a full predict_trees re-walk (max_depth
-    # gathers over the (C, R) bin matrix) for the boosting update
+    # passes over the (C, R) bin matrix) for the boosting update
     tree, node_of_row = build_tree(cfg, binsT, grad, hess, feature_mask,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
     with jax.named_scope("leaf"):
-        contrib = tree["leaf_value"][node_of_row]
+        contrib = _lookup(tree["leaf_value"], node_of_row)
         return tree, pred_raw + cfg.learning_rate * contrib
 
 
@@ -1177,7 +1204,7 @@ def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w_T, pred_T,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
     with jax.named_scope("leaf"):
-        contrib_T = jax.vmap(lambda tr, n: tr["leaf_value"][n]
+        contrib_T = jax.vmap(lambda tr, n: _lookup(tr["leaf_value"], n)
                              )(trees_T, node_T)
         return trees_T, pred_T + cfg.learning_rate * contrib_T
 

@@ -121,6 +121,50 @@ def test_fused_hist_kernel_compiles(one_chip, c, r, b, s):
         ((r,), F32))
 
 
+# (columns, rows, max depth, bins, trees): the benchmark's gbt-higgs cell
+# (256 slots a level in the scan builder), a 3-tree lockstep forest
+# under vmap, and a wide table
+@pytest.mark.parametrize("c,r,depth,b,trees", [
+    (28, 2 ** 24, 8, 64, 1), (28, 2 ** 22, 8, 64, 3),
+    (1000, 2 ** 20, 6, 1024, 1)])
+def test_route_level_streams_without_gather(one_chip, c, r, depth, b, trees):
+    """One level of routing as the scan builder calls it (offset and
+    width traced, 2^depth slots) and the leaf-value lookup: the chip's
+    compiler is given no gather, and no (slots, rows) or (columns, rows)
+    intermediate reaches HBM: the program's temporaries stay a few (rows,)
+    vectors. A per-row gather ran at 40-100 M rows/s on the v5e and was
+    80% of a tree (PERF.md, PR 25)."""
+    import re
+    from shifu_tpu.models import gbdt
+    cfg = gbdt.TreeConfig(max_depth=depth, n_bins=b)
+
+    def one(tree, binsT, node, d):
+        node = gbdt._route_level_at(cfg, tree, binsT, node,
+                                    jnp.left_shift(1, d) - 1,
+                                    jnp.left_shift(1, d))
+        return node, gbdt._lookup(tree["leaf_value"], node)
+
+    def level(tree, binsT, node, d):
+        if trees == 1:
+            return one(tree, binsT, node, d)
+        return jax.vmap(lambda t, n: one(t, binsT, n, d))(tree, node)
+
+    lead = () if trees == 1 else (trees,)
+    n = cfg.n_nodes
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    tree = {"feature": shape(lead + (n,), I32), "bin": shape(lead + (n,), I32),
+            "default_left": shape(lead + (n,), jnp.bool_),
+            "leaf_value": shape(lead + (n,), F32)}
+    compiled = jax.jit(level).lower(
+        tree, shape((c, r), I32), shape(lead + (r,), I32),
+        shape((), I32)).compile()
+    assert not re.search(r"= \S+ gather\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * 4 * trees * r
+
+
 # (nodes, columns, bins): a level of the smoke's trees, the root, a
 # 20-tree lockstep forest level (20·32), 600 columns, 256 bins
 @pytest.mark.parametrize("n,c,b", [(32, 28, 64), (1, 28, 64),

@@ -108,26 +108,133 @@ def test_gbt_missing_direction(rng):
     assert p[miss].mean() > 0.8  # learned that missing → positive
 
 
-def test_route_level_onehot_matches_gather(rng, monkeypatch):
-    """SHIFU_TPU_GBT_ROUTE=onehot (one-hot multiply-reduce feature
-    lookup) must route every row exactly like the gather formulation
-    — same child ids for any tree state."""
-    import jax.numpy as jnp
-    from shifu_tpu.models.gbdt import TreeConfig, _route_level
-    cfg = TreeConfig(max_depth=4, n_bins=64, learning_rate=0.1,
-                     loss="log")
-    c, r = 7, 5000
-    binsT = jnp.asarray(rng.integers(0, 64, (c, r)).astype(np.int32))
-    tree = {"feature": jnp.asarray(
-                rng.integers(-1, c, 31).astype(np.int32)),
-            "bin": jnp.asarray(rng.integers(0, 63, 31).astype(np.int32)),
-            "default_left": jnp.asarray(rng.random(31) < 0.5)}
-    node = jnp.asarray(rng.integers(3, 7, r).astype(np.int32))
-    monkeypatch.setenv("SHIFU_TPU_GBT_ROUTE", "gather")
-    a = np.asarray(_route_level(cfg, tree, binsT, node, 2))
-    monkeypatch.setenv("SHIFU_TPU_GBT_ROUTE", "onehot")
-    b = np.asarray(_route_level(cfg, tree, binsT, node, 2))
-    np.testing.assert_array_equal(a, b)
+def _numpy_route(tree, bins, node, offset, n_level, n_bins):
+    """Plain routing of one level: rows at a node of the level whose
+    feature is >= 0 go to a child, every other row stays."""
+    feature, sbin = np.asarray(tree["feature"]), np.asarray(tree["bin"])
+    default_left = np.asarray(tree["default_left"])
+    out = node.copy()
+    for r in np.flatnonzero((node >= offset) & (node < offset + n_level)):
+        n = node[r]
+        if feature[n] < 0:
+            continue
+        b = bins[feature[n], r]
+        left = default_left[n] if b == n_bins - 1 else b <= sbin[n]
+        out[r] = 2 * n + (1 if left else 2)
+    return out
+
+
+def _route_case(rng, name):
+    """(cfg, tree, bins (C, R), what routing is given for them, node,
+    depth) of one routing case; `tree`/`node` of "vmap" hold 3 trees."""
+    depth, c, n_bins, r, max_depth = 2, 7, 64, 3000, 4
+    if name == "wide":          # past bfloat16's exact integers
+        c, n_bins = 300, 1024
+    cfg = TreeConfig(max_depth=max_depth, n_bins=n_bins)
+    lead = (3,) if name == "vmap" else ()
+    tree = {"feature": rng.integers(-1, c, lead + (cfg.n_nodes,)),
+            "bin": rng.integers(0, n_bins - 1, lead + (cfg.n_nodes,)),
+            "default_left": rng.random(lead + (cfg.n_nodes,)) < 0.5}
+    offset, n_level = 2 ** depth - 1, 2 ** depth
+    # rows on the level, parked above it and (never in a build) below it
+    node = rng.integers(0, cfg.n_nodes, lead + (r,))
+    if name == "parked":        # the whole level but one node is leaves
+        tree["feature"][offset + 1:offset + n_level] = -1
+        node = rng.integers(1, offset + n_level, r)
+    if name == "pad_rows":
+        node[rng.random(r) < 0.3] = -1
+    bins = rng.integers(0, n_bins - 1, (c, r))
+    if name in ("missing", "fused"):
+        bins[rng.random((c, r)) < 0.3] = n_bins - 1
+    given = bins.astype(np.int32)
+    if name == "fused":         # raw values between their bin's cuts
+        cuts = np.sort(rng.normal(size=(c, n_bins - 2)), axis=1)
+        edges = np.concatenate([cuts[:, :1] - 1, cuts, cuts[:, -1:] + 1], 1)
+        mid = ((edges[:, :-1] + edges[:, 1:]) / 2).astype(np.float32)
+        vals = np.take_along_axis(mid, np.minimum(bins, n_bins - 2), axis=1)
+        vals[bins == n_bins - 1] = np.nan
+        given = gbdt.FusedBins(jnp.asarray(vals),
+                               jnp.asarray(cuts.astype(np.float32)))
+    tree = {k: v.astype(bool if k == "default_left" else np.int32)
+            for k, v in tree.items()}
+    return cfg, tree, bins, given, node.astype(np.int32), depth
+
+
+@pytest.mark.parametrize("call", ["static", "traced"])
+@pytest.mark.parametrize("name", ["mixed", "parked", "pad_rows", "missing",
+                                  "wide", "vmap", "fused"])
+def test_route_level_matches_numpy_walk(rng, name, call):
+    """One level of routing against a plain numpy walk of the same
+    tree: the per-level builder's static call and the scan builder's
+    (offset and width traced, slots at n_max), rows parked at leaves
+    and at feature -1, -1 pad rows, the missing bin with both default
+    directions, 300 columns by 1024 bins, three trees under vmap, and
+    raw values + cuts (FusedBins)."""
+    cfg, tree, bins, given, node, depth = _route_case(rng, name)
+    offset, n_level = 2 ** depth - 1, 2 ** depth
+    if name == "missing":
+        on_level = (node >= offset) & (node < offset + n_level)
+        for dl in (False, True):
+            rows = on_level & (tree["default_left"][node] == dl)
+            assert (bins[tree["feature"][node], np.arange(len(node))][rows]
+                    == cfg.n_bins - 1).any()
+    jtree = {k: jnp.asarray(v) for k, v in tree.items()}
+    if not isinstance(given, gbdt.FusedBins):
+        given = jnp.asarray(given)
+
+    def route(t, n, off, width):
+        if call == "static":
+            return gbdt._route_level(cfg, t, given, n, depth)
+        return gbdt._route_level_at(cfg, t, given, n, off, width)
+
+    if name == "vmap":
+        fn = jax.jit(lambda t, n, o, w: jax.vmap(
+            lambda t1, n1: route(t1, n1, o, w))(t, n))
+        want = np.stack([_numpy_route({k: v[i] for k, v in tree.items()},
+                                      bins, node[i], offset, n_level,
+                                      cfg.n_bins) for i in range(3)])
+    else:
+        fn = jax.jit(route)
+        want = _numpy_route(tree, bins, node, offset, n_level, cfg.n_bins)
+    got = np.asarray(fn(jtree, jnp.asarray(node), jnp.int32(offset),
+                        jnp.int32(n_level)))
+    assert (want != node).any() and (want == node).any()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("name", ["mixed", "vmap", "fused"])
+def test_route_lowers_without_gather(rng, name):
+    """No gather op in the lowered routing (traced offset and width, as
+    the scan builder calls it), alone, under vmap over trees and on
+    FusedBins; nor in the leaf-value lookup beside it."""
+    cfg, tree, _, given, node, depth = _route_case(rng, name)
+
+    def route(t, n, off, width):
+        return gbdt._route_level_at(cfg, t, given, n, off, width)
+
+    fn = route if name != "vmap" else (lambda t, n, o, w: jax.vmap(
+        lambda t1, n1: route(t1, n1, o, w))(t, n))
+    text = jax.jit(fn).lower(tree, node, jnp.int32(3), jnp.int32(4)).as_text()
+    assert "gather" not in text
+    leaf = jax.jit(gbdt._lookup).lower(
+        jnp.zeros(cfg.n_nodes, jnp.float32), node.reshape(-1)).as_text()
+    assert "gather" not in leaf
+
+
+def test_lookup_returns_the_float_bit_for_bit():
+    """`_lookup` is a select, not arithmetic: -0.0, inf and NaN come
+    back with their bits; an id outside the table reads 0."""
+    table = np.array([-0.0, 1.5, np.inf, np.nan, -3e-39], np.float32)
+    idx = np.array([0, 3, 4, 2, 1, -1, 5, 0], np.int32)
+    got = np.asarray(gbdt._lookup(jnp.asarray(table), jnp.asarray(idx)))
+    want = np.where((idx >= 0) & (idx < 5), table[np.clip(idx, 0, 4)],
+                    np.float32(0))
+    assert got.view(np.int32).tolist() == want.view(np.int32).tolist()
+    cuts = np.array([[0.5, np.inf], [-1.0, 2.0]], np.float32)
+    got2 = np.asarray(gbdt._lookup(jnp.asarray(cuts),
+                                   jnp.asarray([1, 0, -1], np.int32)))
+    np.testing.assert_array_equal(got2, [[-1.0, 0.5, 0.0],
+                                         [2.0, np.inf, 0.0]])
 
 
 def test_rf_vmapped_forest(rng):

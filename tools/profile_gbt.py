@@ -10,14 +10,14 @@ Usage: python tools/profile_gbt.py [rows] [trees]
 """
 import json
 import os
-
-from shifu_tpu.config.environment import knob_bool, knob_raw
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
+
+from shifu_tpu.config.environment import knob_bool  # noqa: E402
 
 
 def main():
@@ -94,28 +94,16 @@ def main():
           lambda: hists_all_levels(binsT, grad, hess),
           lambda: float(hists_all_levels(binsT, grad, hess)))
 
-    # (c) routing: all levels' row advancement — both formulations
-    # (env is read at trace time; tracing two distinct jits here keeps
-    # the A/B inside one process)
-    caller_route = knob_raw("SHIFU_TPU_GBT_ROUTE")
-    for mode in ("gather", "onehot"):
-        os.environ["SHIFU_TPU_GBT_ROUTE"] = mode
+    # (c) routing: all levels' row advancement
+    @jax.jit
+    def route_all(b):
+        n = jnp.zeros(rows, jnp.int32)
+        for d in range(depth):
+            n = gbdt._route_level(cfg, tree0, b, n, d)
+        return n.sum()
 
-        # fresh function object per mode → its own jit cache; the env
-        # is read at trace time inside _route_level
-        @jax.jit
-        def route_all(b):
-            n = jnp.zeros(rows, jnp.int32)
-            for d in range(depth):
-                n = gbdt._route_level(cfg, tree0, b, n, d)
-            return n.sum()
-
-        timed(f"route_levels_{mode}_s", lambda: route_all(binsT),
-              lambda: float(route_all(binsT)))  # lint: disable=host-sync-in-hot-loop -- profiling: the scalar fetch is the sync
-    if caller_route is None:
-        os.environ.pop("SHIFU_TPU_GBT_ROUTE", None)
-    else:
-        os.environ["SHIFU_TPU_GBT_ROUTE"] = caller_route
+    timed("route_levels_s", lambda: route_all(binsT),
+          lambda: float(route_all(binsT)))
 
     # (d) split selection on depth-6-sized histograms (64 slots)
     g64 = jax.random.normal(key, (64, cols, n_bins))
@@ -130,11 +118,11 @@ def main():
     timed("best_splits64_s", lambda: splits(g64, h64),
           lambda: float(splits(g64, h64)))
 
-    # (e) gradient recompute + leaf gather (the boosting glue)
+    # (e) gradient recompute + leaf lookup (the boosting glue)
     @jax.jit
     def glue(pred):
         g, h = gbdt.gbt_gradients(y, pred, w, cfg.loss)
-        contrib = tree0["leaf_value"][nodes_per_level[-1]]
+        contrib = gbdt._lookup(tree0["leaf_value"], nodes_per_level[-1])
         return (pred + cfg.learning_rate * contrib).sum() + g.sum() + h.sum()
 
     timed("glue_s", lambda: glue(jnp.zeros(rows)),
