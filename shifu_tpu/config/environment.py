@@ -197,7 +197,7 @@ _declare("SHIFU_TPU_MESH_MODEL", "int", 1,
          "devices on the 'model' mesh axis (WDL/MTL table sharding)")
 _declare("SHIFU_TPU_MESH_RULES", "str", None,
          "logical→physical axis overrides 'logical=axis[,...]' "
-         "(empty axis = replicate); unset = rows=data, hidden/cat/"
+         "(empty axis = replicate); unset = rows=data, hidden/vocab/"
          "task=model")
 _declare("SHIFU_TPU_PREEMPT_GRACE_S", "float", 15.0,
          "after observing a peer's preempt marker inside a watched "

@@ -112,12 +112,16 @@ SPAN_FAMILIES: Dict[str, Tuple[str, ...]] = {
     # training entry (train_nn, the WDL/MTL resident trainers,
     # build_gbt, build_gbt_bagged, build_rf) with its phases nested
     # inside on the calling thread: prepare (host work before anything
-    # is placed), place (uploads and the fresh carry), program (the
+    # is placed), shuffle (mini-batch mode only: the rows put in the
+    # job seed's order, padded and cut into batches, on the host for
+    # host inputs and on the device for device inputs), place (uploads
+    # and the fresh carry), program (the
     # call into the jitted program until it returns to Python: trace,
     # lower, cache read or compile, dispatch), wait (the first
     # blocking read of its results: the host waiting on the device),
     # fetch (the remaining device→host copies and result assembly)
-    "train": ("job", "prepare", "place", "program", "wait", "fetch"),
+    "train": ("job", "prepare", "shuffle", "place", "program", "wait",
+              "fetch"),
     # the one sanctioned device→host sync, data/pipeline.host_fetch
     "host": ("sync",),
 }
@@ -132,12 +136,19 @@ ANNOTATION_PREFIX = "shifu:"
 # the loss; jax marks its backward ops `transpose(jvp(..))` itself),
 # update (optimizer update and apply), validate (the validation
 # metric), select (best-epoch and early-stop bookkeeping), and inside
-# them one `layer<i>` a layer of `models/nn.forward`. A boosting round
+# them one `layer<i>` a layer of `models/nn.forward`. Wide-and-deep
+# (`models/wdl.forward`, inside forward_loss and validate): embed (the
+# embedding lookup, and in the backward pass its gradient's
+# accumulation into the table), wide (the wide table's lookup and the
+# dense linear term), deep (the MLP over [dense ‖ embeddings]); inside
+# update, table_update (the optimizer's pass over the two tables). A
+# boosting round
 # (`models/gbdt.py`): gradients, hist (level histograms and sibling
 # subtraction), split (best splits and their fold into the tree),
 # route (rows to their child nodes), leaf (final leaf values, the
 # per-row leaf gather and the prediction update).
 DEVICE_SCOPES = ("forward_loss", "update", "validate", "select",
+                 "embed", "wide", "deep", "table_update",
                  "gradients", "hist", "split", "route", "leaf")
 _LAYER_SCOPE = re.compile(r"layer\d+")
 _WORD = re.compile(r"[A-Za-z_]\w*")

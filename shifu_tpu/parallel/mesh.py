@@ -51,7 +51,7 @@ _MESH_CACHE: dict = {}
 _DEFAULT_RULES: Dict[str, Optional[str]] = {
     "rows": "data",      # feature-matrix rows (the worker-split axis)
     "hidden": "model",   # MLP hidden units (Megatron split)
-    "cat": "model",      # WDL per-column embedding/wide tables
+    "vocab": "model",    # WDL embedding/wide table rows (all columns' ids)
     "task": "model",     # MTL per-task head rows
 }
 
@@ -61,7 +61,7 @@ class MeshRules:
     resolves logical tensor-dimension names to physical axis names
     (unknown names resolve to None = replicated); `rules.spec(...)`
     wraps the resolution in a PartitionSpec. Overrides come from
-    SHIFU_TPU_MESH_RULES ("hidden=,cat=data" — an empty right side
+    SHIFU_TPU_MESH_RULES ("hidden=,vocab=data" — an empty right side
     replicates that logical axis)."""
 
     def __init__(self, overrides: Optional[Dict[str, Optional[str]]] = None):
@@ -449,23 +449,29 @@ def _model_spec(mesh: Mesh, axis_len: int, spec: P,
 
 def wdl_train_shardings(mesh: Mesh, params, megatron_deep: bool = False
                         ) -> dict:
-    """WDL layout (one UNSTACKED parameter set): the per-column
-    embedding + wide tables — the memory hog for vocab-heavy configs,
-    (n_cat, vocab, embed) floats that data-parallel would replicate
-    per chip — shard over 'model' on the categorical-column axis. The
-    deep MLP stays replicated in the product trainer (a few hundred
-    hidden units buy nothing from tensor parallelism and Megatron
-    splits would add two collectives per step); `megatron_deep=True`
-    (the dryrun's compile certification) splits it anyway."""
+    """WDL layout (one UNSTACKED parameter set): the ragged embedding +
+    wide tables — the memory hog for vocab-heavy configs, (ΣV, embed)
+    floats that data-parallel would replicate per chip — shard over
+    'model' on their ROW axis: every chip holds a slice of every
+    column's ids, so a few huge columns among many small ones (Criteo's
+    three largest hold 76% of the rows) still balance, which a split by
+    column cannot. The deep MLP stays replicated in the product trainer
+    (a few hundred hidden units buy nothing from tensor parallelism and
+    Megatron splits would add two collectives per step);
+    `megatron_deep=True` (the dryrun's compile certification) splits it
+    anyway. Each table is held to its own length (`embed` is lane-packed,
+    so the two differ): `wdl.pad_tables(params, model-axis size)` makes
+    both divide, and a table that does not replicates with a warning."""
     rules = default_rules()
     out = {}
     if "embed" in params:
-        nc = int(np.shape(params["embed"])[0])
-        out["embed"] = _model_spec(mesh, nc,
-                                   rules.spec("cat", "vocab", "embed"),
-                                   "WDL embed (n_cat)")
-        out["wide_cat"] = _model_spec(mesh, nc, rules.spec("cat", "vocab"),
-                                      "WDL wide_cat (n_cat)")
+        out["embed"] = _model_spec(mesh, int(np.shape(params["embed"])[0]),
+                                   rules.spec("vocab", "embed"),
+                                   "WDL embed (packed table rows)")
+        out["wide_cat"] = _model_spec(mesh,
+                                      int(np.shape(params["wide_cat"])[0]),
+                                      rules.spec("vocab"),
+                                      "WDL wide_cat (table rows)")
     out["wide_dense"] = NamedSharding(mesh, P())
     out["wide_bias"] = NamedSharding(mesh, P())
     out["deep"] = mlp_param_shardings(mesh, len(params["deep"])) \

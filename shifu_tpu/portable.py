@@ -193,17 +193,22 @@ def wdl_forward(spec: Dict[str, Any], params: Dict[str, Any],
                 idx: Optional[np.ndarray]) -> np.ndarray:
     dense_dim = int(spec["dense_dim"])
     n_cat = int(spec["n_cat"])
-    vocab = int(spec["vocab_size"])
     n = dense.shape[0] if dense_dim else idx.shape[0]
     logit = np.zeros(n, np.float32)
     deep_in = [np.asarray(dense, np.float32)] if dense_dim else []
     if n_cat:
-        cols = np.arange(n_cat)[None, :]
-        safe = np.clip(idx, 0, vocab - 1)
+        # ragged tables: column c's rows start at offset[c]; a file from
+        # before them holds one `vocab_size` and stacked tables, which
+        # are equal columns end to end
+        sizes = np.asarray(spec.get("vocab_sizes")
+                           or [spec["vocab_size"]] * n_cat, np.int64)
+        rows = np.clip(idx, 0, sizes - 1) + (np.cumsum(sizes) - sizes)
+        embed = np.asarray(params["embed"])
+        embed = embed.reshape(-1, embed.shape[-1])
         if spec.get("wide_enable", True):
-            logit = logit + params["wide_cat"][cols, safe].sum(axis=1)
-        emb = params["embed"][cols, safe]
-        deep_in.append(emb.reshape(n, -1))
+            logit = logit + np.asarray(
+                params["wide_cat"]).reshape(-1)[rows].sum(axis=1)
+        deep_in.append(embed[rows].reshape(n, -1))
     if spec.get("wide_enable", True) and dense_dim:
         logit = logit + dense @ params["wide_dense"]
     logit = logit + params["wide_bias"]

@@ -7,25 +7,148 @@ from __future__ import annotations
 import logging
 import os
 import time
+from typing import Any, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+import optax
 
+from shifu_tpu.config.model_config import ModelTrainConf
 from shifu_tpu.models import wdl
 from shifu_tpu.models.spec import save_model
 from shifu_tpu.obs import trace as obs_trace
+from shifu_tpu.parallel import mesh as mesh_mod
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import optimizer_from_params
-from shifu_tpu.train.trainer import (bagging_weights, split_validation,
-                                     train_bags)
+from shifu_tpu.train.trainer import (TrainResult, bagging_weights,
+                                     split_validation, train_bags)
 
 log = logging.getLogger("shifu_tpu")
 
 
-def run_wdl(ctx: ProcessorContext, seed: int = 12306):
+TABLE_LEAVES = ("embed", "wide_cat")
+
+
+def _tables_scoped(optimizer: optax.GradientTransformation
+                   ) -> optax.GradientTransformation:
+    """The same optimizer with the two tables' update under the device
+    scope `table_update`, so a trace tells the table pass from the MLP's.
+    Every `Propagation` rule is per element, so the two halves update
+    exactly as the whole did."""
+    def scoped_update(grads, state, params=None):
+        with jax.named_scope("table_update"):
+            return optimizer.update(grads, state, params)
+
+    def labels(params):
+        return {k: jax.tree.map(
+            lambda _: "tables" if k in TABLE_LEAVES else "rest", v)
+            for k, v in params.items()}
+
+    return optax.multi_transform(
+        {"tables": optax.GradientTransformation(optimizer.init,
+                                                scoped_update),
+         "rest": optimizer}, labels)
+
+
+def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,
+              seed: int = 12306,
+              val_data: Optional[Tuple[Any, Any, Any, Any]] = None
+              ) -> TrainResult:
+    """Train `baggingNum` wide-and-deep models at once over resident
+    rows: what `run_wdl` calls once `norm`'s matrix is loaded, as
+    `train_nn` is to `processor/train.py`. `dense` (N, Dd) float, `idx`
+    (N, Cc) int32 per-column ids, `y`/`w` (N,), host or device arrays;
+    `vocab_sizes` the table rows of each categorical column, missing
+    slot included (`norm`'s `indexVocabSizes`). `val_data` = (dense,
+    idx, y, w) overrides the random validSetRate split. train#params
+    MiniBatchRows > 0 trains in shuffled mini-batches (see
+    `train_bags`); device inputs then stay on the device."""
     t0 = time.time()
+    spec = wdl.WDLSpec.from_train_params(train_conf.params, dense.shape[1],
+                                         idx.shape[1], vocab_sizes)
+    n_bags = max(train_conf.baggingNum, 1)
+    batch_rows = int(train_conf.get_param("MiniBatchRows", 0) or 0)
+    n_rows = int(y.shape[0])
+    with obs_trace.span("train.job", family="wdl", rows=n_rows,
+                        steps=train_conf.numTrainEpochs, bags=n_bags,
+                        batches=(-(-n_rows // batch_rows)
+                                 if 0 < batch_rows < n_rows else 1),
+                        lookups=n_rows * spec.n_cat
+                        * train_conf.numTrainEpochs):
+        with obs_trace.span("train.prepare"):
+            if val_data is not None:
+                d_tr, i_tr, y_tr, w_tr = dense, idx, y, w
+                d_v, i_v, y_v, w_v = val_data
+            else:
+                tr_mask, val_mask = split_validation(
+                    n_rows, train_conf.validSetRate, seed)
+                d_tr, i_tr, y_tr, w_tr = (a[tr_mask]
+                                          for a in (dense, idx, y, w))
+                d_v, i_v, y_v, w_v = (a[val_mask]
+                                      for a in (dense, idx, y, w))
+            by_label = train_conf.stratifiedSample or train_conf.sampleNegOnly
+            bag_w = bagging_weights(int(y_tr.shape[0]), n_bags,
+                                    train_conf.baggingSampleRate,
+                                    train_conf.baggingWithReplacement, seed,
+                                    labels=(np.asarray(y_tr) if by_label
+                                            else None),
+                                    stratified=train_conf.stratifiedSample,
+                                    neg_only=train_conf.sampleNegOnly) \
+                * w_tr[None, :]
+
+            # rows shard over 'data'; with SHIFU_TPU_MESH_MODEL > 1 the
+            # embedding + wide tables additionally shard over 'model' by
+            # row (the vocab-heavy leaves that data-parallel would
+            # replicate per chip), padded so that the split is even
+            mesh = mesh_mod.default_mesh()
+            n_model = mesh.shape.get("model", 1)
+            key = jax.random.PRNGKey(seed)
+            bag_keys = jax.random.split(key, n_bags)
+            stacked = jax.vmap(lambda k: wdl.pad_tables(
+                wdl.init_params(spec, k), n_model))(bag_keys)
+
+            def loss(params, inputs, w_, key_):
+                d_, i_, y_ = inputs
+                return wdl.loss_fn(spec, params, d_, i_, y_, w_)
+
+            def metric(params, inputs, w_):
+                d_, i_, y_ = inputs
+                return wdl.mse(spec, params, d_, i_, y_, w_)
+
+            optimizer = _tables_scoped(
+                optimizer_from_params(train_conf.params))
+            ew = train_conf.earlyStoppingRounds
+            shardings = None
+            if n_model > 1:
+                one = jax.tree.map(lambda l: l[0], stacked)
+                shardings = mesh_mod.wdl_train_shardings(mesh, one)
+        best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
+            loss, metric, optimizer, train_conf.numTrainEpochs,
+            ew if ew and ew > 0 else 0,
+            float(train_conf.convergenceThreshold or 0.0),
+            stacked, (d_tr, i_tr, y_tr), bag_w, (d_v, i_v, y_v), w_v,
+            bag_keys, None, batch_rows=batch_rows, perm_seed=seed,
+            param_shardings=shardings)
+
+        with obs_trace.span("train.fetch"):
+            res = TrainResult(
+                spec=spec,
+                params_per_bag=[wdl.file_params(spec, jax.tree.map(
+                    lambda a, i=i: np.asarray(a[i]), best_params))
+                    for i in range(n_bags)],
+                train_errors=np.asarray(train_errs),
+                val_errors=np.asarray(val_errs),
+                best_val=np.asarray(best_val),
+                best_epoch=np.asarray(best_epoch),
+                wall_seconds=time.time() - t0)
+    log.info("train[WDL]: %d bag(s), %d epochs, best val %s in %.2fs",
+             n_bags, train_conf.numTrainEpochs,
+             np.round(res.best_val, 6).tolist(), res.wall_seconds)
+    return res
+
+
+def run_wdl(ctx: ProcessorContext, seed: int = 12306):
     mc = ctx.model_config
     path = ctx.path_finder.normalized_data_path()
     if mc.train.trainOnDisk:
@@ -47,67 +170,13 @@ def run_wdl(ctx: ProcessorContext, seed: int = 12306):
     if idx.shape[1] == 0:
         log.warning("WDL without categorical index block — deep-only model")
 
-    vocab = max(meta["indexVocabSizes"], default=1)
-    spec = wdl.WDLSpec.from_train_params(mc.train.params, dense.shape[1],
-                                         idx.shape[1], vocab)
-
-    n_bags = max(mc.train.baggingNum, 1)
-    with obs_trace.span("train.job", family="wdl", rows=len(y),
-                        steps=mc.train.numTrainEpochs, bags=n_bags):
-        with obs_trace.span("train.prepare"):
-            tr_mask, val_mask = split_validation(
-                len(y), mc.train.validSetRate, seed)
-            bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
-                                    mc.train.baggingSampleRate,
-                                    mc.train.baggingWithReplacement, seed,
-                                    labels=np.asarray(y[tr_mask]),
-                                    stratified=mc.train.stratifiedSample,
-                                    neg_only=mc.train.sampleNegOnly) \
-                * w[tr_mask][None, :]
-
-            key = jax.random.PRNGKey(seed)
-            bag_keys = jax.random.split(key, n_bags)
-            stacked = jax.vmap(lambda k: wdl.init_params(spec, k))(bag_keys)
-            grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
-
-            def loss(params, inputs, w_, key_):
-                d_, i_, y_ = inputs
-                return wdl.loss_fn(spec, params, d_, i_, y_, w_)
-
-            def metric(params, inputs, w_):
-                d_, i_, y_ = inputs
-                return wdl.mse(spec, params, d_, i_, y_, w_)
-
-            optimizer = optimizer_from_params(mc.train.params)
-            ew = mc.train.earlyStoppingRounds
-            # rows shard over 'data'; with SHIFU_TPU_MESH_MODEL > 1 the
-            # embedding + wide tables additionally shard over 'model' (the
-            # vocab-heavy leaves that data-parallel would replicate per chip)
-            from shifu_tpu.parallel import mesh as mesh_mod
-            mesh = mesh_mod.default_mesh()
-            shardings = None
-            if mesh.shape.get("model", 1) > 1:
-                one = jax.tree.map(lambda l: l[0], stacked)
-                shardings = mesh_mod.wdl_train_shardings(mesh, one)
-        best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
-            loss, metric, optimizer, mc.train.numTrainEpochs,
-            ew if ew and ew > 0 else 0,
-            float(mc.train.convergenceThreshold or 0.0),
-            stacked,
-            (dense[tr_mask], idx[tr_mask], y[tr_mask]),
-            bag_w,
-            (dense[val_mask], idx[val_mask], y[val_mask]),
-            w[val_mask], bag_keys, grad_mask, param_shardings=shardings)
-
-        with obs_trace.span("train.fetch"):
-            spec_meta = _wdl_spec_meta(mc, spec, meta)
-            for i in range(n_bags):
-                p = jax.tree.map(lambda a, i=i: np.asarray(a[i]), best_params)
-                path = ctx.path_finder.model_path(i, "wdl")
-                ctx.path_finder.ensure(path)
-                save_model(path, "wdl", spec_meta, p)
-    log.info("train[WDL]: %d bag(s), best val %s in %.2fs", n_bags,
-             np.round(np.asarray(best_val), 6).tolist(), time.time() - t0)
+    res = train_wdl(mc.train, dense, idx, y, w, meta["indexVocabSizes"],
+                    seed=seed)
+    spec_meta = _wdl_spec_meta(mc, res.spec, meta)
+    for i, p in enumerate(res.params_per_bag):
+        path = ctx.path_finder.model_path(i, "wdl")
+        ctx.path_finder.ensure(path)
+        save_model(path, "wdl", spec_meta, p)
     return None
 
 
@@ -115,7 +184,7 @@ def _wdl_spec_meta(mc, spec, meta):
     return {
         "kind": "wdl",
         "spec": {"dense_dim": spec.dense_dim, "n_cat": spec.n_cat,
-                 "vocab_size": spec.vocab_size,
+                 "vocab_sizes": list(spec.vocab_sizes),
                  "embed_size": spec.embed_size,
                  "hidden_dims": list(spec.hidden_dims),
                  "activations": list(spec.activations), "l2": spec.l2,
@@ -165,10 +234,9 @@ def _run_wdl_streaming(ctx: ProcessorContext, seed: int):
         # the bytes and widen on device
         return (np.asarray(dense[a:b]), i_blk, y, w)
 
-    vocab = max(meta["indexVocabSizes"], default=1)
     n_cat = idx.shape[1] if idx is not None else 0
     spec = wdl.WDLSpec.from_train_params(mc.train.params, dense.shape[1],
-                                         n_cat, vocab)
+                                         n_cat, meta["indexVocabSizes"])
     chunk_rows, n_val = streaming_train_args(mc, meta)
     ck_dir, ck_int = checkpoint_args(mc, ctx, "streaming-wdl")
     res = train_wdl_streaming(mc.train, get_chunk, len(tags), spec,
@@ -181,7 +249,7 @@ def _run_wdl_streaming(ctx: ProcessorContext, seed: int):
     for i, p in enumerate(res.params_per_bag):
         out = ctx.path_finder.model_path(i, "wdl")
         ctx.path_finder.ensure(out)
-        save_model(out, "wdl", spec_meta, p)
+        save_model(out, "wdl", spec_meta, wdl.file_params(spec, p))
     cleanup_checkpoints(ck_dir)
     log.info("train[WDL streaming]: %d bag(s), best val %s in %.2fs",
              len(res.params_per_bag),

@@ -595,7 +595,17 @@ def wdl_row_costs(dense_dim: int, n_cat: int, embed_size: int,
                   hidden_dims, train: bool = True, dtype_bytes: int = 4):
     """WDL = deep MLP over [dense ‖ embeddings] + wide linear logit.
     Embedding rows are gathered per example (read fwd, read+write in
-    the backward scatter)."""
+    the backward scatter).
+
+    A model of what a straightforward implementation moves, per row.
+    The benchmark's `benchmark/work/wdl.py` counts the same MLP
+    operations (3 · 2 · Σ d_in·d_out a training row; the 2 · (dense +
+    n_cat) wide adds here are left out there) but fewer bytes, on
+    purpose: it is a lower bound on ANY implementation, so activations
+    stay on the chip, a batch reads each DISTINCT id's row once however
+    often the id repeats, and the optimizer touches those rows only;
+    this function charges every lookup its row and every layer its
+    activations."""
     deep_in = int(dense_dim) + int(n_cat) * int(embed_size)
     flops, bytes_ = mlp_row_costs(deep_in, hidden_dims, 1, train,
                                   dtype_bytes)

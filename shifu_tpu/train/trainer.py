@@ -181,7 +181,8 @@ def _rescue_empty_bags(w: np.ndarray) -> np.ndarray:
 
 @partial(jax.jit, static_argnames=("loss_fn", "metric_fn", "optimizer",
                                    "n_epochs", "early_stop_window",
-                                   "n_batches"))
+                                   "n_batches"),
+         donate_argnames=("carry_in",))
 def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                      early_stop_window: int, convergence_threshold: float,
                      carry_in, train_inputs, w_train_bags,
@@ -189,22 +190,37 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
     """Generic vmapped-over-bags, scanned-over-epochs trainer (shared by
     NN/LR/WDL/MTL), resumable: takes and returns the full per-bag
     training carry (see init_train_carry) so callers can run in
-    checkpointed chunks.
+    checkpointed chunks. The carry is DONATED: the program updates the
+    parameters, optimizer state and best-epoch copy in the buffers it
+    was given instead of copying each first (three table-sized copies
+    for a WDL job), so a caller that still needs `carry_in` afterwards
+    passes a copy.
 
     loss_fn(params, inputs_tuple, w, key) → scalar training loss;
     metric_fn(params, inputs_tuple, w) → scalar validation error.
     w_train_bags: (B, Nt) per-bag sample weights (bagging multiplicity ×
     row weight). grad_mask: pytree of {0,1} masking fixed layers
-    (continuous training's frozen-layer fitting, NNMaster.java:369-379).
+    (continuous training's frozen-layer fitting, NNMaster.java:369-379),
+    or None where nothing is frozen (no multiply: a mask of ones costs a
+    pass over every gradient, a gigabyte for a WDL table).
 
     n_batches > 1 switches one full-batch update per epoch to an inner
     scan of mini-batch updates (train#params MiniBatchRows): every row
     tensor arrives pre-reshaped to (n_batches, rows/batch, ...) and
     w_train_bags to (B, n_batches, rows/batch); batch order reshuffles
-    per epoch via the carried PRNG key. This is what keeps bagging /
+    per epoch via the carried PRNG key, a function of the bag's key
+    alone: each epoch does `key, _ = split(key)`, `key, pkey =
+    split(key)`, runs the batches in the order `jax.random.permutation(
+    pkey, n_batches)`, and splits `key` once more a batch (`key, _ =
+    split(key)`, the batch's dropout key). This is what keeps bagging /
     grid search / k-fold usable when bags × activations no longer fit
     HBM full-batch.
     """
+
+    def masked(grads):
+        if grad_mask is None:
+            return grads
+        return jax.tree.map(lambda g, m: g * m, grads, grad_mask)
 
     def one_bag(carry_in, w_train):
 
@@ -223,8 +239,7 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                         loss_b, grads_b = jax.value_and_grad(loss_fn)(
                             p, inp_b, w_train[bi], bkey)
                     with jax.named_scope("update"):
-                        grads_b = jax.tree.map(lambda g, m: g * m, grads_b,
-                                               grad_mask)
+                        grads_b = masked(grads_b)
                         upd, o2 = optimizer.update(grads_b, o, p)
                         p2 = optax.apply_updates(p, upd)
                     return (p2, o2, k), (loss_b, jnp.sum(w_train[bi]))
@@ -245,8 +260,7 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                     train_err, grads = jax.value_and_grad(loss_fn)(
                         params, train_inputs, w_train, sub)
                 with jax.named_scope("update"):
-                    grads = jax.tree.map(lambda g, m: g * m, grads,
-                                         grad_mask)
+                    grads = masked(grads)
                     updates, new_opt_state = optimizer.update(
                         grads, opt_state, params)
                     new_params = optax.apply_updates(params, updates)
@@ -316,18 +330,70 @@ def _init_opt_state(optimizer, stacked_params):
                    out_shardings=out_sh)(stacked_params)
 
 
+@jax.jit
+def _own(tree):
+    """The tree in buffers of its own: one dispatch for all its leaves."""
+    return jax.tree.map(jnp.copy, tree)
+
+
 def init_train_carry(optimizer, stacked_params, keys):
     """Fresh per-bag training carry (params, opt_state, best tracker,
     early-stop state, PRNG key) — the checkpointable training state
-    (NNOutput tmp-model + NNMaster recovery state in one pytree)."""
+    (NNOutput tmp-model + NNMaster recovery state in one pytree).
+
+    `train_bags_carry` donates its carry, so `stacked_params` are handed
+    over: they are the carry's running parameters and gone after the
+    first call (a caller that trains from them twice passes a copy each
+    time). The best-epoch tracker and the keys are copies."""
     opt_state = _init_opt_state(optimizer, stacked_params)
     n_bags = keys.shape[0]
+    best, keys = _own((stacked_params, keys))
     return (stacked_params, opt_state,
-            {"params": stacked_params,
-             "val": jnp.full((n_bags,), jnp.inf)},
+            {"params": best, "val": jnp.full((n_bags,), jnp.inf)},
             {"bad": jnp.zeros((n_bags,), jnp.int32),
              "stopped": jnp.zeros((n_bags,), bool)},
             keys)
+
+
+def minibatch_row_order(n_rows: int, seed: int) -> np.ndarray:
+    """The order mini-batch mode puts the training rows in, a function
+    of the job's seed alone: numpy's `default_rng(0xB47C4 ^ seed)
+    .permutation(n_rows)`; batch b holds rows order[b·batch_rows :
+    (b+1)·batch_rows]. The seed derives from the caller's train seed so
+    bags/runs don't all share one order."""
+    return np.random.default_rng(
+        np.uint64(0xB47C4) ^ np.uint64(seed)).permutation(n_rows)
+
+
+def _host_batches(a, axis_rows: int, perm, n_batches: int, batch_rows: int):
+    """permute + pad + reshape in ONE allocation (a permuted
+    intermediate copy would double host RAM exactly when MiniBatchRows
+    is in use for memory reasons)."""
+    a = np.asarray(a)
+    padded = a.shape[:axis_rows] + (n_batches * batch_rows,) \
+        + a.shape[axis_rows + 1:]
+    out = np.zeros(padded, a.dtype)  # zero weight ⇒ pad is inert
+    sel = [slice(None)] * a.ndim
+    sel[axis_rows] = slice(0, a.shape[axis_rows])
+    # mode='clip' (a no-op: perm is a permutation) lets take write
+    # straight into the out view — the default mode='raise' always
+    # buffers a full temporary copy
+    np.take(a, perm, axis=axis_rows, out=out[tuple(sel)], mode="clip")
+    shape = (a.shape[:axis_rows] + (n_batches, batch_rows)
+             + a.shape[axis_rows + 1:])
+    return out.reshape(shape)
+
+
+@partial(jax.jit, static_argnames=("axis_rows", "n_batches", "batch_rows"))
+def _device_batches(a, axis_rows: int, perm, n_batches: int,
+                    batch_rows: int):
+    """`_host_batches` for an array that lives on the device."""
+    out = jnp.take(a, perm, axis=axis_rows)
+    widths = [(0, 0)] * out.ndim
+    widths[axis_rows] = (0, n_batches * batch_rows - out.shape[axis_rows])
+    out = jnp.pad(out, widths)
+    return out.reshape(out.shape[:axis_rows] + (n_batches, batch_rows)
+                       + out.shape[axis_rows + 1:])
 
 
 def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
@@ -349,58 +415,47 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
     aggregation (nn/NNMaster.java:248-259) — while parameters,
     optimizer state, keys and grad masks replicate. Zero-weight row
     padding is inert because every loss/metric normalizes by sum(w).
+    `stacked_params` are handed over (see init_train_carry): device
+    arrays among them are gone when this returns.
 
-    batch_rows > 0 enables mini-batch SGD: rows reshape to
-    (n_batches, batch_rows) on the host, the within-batch row axis
-    shards over the mesh, and the epoch becomes an in-graph scan over
-    shuffled batches (see train_bags_carry) — activation memory scales
-    with batch_rows × bags instead of rows × bags."""
-    with obs_trace.span("train.place"):
-        mesh = mesh_mod.default_mesh()
-        # .shape, not np.asarray(...).shape: the inputs can be device
-        # arrays (on-device data generation), and asarray would pull the
-        # whole array back to host just to read a dimension
-        n_rows = int(train_inputs[0].shape[0])
-        n_batches = 1
-        if batch_rows and 0 < batch_rows < n_rows:
-            n_batches = -(-n_rows // batch_rows)
+    batch_rows > 0 enables mini-batch SGD: rows are put in
+    `minibatch_row_order(n_rows, perm_seed)` and reshape to (n_batches,
+    batch_rows), zero-weight rows padding the last batch — host inputs
+    on the host in one allocation, device inputs on the device with no
+    read-back (`shifu:train.shuffle`) — the within-batch row axis shards
+    over the mesh, and the epoch becomes an in-graph scan over shuffled
+    batches (see train_bags_carry) — activation memory scales with
+    batch_rows × bags instead of rows × bags."""
+    mesh = mesh_mod.default_mesh()
+    # .shape, not np.asarray(...).shape: the inputs can be device arrays
+    # (on-device data generation), and asarray would pull the whole
+    # array back to host just to read a dimension
+    n_rows = int(train_inputs[0].shape[0])
+    n_batches = 1
+    if batch_rows and 0 < batch_rows < n_rows:
+        n_batches = -(-n_rows // batch_rows)
+        with obs_trace.span("train.shuffle", rows=n_rows, batches=n_batches):
             # break any on-disk row ordering (sorted/grouped data would
             # otherwise make every mini-batch class-homogeneous): rows are
             # permuted once here, and the in-graph scan additionally
-            # shuffles BATCH order every epoch. The seed derives from the
-            # caller's train seed so bags/runs don't all share one order.
-            perm = np.random.default_rng(
-                np.uint64(0xB47C4) ^ np.uint64(perm_seed)).permutation(n_rows)
+            # shuffles BATCH order every epoch
+            perm = minibatch_row_order(n_rows, perm_seed)
             if any(isinstance(t, jax.Array) for t in train_inputs):
-                # to_batches permutes on the HOST (single-allocation
-                # permute+pad — mini-batch mode exists to bound host
-                # memory): device inputs get pulled back first — the very
-                # transfer a caller placing them on device was avoiding
-                log.warning("mini-batch mode with device-array inputs: "
-                            "rows are permuted on host, forcing a "
-                            "device->host readback of the full dataset")
-
-            def to_batches(a, axis_rows=0):
-                # permute + pad + reshape in ONE allocation (a permuted
-                # intermediate copy would double host RAM exactly when
-                # MiniBatchRows is in use for memory reasons)
-                a = np.asarray(a)
-                padded = a.shape[:axis_rows] + (n_batches * batch_rows,) \
-                    + a.shape[axis_rows + 1:]
-                out = np.zeros(padded, a.dtype)  # zero weight ⇒ pad is inert
-                sel = [slice(None)] * a.ndim
-                sel[axis_rows] = slice(0, a.shape[axis_rows])
-                # mode='clip' (a no-op: perm is a permutation) lets take
-                # write straight into the out view — the default
-                # mode='raise' always buffers a full temporary copy
-                np.take(a, perm, axis=axis_rows, out=out[tuple(sel)],
-                        mode="clip")
-                shape = (a.shape[:axis_rows] + (n_batches, batch_rows)
-                         + a.shape[axis_rows + 1:])
-                return out.reshape(shape)
-
-            train_inputs = tuple(to_batches(t) for t in train_inputs)
+                # device inputs (on-device data generation) stay there:
+                # the order goes up, the rows never come down
+                perm = jnp.asarray(perm)
+                to_batches = partial(_device_batches, perm=perm,
+                                     n_batches=n_batches,
+                                     batch_rows=batch_rows)
+            else:
+                to_batches = partial(_host_batches, perm=perm,
+                                     n_batches=n_batches,
+                                     batch_rows=batch_rows)
+            train_inputs = tuple(to_batches(t, axis_rows=0)
+                                 for t in train_inputs)
             w_train_bags = to_batches(w_train_bags, axis_rows=1)
+    with obs_trace.span("train.place"):
+        if n_batches > 1:
             train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 1)
                                  for t in train_inputs)
             w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=2)
@@ -418,7 +473,8 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
             stacked_params = mesh_mod.place_stacked(stacked_params,
                                                     param_shardings)
             # grad_mask is UNSTACKED (applied per-bag inside the vmap)
-            grad_mask = mesh_mod.place(grad_mask, param_shardings)
+            if grad_mask is not None:
+                grad_mask = mesh_mod.place(grad_mask, param_shardings)
         else:
             if mesh.shape.get("model", 1) > 1:
                 log.warning(
@@ -433,6 +489,7 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
             mesh, jnp.asarray(dropout_keys))
 
         carry = init_train_carry(optimizer, stacked_params, dropout_keys)
+        del stacked_params
     done = 0
     tr_chunks, va_chunks = [], []
     if checkpoint_dir and checkpoint_interval > 0:
@@ -456,11 +513,14 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
                 while done < n_epochs:
                     chunk = min(checkpoint_interval, n_epochs - done)
                     with obs_trace.span("train.program", steps=chunk):
+                        # a copy goes in: the background checkpoint
+                        # writer may still be reading the last carry
                         carry, tr, va = train_bags_carry(
                             loss_fn, metric_fn, optimizer, chunk,
                             early_stop_window, convergence_threshold,
-                            carry, train_inputs, w_train_bags, val_inputs,
-                            w_val, grad_mask, n_batches)
+                            _own(carry), train_inputs,
+                            w_train_bags, val_inputs, w_val, grad_mask,
+                            n_batches)
                     # keep the per-chunk error curves ON DEVICE — the
                     # host sync happens once after the loop, so chunk
                     # k+1 dispatches while k's errors are still in

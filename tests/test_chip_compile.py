@@ -256,3 +256,31 @@ def test_whole_gbt_round_compiles_on_four_chip_mesh(topo, monkeypatch):
         shape((28,), F32), mesh=mesh, subtract=None).compile().as_text()
     assert text.count("tpu_custom_call") >= 2      # histogram AND split
     assert "all-reduce" in text and "all-gather" not in text
+
+
+def test_wdl_table_step_compiles_and_fits(one_chip):
+    """The lookup of a batch in a lane-packed embedding table of the
+    benchmark's `wdl-criteo` size, its gradient's scatter-add and a dense
+    AdaGrad pass: the chip's compiler takes it, keeps the table one
+    128-lane row a packed row (no padded copy: a (rows, 4, 32) view would
+    be tiled to eight times its size), and its temporaries stay a few
+    table sizes."""
+    from shifu_tpu.models import wdl
+    rows, e, batch, cols = 8_440_680, 32, 16_384, 26
+    packed = (-(-rows // (wdl.LANES // e)), wdl.LANES)
+
+    def step(table, acc, ids, proj):
+        def loss(t):
+            return jnp.sum(wdl.lookup(t, ids, e) * proj)
+        g = jax.grad(loss)(table)
+        acc = acc + g * g
+        return table - 0.01 * g * jax.lax.rsqrt(acc + 1e-7), acc
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (packed, F32), (packed, F32), ((batch, cols), I32),
+        ((batch, cols, e), F32))]
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "f32[%d,%d]{1,0" % packed in text, "the table is not row-major"
+    table_bytes = packed[0] * packed[1] * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * table_bytes

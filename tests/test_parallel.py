@@ -332,11 +332,11 @@ def test_mesh_rules_defaults_and_env_overrides(monkeypatch):
     rules = mesh_mod.default_rules()
     assert rules("rows", "hidden") == ("data", "model")
     assert rules("unknown") == (None,)
-    # override: replicate 'hidden' (empty RHS), re-point 'cat' to data
-    monkeypatch.setenv("SHIFU_TPU_MESH_RULES", "hidden=,cat=data")
+    # override: replicate 'hidden' (empty RHS), re-point 'vocab' to data
+    monkeypatch.setenv("SHIFU_TPU_MESH_RULES", "hidden=,vocab=data")
     rules = mesh_mod.default_rules()
     assert rules("hidden") == (None,)
-    assert rules("cat") == ("data",)
+    assert rules("vocab") == ("data",)
     assert rules("task") == ("model",)   # untouched default
     monkeypatch.setenv("SHIFU_TPU_MESH_RULES", "garbage")
     with pytest.raises(ValueError, match="SHIFU_TPU_MESH_RULES"):

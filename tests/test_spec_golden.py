@@ -93,12 +93,11 @@ def _build_spec(kind, rng):
     if kind == "wdl":
         import jax
         from shifu_tpu.models import wdl
-        spec = wdl.WDLSpec(dense_dim=6, n_cat=2, vocab_size=5,
+        spec = wdl.WDLSpec(dense_dim=6, n_cat=2, vocab_sizes=(5, 5),
                            embed_size=3, hidden_dims=(4,),
                            activations=("relu",))
-        params = jax.tree.map(np.asarray,
-                              wdl.init_params(spec,
-                                              jax.random.PRNGKey(5)))
+        params = wdl.file_params(spec, jax.tree.map(
+            np.asarray, wdl.init_params(spec, jax.random.PRNGKey(5))))
         meta = {"spec": spec.__dict__,
                 "denseNames": [f"x{i}" for i in range(6)],
                 "indexNames": ["c0", "c1"]}

@@ -13,7 +13,7 @@ from shifu_tpu.models import mtl, wdl
 
 
 def test_wdl_forward_shapes(rng):
-    spec = wdl.WDLSpec(dense_dim=5, n_cat=3, vocab_size=7, embed_size=4,
+    spec = wdl.WDLSpec(dense_dim=5, n_cat=3, vocab_sizes=(7, 7, 7), embed_size=4,
                        hidden_dims=(8,), activations=("relu",))
     params = wdl.init_params(spec, jax.random.PRNGKey(0))
     d = jnp.asarray(rng.normal(0, 1, (10, 5)).astype(np.float32))
@@ -30,7 +30,7 @@ def test_wdl_learns_categorical_signal(rng):
     idx = rng.integers(0, 6, (n, 2)).astype(np.int32)
     y = (idx[:, 0] >= 3).astype(np.float32)
     d = rng.normal(0, 1, (n, 3)).astype(np.float32)
-    spec = wdl.WDLSpec(dense_dim=3, n_cat=2, vocab_size=7, embed_size=4,
+    spec = wdl.WDLSpec(dense_dim=3, n_cat=2, vocab_sizes=(7, 7), embed_size=4,
                        hidden_dims=(8,), activations=("relu",))
     params = wdl.init_params(spec, jax.random.PRNGKey(1))
     import optax
