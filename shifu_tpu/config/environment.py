@@ -273,11 +273,6 @@ _declare("SHIFU_TPU_TREE_VMEM_MB", "int", 64,
          "VMEM budget for the fused tree-inference kernel's row/tree "
          "tiling (pallas_trees._derive_tiles); also its compiled "
          "VMEM limit")
-_declare("SHIFU_TPU_TREE_SCAN", "bool", "1",
-         "1 = build_tree/build_forest and the resident streaming GBT "
-         "tier grow all levels inside one lax.fori_loop dispatch "
-         "(fixed-width level state, masked inactive nodes); 0 = the "
-         "per-level Python loop (depth+1 dispatches per tree)")
 _declare("SHIFU_TPU_GBT_RESIDENT_STATE", "str", "auto",
          "streaming GBT row-state tier: 1 keeps node/pred/grad/hess as "
          "device arrays (zero host syncs per level, one per round), 0 "

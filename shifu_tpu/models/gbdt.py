@@ -311,14 +311,8 @@ def build_forest(cfg: TreeConfig, binsT, grad_T, hess_T, feature_masks,
     per tree (growth already routed rows to their final nodes — see
     build_tree — so lockstep boosting gathers leaf_value[node] instead
     of re-walking T trees)."""
-    c, r = binsT.shape
+    r = binsT.shape[1]
     n_trees = grad_T.shape[0]
-    if tree_scan_enabled() and cfg.max_depth >= 1:
-        trees, node_T = _grow_forest_scan(cfg, binsT, grad_T, hess_T,
-                                          feature_masks, mesh, subtract)
-        if return_nodes:
-            return trees, node_T
-        return trees
     trees = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (n_trees,) + a.shape),
         _empty_tree(cfg))
@@ -336,9 +330,10 @@ def build_forest(cfg: TreeConfig, binsT, grad_T, hess_T, feature_masks,
         )(trees, node_T)
         prev_g, prev_h = g, h
 
-    g, h = _forest_child_histograms(cfg, binsT, node_T, grad_T, hess_T,
-                                    cfg.max_depth, prev_g, prev_h,
-                                    trees, mesh, subtract)
+    g, h = _forest_child_histograms(
+        cfg, _leaf_columns(binsT), node_T, grad_T, hess_T, cfg.max_depth,
+        _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2), trees, mesh,
+        subtract)
     trees = jax.vmap(lambda t, gh, hh: _final_leaves(cfg, t, gh, hh)
                      )(trees, g, h)
     if return_nodes:
@@ -527,6 +522,23 @@ def _final_leaves(cfg: TreeConfig, tree, g_hist, h_hist):
     return tree
 
 
+# _final_leaves reads the histogram of column 0 and no other, so the
+# leaf level's pass is given that column alone (a (1, rows) bins block
+# spans its whole axis, which Mosaic takes: PERF.md, PR 27)
+_LEAF_COLUMNS = 1
+
+
+def _leaf_columns(x, axis: int = 0):
+    """The first _LEAF_COLUMNS columns of a bin matrix ((C, R) or
+    FusedBins, axis 0) or of a level's histograms ((..., P, C, B),
+    axis -2); None stays None."""
+    if x is None:
+        return None
+    if isinstance(x, FusedBins):
+        return FusedBins(_leaf_columns(x.valuesT), _leaf_columns(x.cuts))
+    return jax.lax.slice_in_dim(x, 0, _LEAF_COLUMNS, axis=axis)
+
+
 def _select(hit, values):
     """The value that `hit` marks along axis 0, bit for bit; 0 (False)
     where it marks none. A select and an integer sum over that axis,
@@ -546,8 +558,11 @@ def _lookup(table, idx):
     """table[idx] for a small (S,) or (S, K) table and (R,) ids, by a
     compare of every id against the S slots (a per-row gather from a
     511-entry table ran at ~100 M rows/s on the v5e; PERF.md, PR 25).
-    An id outside [0, S) reads 0. Returns (R,), or (K, R) for a (S, K)
-    table: rows stay on the lane axis."""
+    The cost is S compares a row, so callers hand it no more than they
+    read: routing one level's nodes (S from 1 to 2^(max_depth-1)), the
+    boosting update a whole tree's leaf values. An id outside [0, S)
+    reads 0. Returns (R,), or (K, R) for a (S, K) table: rows stay on
+    the lane axis."""
     s = table.shape[0]
     slots = jnp.arange(s, dtype=jnp.int32).reshape((s,) + (1,) * table.ndim)
     return _select(idx == slots, table[..., None])
@@ -570,24 +585,21 @@ def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int):
 
 @jax.named_scope("route")
 def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
-                    level_offset, n_level):
-    """_route_level core with level_offset/n_level as values rather
-    than a static depth: the scan builder traces them, and then the
-    level's slots are the n_max = 2^max_depth it could hold at most.
-    Both per-row lookups (the split of the row's node, the row's bin in
+                    level_offset: int, n_level: int):
+    """_route_level's core, for the level that holds the n_level nodes
+    from level_offset on: both Python ints, so the selects below run
+    over that level's own width (1 at the root, 2^d at depth d) and
+    never over the 2^max_depth slots the deepest level holds. Both
+    per-row lookups (the split of the row's node, the row's bin in
     that split's feature) are selects (`_lookup`, `_pick_row`), all in
     integers: exact, so every builder routes bitwise alike. Rows
     outside the level (parked at a leaf, -1 pad rows) match no slot,
     read feature -1 and stay where they are."""
-    n_slots = n_level if isinstance(n_level, int) else 2 ** cfg.max_depth
-    slots = jnp.arange(n_slots, dtype=jnp.int32)
-    level_ids = jnp.where(slots < n_level, level_offset + slots, -1)
-
     def of_node(table):
-        # the level's slots of the table first (a dynamic_slice batches
-        # into a gather under vmap; a slot past the level reads 0),
-        # then each row's slot of those
-        return _lookup(_lookup(table, level_ids), node_of_row - level_offset)
+        # the level's nodes of the table (a static slice, under vmap
+        # over trees too), then each row's slot of those
+        return _lookup(table[level_offset:level_offset + n_level],
+                       node_of_row - level_offset)
 
     # feature + 1, so that a row no slot matched reads feature -1
     node_feat = of_node(tree["feature"] + 1) - 1
@@ -617,7 +629,11 @@ def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
 def build_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh=None,
                subtract=None, return_nodes=False):
     """Grow one tree level-by-level (all nodes of a level at once —
-    DTMaster's todoNodes batch IS the level here).
+    DTMaster's todoNodes batch IS the level here), all levels in this
+    one jit (_grow_tree): the histogram kernel is called once a level
+    at that level's own slot count (1, 1, 2, ..., 2^(max_depth-1)
+    under sibling subtraction), the leaf level on the columns its
+    totals read (_leaf_columns).
 
     binsT: (C, R) int32 TRANSPOSED bin matrix, missing = n_bins-1 (rows
     ride the lane axis — a row-major (R, C) array with C < 128 would
@@ -634,15 +650,26 @@ def build_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh=None,
     of re-walking the tree from the root (predict_trees), saving
     max_depth gathers over the (C, R) bin matrix per round.
     """
-    c, r = binsT.shape
-    if tree_scan_enabled() and cfg.max_depth >= 1:
-        tree, node_of_row = _grow_tree_scan(cfg, binsT, grad, hess,
-                                            feature_mask, mesh, subtract)
-        if return_nodes:
-            return tree, node_of_row
-        return tree
+    tree, node_of_row = _grow_tree(cfg, binsT, grad, hess, feature_mask,
+                                   mesh, subtract)
+    if return_nodes:
+        return tree, node_of_row
+    return tree
+
+
+def _grow_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh,
+               subtract, node0=None):
+    """THE growth loop of a single tree, unrolled over depth when the
+    caller's jit traces it, so every level's shapes are that level's
+    own: its histogram pass covers the slots it reads (the root 1,
+    level d the 2^(d-1) left children under sibling subtraction, 2^d
+    without), its split search and its routing selects the 2^d nodes
+    it holds. node0: the rows' starting nodes (the resident streaming
+    tier parks its pad rows at -1, which no level's slots match).
+    Returns (tree, landing node of every row)."""
     tree = _empty_tree(cfg)
-    node_of_row = jnp.zeros(r, jnp.int32)  # all rows at root
+    node_of_row = (jnp.zeros(binsT.shape[1], jnp.int32) if node0 is None
+                   else node0)
 
     prev_g = prev_h = None
     for depth in range(cfg.max_depth):
@@ -655,12 +682,10 @@ def build_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh=None,
         prev_g, prev_h = g_hist, h_hist
 
     g_hist, h_hist = _child_level_histograms(
-        cfg, binsT, node_of_row, grad, hess, cfg.max_depth, prev_g,
-        prev_h, tree["is_leaf"], tree["feature"], mesh, subtract)
-    tree = _final_leaves(cfg, tree, g_hist, h_hist)
-    if return_nodes:
-        return tree, node_of_row
-    return tree
+        cfg, _leaf_columns(binsT), node_of_row, grad, hess, cfg.max_depth,
+        _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2),
+        tree["is_leaf"], tree["feature"], mesh, subtract)
+    return _final_leaves(cfg, tree, g_hist, h_hist), node_of_row
 
 
 def _use_hist_subtract() -> bool:
@@ -731,199 +756,6 @@ def _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level):
     g = jnp.stack([gl, gr], axis=-3).reshape(lead + (n_level, c, b))
     h = jnp.stack([hl, hr], axis=-3).reshape(lead + (n_level, c, b))
     return g, h
-
-
-# ---------------------------------------------------------------------------
-# Single-dispatch builds — all levels inside one lax.fori_loop
-# ---------------------------------------------------------------------------
-
-def tree_scan_enabled() -> bool:
-    """SHIFU_TPU_TREE_SCAN=1 grows every level of build_tree /
-    build_forest / the single-chunk resident streaming tier inside ONE
-    lax.fori_loop-over-levels jit — one dispatch per tree (or per
-    lockstep forest round) instead of (depth+1). Read at TRACE time
-    like the other build knobs."""
-    return knob_bool("SHIFU_TPU_TREE_SCAN")
-
-
-@jax.named_scope("split")
-def _fold_splits_masked(cfg: TreeConfig, tree, s, level_offset, n_level,
-                        n_max: int):
-    """_fold_splits at a FIXED n_max slot width with traced
-    level_offset/n_level: slots past the live level get an
-    out-of-range scatter id and DROP, so a fori_loop level body reuses
-    one shape for every depth without clobbering later levels' nodes.
-    For live slots the written values are the same expressions as
-    _fold_splits — bitwise parity per node."""
-    rng = jnp.arange(n_max)
-    ids = level_offset + rng
-    safe = jnp.where(rng < n_level, ids, cfg.n_nodes)  # OOB → dropped
-    can_split = (s["gain"] > cfg.min_info_gain) & jnp.isfinite(s["gain"])
-    tree = dict(tree)
-    tree["feature"] = tree["feature"].at[safe].set(
-        jnp.where(can_split, s["feature"], -1), mode="drop")
-    tree["bin"] = tree["bin"].at[safe].set(s["bin"], mode="drop")
-    tree["default_left"] = tree["default_left"].at[safe].set(
-        s["default_left"], mode="drop")
-    tree["gain"] = tree["gain"].at[safe].set(
-        jnp.where(can_split, s["gain"], 0.0), mode="drop")
-    g_tot = s["g_tot"] if s["g_tot"].ndim == 1 else s["g_tot"][:, 0]
-    h_tot = s["h_tot"] if s["h_tot"].ndim == 1 else s["h_tot"][:, 0]
-    val = -g_tot / (h_tot + cfg.reg_lambda)
-    tree["is_leaf"] = tree["is_leaf"].at[safe].set(~can_split,
-                                                   mode="drop")
-    tree["leaf_value"] = tree["leaf_value"].at[safe].set(
-        jnp.where(can_split, 0.0, val), mode="drop")
-    return tree
-
-
-@jax.named_scope("hist")
-def _parent_split_mask_at(is_leaf, feature, prev_offset, n_slots: int):
-    """_parent_split_mask at a fixed n_slots width with a traced
-    prev_offset. Slots past the real parent level read ids that spill
-    into the (still-empty) current level — feature -1 there masks them
-    False, so phantom parents can never subtract."""
-    parent_ids = prev_offset + jnp.arange(n_slots)
-    return (~is_leaf[..., parent_ids]) & (feature[..., parent_ids] >= 0)
-
-
-def _grow_tree_scan(cfg: TreeConfig, binsT, grad, hess, feature_mask,
-                    mesh, subtract, node0=None):
-    """build_tree's level loop as one lax.fori_loop over depths
-    1..max_depth-1 (depth 0 and the final leaf level peel off
-    statically — the first has no parent state, the last no splits).
-    Every in-loop level runs at the fixed width n_max = 2^max_depth:
-    dead slots carry zero histograms, scatter-drop out of the fold,
-    and subtract as masked zeros — the same per-cell adds and
-    per-node split math as the per-level loop, so trees are bitwise
-    identical on the XLA scatter path (tests/test_gbt_device.py pins
-    it). Returns (tree, node_of_row) like build_tree(return_nodes).
-
-    node0: optional initial row→node vector (the streaming tiers park
-    pad rows at -1, which dumps/ignores them exactly as the per-level
-    _stream_level_chunk does)."""
-    c, r = binsT.shape
-    n_max = 2 ** cfg.max_depth
-    fm = feature_mask
-    use_sub = _use_hist_subtract() if subtract is None else subtract
-    tree = _empty_tree(cfg)
-    node = jnp.zeros(r, jnp.int32) if node0 is None else node0
-
-    g, h = _level_histograms(binsT, node, grad, hess, 0, n_max,
-                             cfg.n_bins, mesh=mesh)
-    tree = _fold_splits_masked(cfg, tree,
-                               _best_splits((g, h), cfg, fm, mesh=mesh),
-                               0, 1, n_max)
-    node = _route_level_at(cfg, tree, binsT, node, 0, 1)
-
-    def body(d, carry):
-        tree, node, prev_g, prev_h = carry
-        offset = jnp.left_shift(1, d) - 1
-        width = jnp.left_shift(1, d)
-        if use_sub:
-            half = _left_half_nodes(node, offset, width)
-            gl, hl = _level_histograms(binsT, half, grad, hess, offset,
-                                       n_max, cfg.n_bins, mesh=mesh)
-            split = _parent_split_mask_at(
-                tree["is_leaf"], tree["feature"],
-                jnp.left_shift(1, d - 1) - 1, n_max // 2)
-            g, h = _subtract_siblings(
-                prev_g[:n_max // 2], prev_h[:n_max // 2],
-                gl[:n_max // 2], hl[:n_max // 2], split, n_max)
-        else:
-            g, h = _level_histograms(binsT, node, grad, hess, offset,
-                                     n_max, cfg.n_bins, mesh=mesh)
-        s = _best_splits((g, h), cfg, fm, mesh=mesh)
-        tree = _fold_splits_masked(cfg, tree, s, offset, width, n_max)
-        node = _route_level_at(cfg, tree, binsT, node, offset, width)
-        return tree, node, g, h
-
-    if cfg.max_depth > 1:
-        tree, node, g, h = jax.lax.fori_loop(1, cfg.max_depth, body,
-                                             (tree, node, g, h))
-    # final level: width is exactly n_max (static) — reuse the
-    # per-level builder's own histogram step for bitwise parity
-    g_f, h_f = _child_level_histograms(
-        cfg, binsT, node, grad, hess, cfg.max_depth,
-        g[:n_max // 2] if n_max > 1 else g,
-        h[:n_max // 2] if n_max > 1 else h,
-        tree["is_leaf"], tree["feature"], mesh, subtract)
-    tree = _final_leaves(cfg, tree, g_f, h_f)
-    return tree, node
-
-
-def _forest_apply_level_masked(cfg: TreeConfig, trees, g, h,
-                               feature_masks, offset, width, n_max: int,
-                               mesh=None):
-    """_forest_apply_level at the fixed scan width (one split search
-    over T·n_max slots; dead slots drop out of the masked fold)."""
-    t, p, c, b = g.shape
-    mask2 = jnp.repeat(feature_masks, p, axis=0)           # (T·P, C)
-    s = _best_splits((g.reshape(t * p, c, b), h.reshape(t * p, c, b)),
-                     cfg, mask2, mesh=mesh)
-    s_T = jax.tree.map(lambda a: a.reshape((t, p) + a.shape[1:]), s)
-    return jax.vmap(lambda tr, sv: _fold_splits_masked(
-        cfg, tr, sv, offset, width, n_max))(trees, s_T)
-
-
-def _grow_forest_scan(cfg: TreeConfig, binsT, grad_T, hess_T,
-                      feature_masks, mesh, subtract):
-    """build_forest's lockstep level loop inside one fori_loop — the
-    forest twin of _grow_tree_scan: a whole bagged round is ONE
-    dispatch. Returns (trees, node_T)."""
-    c, r = binsT.shape
-    n_trees = grad_T.shape[0]
-    n_max = 2 ** cfg.max_depth
-    use_sub = _use_hist_subtract() if subtract is None else subtract
-    trees = jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (n_trees,) + a.shape),
-        _empty_tree(cfg))
-    node_T = jnp.zeros((n_trees, r), jnp.int32)
-
-    g, h = _forest_level_histograms(binsT, node_T, grad_T, hess_T, 0,
-                                    n_max, cfg.n_bins, mesh=mesh)
-    trees = _forest_apply_level_masked(cfg, trees, g, h, feature_masks,
-                                       0, 1, n_max, mesh=mesh)
-    node_T = jax.vmap(lambda t, n: _route_level_at(
-        cfg, t, binsT, n, 0, 1))(trees, node_T)
-
-    def body(d, carry):
-        trees, node_T, prev_g, prev_h = carry
-        offset = jnp.left_shift(1, d) - 1
-        width = jnp.left_shift(1, d)
-        if use_sub:
-            half_T = _left_half_nodes(node_T, offset, width)
-            gl, hl = _forest_level_histograms(binsT, half_T, grad_T,
-                                              hess_T, offset, n_max,
-                                              cfg.n_bins, mesh=mesh)
-            split = _parent_split_mask_at(
-                trees["is_leaf"], trees["feature"],
-                jnp.left_shift(1, d - 1) - 1, n_max // 2)
-            g, h = _subtract_siblings(
-                prev_g[:, :n_max // 2], prev_h[:, :n_max // 2],
-                gl[:, :n_max // 2], hl[:, :n_max // 2], split, n_max)
-        else:
-            g, h = _forest_level_histograms(binsT, node_T, grad_T,
-                                            hess_T, offset, n_max,
-                                            cfg.n_bins, mesh=mesh)
-        trees = _forest_apply_level_masked(cfg, trees, g, h,
-                                           feature_masks, offset, width,
-                                           n_max, mesh=mesh)
-        node_T = jax.vmap(lambda t, n: _route_level_at(
-            cfg, t, binsT, n, offset, width))(trees, node_T)
-        return trees, node_T, g, h
-
-    if cfg.max_depth > 1:
-        trees, node_T, g, h = jax.lax.fori_loop(1, cfg.max_depth, body,
-                                                (trees, node_T, g, h))
-    g_f, h_f = _forest_child_histograms(
-        cfg, binsT, node_T, grad_T, hess_T, cfg.max_depth,
-        g[:, :n_max // 2] if n_max > 1 else g,
-        h[:, :n_max // 2] if n_max > 1 else h,
-        trees, mesh, subtract)
-    trees = jax.vmap(lambda t, gh, hh: _final_leaves(cfg, t, gh, hh)
-                     )(trees, g_f, h_f)
-    return trees, node_T
 
 
 def _walk_trees(trees, binsT, max_depth: int, n_bins: int):
@@ -1631,12 +1463,12 @@ def _build_tree_streaming_device(cfg: TreeConfig, bins_put, n_chunks: int,
 def _build_tree_fused_resident(cfg: TreeConfig, binsT_c, node0, grad_c,
                                hess_c, fm, mesh=None):
     """Whole-tree single-dispatch build for the resident streaming
-    tier when the data is ONE chunk: the fori_loop scan builder grows
-    every level inside this jit, so a round costs one dispatch instead
-    of (max_depth+1). node0 carries the pad rows at -1 (hist dump slot
-    + routing no-op), exactly like _stream_level_chunk."""
-    return _grow_tree_scan(cfg, binsT_c.astype(jnp.int32), grad_c,
-                           hess_c, fm, mesh, None, node0=node0)
+    tier when the data is ONE chunk: build_tree's growth loop inside
+    this jit, so a round costs one dispatch instead of (max_depth+1).
+    node0 carries the pad rows at -1 (hist dump slot + routing no-op),
+    exactly like _stream_level_chunk."""
+    return _grow_tree(cfg, binsT_c.astype(jnp.int32), grad_c, hess_c,
+                      fm, mesh, None, node0=node0)
 
 
 def _build_gbt_streaming_resident(cfg: TreeConfig, bins_mm, y_mm, w_mm,
@@ -1714,11 +1546,10 @@ def _build_gbt_streaming_resident(cfg: TreeConfig, bins_mm, y_mm, w_mm,
 
     grad_state: List[Any] = [None] * n_chunks
     hess_state: List[Any] = [None] * n_chunks
-    # single-chunk data + the scan builder ⇒ the bins chunk stays
-    # resident across rounds and a whole tree is ONE dispatch per round
-    # (counted via tree_build_dispatches; tests/test_gbt_device.py)
-    resident_fused = (n_chunks == 1 and tree_scan_enabled()
-                      and cfg.max_depth >= 1)
+    # single-chunk data ⇒ the bins chunk stays resident across rounds
+    # and a whole tree is ONE dispatch per round (counted via
+    # tree_build_dispatches; tests/test_gbt_device.py)
+    resident_fused = n_chunks == 1
     bins_resident = bins_put(0) if resident_fused else None
     val_errs: List[float] = []
     best_val, bad = np.inf, 0

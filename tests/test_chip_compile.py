@@ -65,10 +65,13 @@ def _compile(fn, sharding, *shapes):
 
 # (columns, rows, bins, level nodes): the smoke's HIGGS shape at 1M and
 # 11M rows, the root and the widest level, one 600-column and one
-# 256-bin case
+# 256-bin case; the benchmark's gbt-higgs cell at two slots (under a
+# sublane tile) and its leaf level, 128 slots on the one column
+# `gbdt._leaf_columns` hands it
 HIST_CASES = [(28, 1_000_000, 64, 1), (28, 1_000_000, 64, 32),
               (28, 11_000_000, 64, 64), (600, 1_000_000, 64, 64),
-              (28, 1_000_000, 256, 64)]
+              (28, 1_000_000, 256, 64), (28, 2 ** 24, 64, 2),
+              (1, 2 ** 24, 64, 128)]
 
 
 @pytest.mark.parametrize("c,r,b,s", HIST_CASES)
@@ -121,33 +124,46 @@ def test_fused_hist_kernel_compiles(one_chip, c, r, b, s):
         ((r,), F32))
 
 
-# (columns, rows, max depth, bins, trees): the benchmark's gbt-higgs cell
-# (256 slots a level in the scan builder), a 3-tree lockstep forest
-# under vmap, and a wide table
+@pytest.mark.parametrize("s", [1, 64])
+def test_hist_kernel_compiles_under_vmap_over_trees(one_chip, s):
+    """The lockstep forest's pass (`gbdt._forest_level_histograms`): a
+    vmap of the kernel over three trees' row state, the bin matrix
+    shared. The `pallas_call` gains a grid axis and the stacked G/H
+    operand is built per tree inside the kernel: Mosaic takes it at the
+    root's one slot and at a full pass of the array's width."""
+    from shifu_tpu.ops import pallas_hist
+    c, r, b, trees = 28, 2 ** 22, 64, 3
+    _compile(jax.vmap(
+        lambda sl, g, h, bt: pallas_hist.level_histograms_pallas(
+            bt, sl, g, h, s, b), in_axes=(0, 0, 0, None)), one_chip,
+        ((trees, r), I32), ((trees, r), F32), ((trees, r), F32),
+        ((c, r), I32))
+
+
+# (columns, rows, max depth, bins, trees): the benchmark's gbt-higgs cell,
+# a 3-tree lockstep forest under vmap, and a wide table
 @pytest.mark.parametrize("c,r,depth,b,trees", [
     (28, 2 ** 24, 8, 64, 1), (28, 2 ** 22, 8, 64, 3),
     (1000, 2 ** 20, 6, 1024, 1)])
 def test_route_level_streams_without_gather(one_chip, c, r, depth, b, trees):
-    """One level of routing as the scan builder calls it (offset and
-    width traced, 2^depth slots) and the leaf-value lookup: the chip's
-    compiler is given no gather, and no (slots, rows) or (columns, rows)
-    intermediate reaches HBM: the program's temporaries stay a few (rows,)
-    vectors. A per-row gather ran at 40-100 M rows/s on the v5e and was
-    80% of a tree (PERF.md, PR 25)."""
+    """The widest level a build routes (depth - 1: 2^(depth-1) nodes,
+    a static slice of the tree's arrays) and the leaf-value lookup: the
+    chip's compiler is given no gather, and no (slots, rows) or
+    (columns, rows) intermediate reaches HBM: the program's temporaries
+    stay a few (rows,) vectors. A per-row gather ran at 40-100 M rows/s
+    on the v5e and was 80% of a tree (PERF.md, PR 25)."""
     import re
     from shifu_tpu.models import gbdt
     cfg = gbdt.TreeConfig(max_depth=depth, n_bins=b)
 
-    def one(tree, binsT, node, d):
-        node = gbdt._route_level_at(cfg, tree, binsT, node,
-                                    jnp.left_shift(1, d) - 1,
-                                    jnp.left_shift(1, d))
+    def one(tree, binsT, node):
+        node = gbdt._route_level(cfg, tree, binsT, node, depth - 1)
         return node, gbdt._lookup(tree["leaf_value"], node)
 
-    def level(tree, binsT, node, d):
+    def level(tree, binsT, node):
         if trees == 1:
-            return one(tree, binsT, node, d)
-        return jax.vmap(lambda t, n: one(t, binsT, n, d))(tree, node)
+            return one(tree, binsT, node)
+        return jax.vmap(lambda t, n: one(t, binsT, n))(tree, node)
 
     lead = () if trees == 1 else (trees,)
     n = cfg.n_nodes
@@ -159,8 +175,7 @@ def test_route_level_streams_without_gather(one_chip, c, r, depth, b, trees):
             "default_left": shape(lead + (n,), jnp.bool_),
             "leaf_value": shape(lead + (n,), F32)}
     compiled = jax.jit(level).lower(
-        tree, shape((c, r), I32), shape(lead + (r,), I32),
-        shape((), I32)).compile()
+        tree, shape((c, r), I32), shape(lead + (r,), I32)).compile()
     assert not re.search(r"= \S+ gather\(", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes <= 4 * 4 * trees * r
 

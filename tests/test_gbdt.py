@@ -124,10 +124,13 @@ def _numpy_route(tree, bins, node, offset, n_level, n_bins):
     return out
 
 
-def _route_case(rng, name):
+def _route_case(rng, name, depth=2):
     """(cfg, tree, bins (C, R), what routing is given for them, node,
-    depth) of one routing case; `tree`/`node` of "vmap" hold 3 trees."""
-    depth, c, n_bins, r, max_depth = 2, 7, 64, 3000, 4
+    depth) of one routing case at the level `depth`; `tree`/`node` of
+    "vmap" hold 3 trees."""
+    c, n_bins, r, max_depth = 7, 64, 3000, 4
+    if name == "parked" and depth == 0:
+        depth = 3               # parked rows need a level above theirs
     if name == "wide":          # past bfloat16's exact integers
         c, n_bins = 300, 1024
     cfg = TreeConfig(max_depth=max_depth, n_bins=n_bins)
@@ -138,9 +141,14 @@ def _route_case(rng, name):
     offset, n_level = 2 ** depth - 1, 2 ** depth
     # rows on the level, parked above it and (never in a build) below it
     node = rng.integers(0, cfg.n_nodes, lead + (r,))
+    # the level's first node splits, so some row moves in every case
+    tree["feature"][..., offset] = np.maximum(tree["feature"][..., offset], 0)
     if name == "parked":        # the whole level but one node is leaves
         tree["feature"][offset + 1:offset + n_level] = -1
         node = rng.integers(1, offset + n_level, r)
+    if name == "missing":       # both default directions where two fit
+        k = min(2, n_level)
+        tree["default_left"][offset:offset + k] = [False, True][:k]
     if name == "pad_rows":
         node[rng.random(r) < 0.3] = -1
     bins = rng.integers(0, n_bins - 1, (c, r))
@@ -160,21 +168,22 @@ def _route_case(rng, name):
     return cfg, tree, bins, given, node.astype(np.int32), depth
 
 
-@pytest.mark.parametrize("call", ["static", "traced"])
+@pytest.mark.parametrize("level", [2, 0])
 @pytest.mark.parametrize("name", ["mixed", "parked", "pad_rows", "missing",
                                   "wide", "vmap", "fused"])
-def test_route_level_matches_numpy_walk(rng, name, call):
+def test_route_level_matches_numpy_walk(rng, name, level):
     """One level of routing against a plain numpy walk of the same
-    tree: the per-level builder's static call and the scan builder's
-    (offset and width traced, slots at n_max), rows parked at leaves
-    and at feature -1, -1 pad rows, the missing bin with both default
-    directions, 300 columns by 1024 bins, three trees under vmap, and
-    raw values + cuts (FusedBins)."""
-    cfg, tree, bins, given, node, depth = _route_case(rng, name)
+    tree, at an inner level (four nodes) and at the root (one slot:
+    every select runs over the level's own width; "parked" takes the
+    deepest level there): rows parked at leaves and at feature -1, -1
+    pad rows, the missing bin with both default directions, 300 columns
+    by 1024 bins, three trees under vmap, and raw values + cuts
+    (FusedBins)."""
+    cfg, tree, bins, given, node, depth = _route_case(rng, name, level)
     offset, n_level = 2 ** depth - 1, 2 ** depth
     if name == "missing":
         on_level = (node >= offset) & (node < offset + n_level)
-        for dl in (False, True):
+        for dl in np.unique(tree["default_left"][offset:offset + n_level]):
             rows = on_level & (tree["default_left"][node] == dl)
             assert (bins[tree["feature"][node], np.arange(len(node))][rows]
                     == cfg.n_bins - 1).any()
@@ -182,39 +191,34 @@ def test_route_level_matches_numpy_walk(rng, name, call):
     if not isinstance(given, gbdt.FusedBins):
         given = jnp.asarray(given)
 
-    def route(t, n, off, width):
-        if call == "static":
-            return gbdt._route_level(cfg, t, given, n, depth)
-        return gbdt._route_level_at(cfg, t, given, n, off, width)
+    def route(t, n):
+        return gbdt._route_level(cfg, t, given, n, depth)
 
     if name == "vmap":
-        fn = jax.jit(lambda t, n, o, w: jax.vmap(
-            lambda t1, n1: route(t1, n1, o, w))(t, n))
+        fn = jax.jit(jax.vmap(route))
         want = np.stack([_numpy_route({k: v[i] for k, v in tree.items()},
                                       bins, node[i], offset, n_level,
                                       cfg.n_bins) for i in range(3)])
     else:
         fn = jax.jit(route)
         want = _numpy_route(tree, bins, node, offset, n_level, cfg.n_bins)
-    got = np.asarray(fn(jtree, jnp.asarray(node), jnp.int32(offset),
-                        jnp.int32(n_level)))
+    got = np.asarray(fn(jtree, jnp.asarray(node)))
     assert (want != node).any() and (want == node).any()
     np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("name", ["mixed", "vmap", "fused"])
 def test_route_lowers_without_gather(rng, name):
-    """No gather op in the lowered routing (traced offset and width, as
-    the scan builder calls it), alone, under vmap over trees and on
-    FusedBins; nor in the leaf-value lookup beside it."""
+    """No gather op in the lowered routing of a level (its nodes are a
+    static slice of the tree's arrays), alone, under vmap over trees and
+    on FusedBins; nor in the leaf-value lookup beside it."""
     cfg, tree, _, given, node, depth = _route_case(rng, name)
 
-    def route(t, n, off, width):
-        return gbdt._route_level_at(cfg, t, given, n, off, width)
+    def route(t, n):
+        return gbdt._route_level_at(cfg, t, given, n, 3, 4)
 
-    fn = route if name != "vmap" else (lambda t, n, o, w: jax.vmap(
-        lambda t1, n1: route(t1, n1, o, w))(t, n))
-    text = jax.jit(fn).lower(tree, node, jnp.int32(3), jnp.int32(4)).as_text()
+    fn = route if name != "vmap" else jax.vmap(route)
+    text = jax.jit(fn).lower(tree, node).as_text()
     assert "gather" not in text
     leaf = jax.jit(gbdt._lookup).lower(
         jnp.zeros(cfg.n_nodes, jnp.float32), node.reshape(-1)).as_text()

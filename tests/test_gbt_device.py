@@ -221,9 +221,10 @@ def test_val_metric_aligned_across_builders(rng, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# single-dispatch builds (SHIFU_TPU_TREE_SCAN): the fori_loop-over-
-# levels builder must be BITWISE identical to the per-level host loop,
-# and the resident streaming tier must build each tree in ONE dispatch
+# one growth loop (gbdt._grow_tree): every level's histogram pass is
+# sized to what the level reads, whoever calls it; the whole tree in
+# one jit is BITWISE what the level-by-level dispatches build; the
+# resident single-chunk tier still pays one dispatch a tree
 # ---------------------------------------------------------------------------
 
 def _tree_bitwise(a, b, ctx=""):
@@ -232,84 +233,157 @@ def _tree_bitwise(a, b, ctx=""):
                                       err_msg=f"{ctx}:{k}")
 
 
-@pytest.mark.parametrize("depth", [1, 2, 3, 5])
-@pytest.mark.parametrize("subtract", [False, True])
-def test_scan_tree_bitwise_matches_per_level(rng, monkeypatch, depth,
-                                             subtract):
-    """build_tree with the level scan on vs off: identical histograms
-    scatter in identical row order, the masked folds write identical
-    values, so the whole tree (and the landing nodes) is bit-equal —
-    not allclose, equal."""
-    bins, y = _case(rng, n=700, c=6)
+def _tree_case(rng, n=700, c=6):
+    bins, y = _case(rng, n=n, c=c)
     binsT = jnp.asarray(np.ascontiguousarray(bins.T))
     grad = jnp.asarray(-(y - 0.5))
-    hess = jnp.ones_like(grad)
-    fm = jnp.ones(6, jnp.float32)
-    cfg = _cfg(depth=depth)
-
-    def build(scan):
-        monkeypatch.setenv("SHIFU_TPU_TREE_SCAN", scan)
-        jax.clear_caches()  # scan mode resolves at trace time
-        return gbdt.build_tree(cfg, binsT, grad, hess, fm,
-                               subtract=subtract, return_nodes=True)
-
-    t_loop, n_loop = build("0")
-    t_scan, n_scan = build("1")
-    _tree_bitwise(t_loop, t_scan, f"d{depth}/sub{subtract}")
-    np.testing.assert_array_equal(np.asarray(n_loop), np.asarray(n_scan))
+    return binsT, grad, jnp.ones_like(grad), jnp.ones(c, jnp.float32)
 
 
-@pytest.mark.parametrize("subtract", [False, True])
-def test_scan_forest_bitwise_matches_per_level(rng, monkeypatch,
-                                               subtract):
-    """build_forest (the lockstep multi-tree builder) under the same
-    scan flip — per-tree feature masks and sibling subtraction
-    included."""
-    bins, y = _case(rng, n=600, c=5)
+def _forest_case(rng, n=600, c=5):
+    bins, y = _case(rng, n=n, c=c)
     binsT = jnp.asarray(np.ascontiguousarray(bins.T))
     grad_T = jnp.asarray(np.stack([-y, -y * 0.5, y - 0.3])
                          .astype(np.float32))
-    hess_T = jnp.ones_like(grad_T)
-    masks = jnp.asarray((rng.random((3, 5)) > 0.3).astype(np.float32))
+    masks = jnp.asarray((rng.random((3, c)) > 0.3).astype(np.float32))
+    return binsT, grad_T, jnp.ones_like(grad_T), masks
+
+
+def _kernel_calls(monkeypatch):
+    """Spy on the one place every builder's histogram pass goes
+    through: (slots, columns) of each call, in trace order."""
+    calls = []
+    real = gbdt._local_level_histograms
+
+    def spy(binsT, slot, grad, hess, n_level_nodes, n_bins):
+        calls.append((n_level_nodes, binsT.shape[0]))
+        return real(binsT, slot, grad, hess, n_level_nodes, n_bins)
+
+    monkeypatch.setattr(gbdt, "_local_level_histograms", spy)
+    return calls
+
+
+def _slots_a_level(depth, subtract):
+    """What each level reads: with sibling subtraction the root and
+    then the left children, 1, 1, 2, ..., 2^(D-1); without, the whole
+    level, 1, 2, ..., 2^D."""
+    if subtract:
+        return [1] + [2 ** (d - 1) for d in range(1, depth + 1)]
+    return [2 ** d for d in range(depth + 1)]
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 5])
+@pytest.mark.parametrize("subtract", [False, True])
+@pytest.mark.parametrize("builder", ["tree", "forest"])
+def test_levels_ask_the_kernel_for_their_own_slots(rng, monkeypatch, builder,
+                                                   depth, subtract):
+    """No level's pass is wider than what it reads: the kernel is asked
+    for 1, 1, 2, ... slots, never for the 2^max_depth of the deepest
+    level, and the leaf level for the one column its totals read; the
+    lockstep forest (a vmap of the same pass over trees) asks for the
+    same as the single tree."""
+    case, build = {"tree": (_tree_case, gbdt.build_tree),
+                   "forest": (_forest_case, gbdt.build_forest)}[builder]
+    args = case(rng)
+    calls = _kernel_calls(monkeypatch)
+    jax.eval_shape(lambda *a: build.__wrapped__(
+        _cfg(depth=depth), *a, subtract=subtract), *args)
+    assert [s for s, _ in calls] == _slots_a_level(depth, subtract)
+    assert [c for _, c in calls] == \
+        [args[0].shape[0]] * depth + [gbdt._LEAF_COLUMNS]
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 5])
+@pytest.mark.parametrize("subtract", [False, True])
+def test_one_jit_tree_bitwise_matches_level_dispatches(
+        rng, monkeypatch, depth, subtract):
+    """build_tree (every level unrolled into one jit) against the
+    resident streaming tier's level-by-level dispatches on one chunk
+    (_stream_level_chunk a level, the leaf level on every column): the
+    same histograms scatter in the same row order, so the whole tree
+    and the landing nodes are bit-equal — not allclose, equal."""
+    binsT, grad, hess, fm = _tree_case(rng)
+    cfg = _cfg(depth=depth)
+    monkeypatch.setenv("SHIFU_TPU_HIST_SUBTRACT", "1" if subtract else "0")
+    t_jit, n_jit = gbdt.build_tree(cfg, binsT, grad, hess, fm,
+                                   subtract=subtract, return_nodes=True)
+    node_state = [jnp.zeros(binsT.shape[1], jnp.int32)]
+    t_lvl = gbdt._build_tree_streaming_device(
+        cfg, lambda ci: binsT, 1, node_state, [grad], [hess], fm, None)
+    _tree_bitwise(t_jit, t_lvl, f"d{depth}/sub{subtract}")
+    # the level dispatches route lazily: the last level's routing is
+    # the caller's, as build_gbt_streaming does it
+    n_lvl = gbdt._route_level(cfg, t_lvl, binsT, node_state[0], depth - 1)
+    np.testing.assert_array_equal(np.asarray(n_jit), np.asarray(n_lvl))
+
+
+@pytest.mark.parametrize("subtract", [False, True])
+def test_forest_bitwise_matches_single_trees(rng, subtract):
+    """build_forest's lockstep trees against build_tree a tree, each
+    with its own gradients and feature mask: on the XLA scatter path
+    the vmapped pass adds the same cells in the same order."""
+    binsT, grad_T, hess_T, masks = _forest_case(rng)
     cfg = _cfg(depth=3)
+    trees, node_T = gbdt.build_forest(cfg, binsT, grad_T, hess_T, masks,
+                                      subtract=subtract, return_nodes=True)
+    for t in range(3):
+        one, node = gbdt.build_tree(cfg, binsT, grad_T[t], hess_T[t],
+                                    masks[t], subtract=subtract,
+                                    return_nodes=True)
+        _tree_bitwise(jax.tree.map(lambda a, t=t: a[t], trees), one,
+                      f"tree{t}/sub{subtract}")
+        np.testing.assert_array_equal(np.asarray(node_T[t]),
+                                      np.asarray(node))
 
-    def build(scan):
-        monkeypatch.setenv("SHIFU_TPU_TREE_SCAN", scan)
-        jax.clear_caches()
-        return gbdt.build_forest(cfg, binsT, grad_T, hess_T, masks,
-                                 subtract=subtract, return_nodes=True)
 
-    (t_loop, n_loop), (t_scan, n_scan) = build("0"), build("1")
-    _tree_bitwise(t_loop, t_scan, f"forest/sub{subtract}")
-    np.testing.assert_array_equal(np.asarray(n_loop), np.asarray(n_scan))
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("subtract", [False, True])
+def test_leaf_level_on_its_columns_gives_the_same_leaves(
+        rng, monkeypatch, backend, subtract):
+    """_final_leaves reads column 0's histogram alone, so the leaf
+    level's pass runs on _LEAF_COLUMNS columns: the leaf values are
+    bitwise those of a pass over every column, through the scatter and
+    through the kernel (interpret mode)."""
+    binsT, grad, hess, fm = _tree_case(rng, n=500)
+    cfg = _cfg(depth=3)
+    monkeypatch.setenv("SHIFU_TPU_HIST", backend)
+
+    def build(columns):
+        monkeypatch.setattr(gbdt, "_LEAF_COLUMNS", columns)
+        jax.clear_caches()      # both knobs are read when traced
+        return gbdt.build_tree(cfg, binsT, grad, hess, fm,
+                               subtract=subtract)
+
+    cut, whole = build(1), build(binsT.shape[0])
+    jax.clear_caches()
+    assert np.asarray(cut["is_leaf"])[-2 ** 3:].all()
+    _tree_bitwise(cut, whole, f"{backend}/sub{subtract}")
 
 
 def test_resident_single_chunk_one_dispatch_per_tree(rng, monkeypatch):
-    """THE dispatch gate: a single-chunk resident build with the scan
-    on launches ONE device computation per tree (counted by the
-    pipeline tree_build_dispatches counter); with the scan off it pays
-    one per level plus the final-leaf pass. Trees bitwise identical
-    either way, and the resident zero-host-sync contract holds on
-    both paths."""
+    """THE dispatch gate, with no knob set: a single-chunk resident
+    build launches ONE device computation per tree (counted by the
+    pipeline tree_build_dispatches counter) and keeps the resident
+    zero-host-sync contract; the same rows in two chunks pay one
+    dispatch a chunk a level, for the same splits in the first tree."""
     bins, y = _case(rng, n=800)
     w = np.ones_like(y)
     cfg = _cfg(loss="log")
     n_trees = 3
     monkeypatch.setenv("SHIFU_TPU_GBT_RESIDENT_STATE", "1")
 
-    def run(scan):
-        monkeypatch.setenv("SHIFU_TPU_TREE_SCAN", scan)
-        jax.clear_caches()
+    def run(chunk_rows):
         drain_stage_timers()
         trees, _ = gbdt.build_gbt_streaming(cfg, bins, y, w, n_trees,
-                                            chunk_rows=1 << 20)
+                                            chunk_rows=chunk_rows)
         return trees, drain_stage_timers()
 
-    t_off, timers_off = run("0")
-    t_on, timers_on = run("1")
-    _tree_bitwise(t_off, t_on, "resident")
-    assert timers_on.get("tree_build_dispatches") == n_trees, timers_on
-    assert timers_off.get("tree_build_dispatches") == \
-        n_trees * (cfg.max_depth + 1), timers_off
-    assert timers_on.get("host_syncs", 0) == 0, timers_on
-    assert timers_off.get("host_syncs", 0) == 0, timers_off
+    t_one, timers_one = run(1 << 20)
+    t_two, timers_two = run(400)
+    assert timers_one.get("tree_build_dispatches") == n_trees, timers_one
+    assert timers_two.get("tree_build_dispatches") == \
+        n_trees * 2 * (cfg.max_depth + 1), timers_two
+    assert timers_one.get("host_syncs", 0) == 0, timers_one
+    assert timers_two.get("host_syncs", 0) == 0, timers_two
+    for k in ("feature", "bin", "is_leaf", "default_left"):
+        np.testing.assert_array_equal(t_one[k][0], t_two[k][0], err_msg=k)

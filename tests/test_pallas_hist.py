@@ -156,3 +156,87 @@ def test_fused_gbt_matches_prebinned_same_backend(rng, monkeypatch,
         jax.clear_caches()  # don't leak the pinned backend's traces
 
     _assert_same_ensemble(_tree_arrays(t_int), _tree_arrays(t_fused))
+
+
+# ---------------------------------------------------------------------------
+# every slot count from 1 (gbdt._grow_tree calls the kernel once a level
+# at what that level reads), and G and H in one stacked contraction
+# ---------------------------------------------------------------------------
+
+def _slot_case(rng, n_slots, n=700, c=5, n_bins=16):
+    binsT = rng.integers(0, n_bins, (c, n)).astype(np.int32)
+    slot = rng.integers(-1, n_slots + 2, n).astype(np.int32)
+    grad = rng.normal(0, 1, n).astype(np.float32)
+    hess = rng.uniform(0.5, 1.5, n).astype(np.float32)
+    return binsT, slot, grad, hess, n_bins
+
+
+@pytest.mark.parametrize("n_slots", [1, 2, 8, 64, 128])
+def test_kernel_matches_scatter_at_every_level_width(rng, n_slots):
+    """level_histograms_pallas (interpret mode) == the scatter-add at
+    the slot counts a depth-8 build asks for, the one-slot root and
+    slot counts under a sublane tile among them; rows outside the level
+    (-1, the dump slot and past it) add to no cell."""
+    from shifu_tpu.ops.pallas_hist import level_histograms_pallas
+    binsT, slot, grad, hess, n_bins = _slot_case(rng, n_slots)
+    g0, h0 = _scatter_ref(binsT, slot, grad, hess, n_slots, n_bins)
+    g1, h1 = level_histograms_pallas(
+        jnp.asarray(binsT), jnp.asarray(slot), jnp.asarray(grad),
+        jnp.asarray(hess), n_slots, n_bins, row_tile=128, interpret=True)
+    assert g1.shape == h1.shape == (n_slots, 5, n_bins)
+    np.testing.assert_allclose(np.asarray(g1), g0, rtol=1e-5, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(h1), h0, rtol=1e-5, atol=1e-3)
+
+
+def _two_contractions(binsT, slot, grad, hess, n_slots, n_bins, row_tile):
+    """What the kernel computed before G and H shared an operand: a row
+    tile at a time, the gradient-weighted and the hessian-weighted node
+    one-hot each contracted against the bin one-hot, summed over tiles
+    in order."""
+    c, r = binsT.shape
+    g = jnp.zeros((n_slots, c, n_bins), jnp.float32)
+    h = jnp.zeros((n_slots, c, n_bins), jnp.float32)
+    for a in range(0, r, row_tile):
+        b = binsT[:, a:a + row_tile]
+        onehot = (b[:, None, :] == jnp.arange(n_bins)[None, :, None]
+                  ).astype(jnp.float32).reshape(c * n_bins, -1)
+        node = (slot[None, a:a + row_tile]
+                == jnp.arange(n_slots)[:, None]).astype(jnp.float32)
+        for acc, w in ((0, grad), (1, hess)):
+            part = jax.lax.dot_general(
+                node * w[None, a:a + row_tile], onehot,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32
+            ).reshape(n_slots, c, n_bins)
+            g, h = (g + part, h) if acc == 0 else (g, h + part)
+    return g, h
+
+
+@pytest.mark.parametrize("n_slots", [2, 4, 8, 64])
+def test_stacked_contraction_is_bitwise_two_contractions(rng, n_slots):
+    """G and H ride one (2·S8, rows) operand through one contraction:
+    every cell is the sum of the same products in the same order as
+    with a contraction each, so the histograms are bit-equal (same row
+    tile; rows a multiple of it, as the pad rows add exact zeros). The
+    one-slot case is read on the chip (PERF.md, PR 27): a one-row
+    operand takes the CPU backend's matrix-vector routine, which sums
+    in another order than its matrix product."""
+    from shifu_tpu.ops.pallas_hist import level_histograms_pallas
+    binsT, slot, grad, hess, n_bins = _slot_case(rng, n_slots, n=768)
+    args = [jnp.asarray(a) for a in (binsT, slot, grad, hess)]
+    g0, h0 = _two_contractions(*args, n_slots, n_bins, 128)
+    g1, h1 = level_histograms_pallas(*args, n_slots, n_bins, row_tile=128,
+                                     interpret=True)
+    np.testing.assert_array_equal(np.asarray(g1), np.asarray(g0))
+    np.testing.assert_array_equal(np.asarray(h1), np.asarray(h0))
+
+
+def test_tile_budget_counts_the_stacked_operand():
+    """derive_tiles reckons the (2·S8, TR) stacked operand and the
+    (2·S8, TC·B) output block: at the cell's shape the row tile stays
+    512 at every slot count a depth-8 build asks for, and a budget the
+    stacked buffers overrun halves it."""
+    from shifu_tpu.ops.pallas_hist import derive_tiles
+    for s in (1, 8, 64, 128, 256):
+        assert derive_tiles(28, s, 64) == (512, 28)
+    assert derive_tiles(28, 4096, 64)[0] < 512
