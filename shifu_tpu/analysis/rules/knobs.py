@@ -10,10 +10,8 @@ Flags, per file:
     itself — route it through `knob_int`/`knob_float`/`knob_str`/
     `knob_bool`/`knob_raw` so typing and defaults live in one place.
 
-Flags, cross-file (finalize): a registry entry with scope="package"
-that no scanned file ever references by name — a dead knob. Entries
-with other scopes (bench/tools) are exempt when only the package tree
-is scanned; `tools/lint.sh` scans those files too.
+Flags, cross-file (finalize): a registry entry that no scanned file
+ever references by name — a dead knob.
 """
 
 from __future__ import annotations
@@ -95,9 +93,7 @@ def finalize(ctx: dict) -> List[Finding]:
     if not ctx.get("knob-registry-scanned"):
         return findings
     seen: Set[str] = ctx.get("knob-refs", set())
-    for name, knob in sorted(_registry().items()):
-        if knob.scope != "package":
-            continue
+    for name in sorted(_registry()):
         if name not in seen:
             findings.append(Finding(
                 "undeclared-knob", "config/environment.py", 0, 0,

@@ -675,9 +675,6 @@ def cmd_knobs(args) -> int:
             print(knobs_markdown(), end="")
             return 0
         rows = knobs_rows()
-        if not getattr(args, "all", False):
-            rows = [r for r in rows
-                    if r["scope"] == "package" or r["current"]]
         name_w = max(len(r["name"]) for r in rows)
         type_w = max(len(r["type"]) for r in rows)
         dflt_w = max(max(len(r["default"]) for r in rows), len("default"))
@@ -903,8 +900,6 @@ def build_parser() -> argparse.ArgumentParser:
     p = sub.add_parser("knobs",
                        help="list every SHIFU_TPU_* knob (type/default/"
                             "current/doc)")
-    p.add_argument("--all", action="store_true",
-                   help="include bench/tools-scoped knobs even when unset")
     p.add_argument("--markdown", action="store_true",
                    help="emit the markdown table (same as python -m "
                         "shifu_tpu.analysis --knobs-md)")

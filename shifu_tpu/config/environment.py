@@ -97,25 +97,19 @@ def load_shifuconfig(shifu_home: Optional[str] = None) -> Dict[str, str]:
 #
 # `default=None` means "unset = auto/off" — the reading site owns the
 # contextual fallback (e.g. SHIFU_TPU_MESH_DEVICES unset = all devices).
-# `scope` says where the knob is read: "package" entries must be
-# referenced inside shifu_tpu/ itself; "bench"/"tools" entries live in
-# bench.py / tools/ and are exempt from the dead-entry check when only
-# the package is scanned.
 
 class Knob(NamedTuple):
     name: str
     type: str            # int | float | str | bool | flag
     default: object      # documented default; None = unset (auto/off)
     doc: str
-    scope: str = "package"
 
 
 KNOBS: "Dict[str, Knob]" = {}
 
 
-def _declare(name: str, type_: str, default, doc: str,
-             scope: str = "package") -> None:
-    KNOBS[name] = Knob(name, type_, default, doc, scope)
+def _declare(name: str, type_: str, default, doc: str) -> None:
+    KNOBS[name] = Knob(name, type_, default, doc)
 
 
 # --- resilience / retries / faults ---
@@ -427,61 +421,6 @@ _declare("SHIFU_TPU_INGEST_WINDOW_ROWS", "int", 65_536,
          "max rows one `shifu watch --ingest` tick consumes from the "
          "row log per read_window (the drift window size cap; the "
          "rest stays committed for the next tick)")
-# --- bench / tools (read outside the package) ---
-_declare("SHIFU_TPU_BENCH_ATTEMPTS", "int", 2,
-         "re-measure attempts per bench workload", scope="bench")
-_declare("SHIFU_TPU_BENCH_PROBE_TIMEOUT_S", "int", 300,
-         "per-attempt timeout for the bench backend probe subprocess",
-         scope="bench")
-_declare("SHIFU_TPU_BENCH_PROBE_ATTEMPTS", "int", 3,
-         "backend probe attempts before falling back to cpu",
-         scope="bench")
-_declare("SHIFU_TPU_BENCH_FALLBACK_REASON", "str", None,
-         "why this bench run fell back off the default backend; set "
-         "by the probe (not by hand) so every BENCH_LOCAL.jsonl "
-         "record persisted afterwards — including from task "
-         "subprocesses — stamps probe.fallback_reason and "
-         "tools/bench_regress.py keeps fallback records out of the "
-         "genuine hardware trend", scope="bench")
-_declare("SHIFU_TPU_BENCH_REFRESH", "flag", "0",
-         "1 = re-measure even when a baseline record exists",
-         scope="bench")
-_declare("SHIFU_TPU_BENCH_STREAMING", "bool", "1",
-         "0 = skip the streaming-trainer bench workload",
-         scope="bench")
-_declare("SHIFU_TPU_DIST_STATS_ROWS", "int", 400_000,
-         "row count for the dist_stats bench table", scope="bench")
-_declare("SHIFU_TPU_DIST_STATS_HOSTS", "int", 2,
-         "subprocess host count for the dist_stats bench",
-         scope="bench")
-_declare("SHIFU_TPU_RF_ROWS", "int", 11_000_000,
-         "row count for the RF bench workload", scope="bench")
-_declare("SHIFU_TPU_RF_TREES", "int", 40,
-         "tree count for the RF bench workload", scope="bench")
-_declare("SHIFU_TPU_STREAM_ROWS", "int", 15_000_000,
-         "row count for the streaming-trainer bench", scope="bench")
-_declare("SHIFU_TPU_STREAM_FEATURES", "int", 300,
-         "feature count for the streaming-trainer bench",
-         scope="bench")
-_declare("SHIFU_TPU_STREAM_CHUNK_ROWS", "int", 262_144,
-         "chunk rows for the streaming-trainer bench", scope="bench")
-_declare("SHIFU_TPU_PIPE_ROWS", "int", 1_000_000,
-         "row count for the input-pipeline bench", scope="bench")
-_declare("SHIFU_TPU_PIPE_EPOCHS", "int", 30,
-         "epochs for the input-pipeline bench", scope="bench")
-_declare("SHIFU_TPU_GBT_TRACE", "flag", "0",
-         "1 = capture a jax.profiler trace in tools/profile_gbt.py",
-         scope="tools")
-_declare("SHIFU_TPU_SERVE_BENCH_QPS", "float", 200.0,
-         "offered Poisson arrival rate for the serving bench",
-         scope="bench")
-_declare("SHIFU_TPU_SERVE_BENCH_SECONDS", "float", 8.0,
-         "open-loop load duration for the serving bench",
-         scope="bench")
-_declare("SHIFU_TPU_FLEET_BENCH_MODELS", "int", 3,
-         "registry models served by the fleet bench", scope="bench")
-_declare("SHIFU_TPU_FLEET_BENCH_SECONDS", "float", 6.0,
-         "diurnal load duration for the fleet bench", scope="bench")
 
 
 # ---------------------------------------------------------------------------
@@ -579,14 +518,14 @@ def knob_bool(name: str, default: Optional[bool] = None) -> bool:
 
 def knobs_rows() -> List[dict]:
     """One row per declared knob: name, type, default, current value
-    (unset → ''), doc, scope — the `shifu knobs` table."""
+    (unset → ''), doc — the `shifu knobs` table."""
     rows = []
     for k in sorted(KNOBS.values()):
         cur = os.environ.get(k.name)
         rows.append({"name": k.name, "type": k.type,
                      "default": "" if k.default is None else str(k.default),
                      "current": "" if cur is None else cur,
-                     "doc": k.doc, "scope": k.scope})
+                     "doc": k.doc})
     return rows
 
 

@@ -225,7 +225,7 @@ def _record_train_roofline(spec: nn_mod.MLPSpec, n_rows: int,
     analytic per-row costs from the trained spec combined with the
     measured row-epochs/s (profiling.roofline). Wall covers the whole
     train loop (compile included), so the utilization figures are a
-    floor — the bench's delta-timed numbers are the sharp ones."""
+    floor."""
     from shifu_tpu import profiling
     try:
         n_train = max(int(n_rows * (1 - (valid_rate or 0.0))), 1)

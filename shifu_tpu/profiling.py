@@ -397,9 +397,9 @@ def step_metrics(root: str, step: str, extra: Optional[Dict] = None):
 # Analytic per-row FLOPs and bytes-moved are derived from the model
 # spec alone, so the same numbers describe every backend; utilization
 # divides measured throughput by the peaks of the device the run was
-# on, looked up in DEVICE_PEAKS. bench.py emits one `roofline` block
-# per task and tools/check_steps_schema.py pins README docs to
-# ROOFLINE_FIELDS.
+# on, looked up in DEVICE_PEAKS. processor/train.py attaches one
+# `roofline` block to the train step's steps.jsonl record, and
+# tools/check_steps_schema.py pins README docs to ROOFLINE_FIELDS.
 
 # Published per-chip peaks keyed by jax's `device_kind`. Source: Google
 # Cloud TPU documentation, "TPU v5e" system architecture — 197 TFLOP/s
@@ -434,73 +434,14 @@ ROOFLINE_FIELDS = ("family", "compute_dtype", "flops_per_row",
                    "bytes_per_s", "arith_intensity", "ridge_intensity",
                    "mxu_util", "hbm_util", "bound")
 
-# the serving bench's record schema (bench.py task_serving builds its
-# JSON line from exactly these keys, plus the shared `roofline` block);
+# the FleetService summary schema: serve/fleet.py builds its
+# stats()["fleet"] block from exactly these keys — resident model
+# count, LRU evictions, total re-warm seconds, the low-priority shed
+# fraction, per-priority-class p99 latency, and the hot-swap counters.
 # tools/check_steps_schema.py pins README docs to this tuple the same
 # way it pins ROOFLINE_FIELDS.
-SERVING_FIELDS = ("qps_offered", "qps_sustained", "requests",
-                  "rejected", "rows_per_s", "p50_ms", "p95_ms",
-                  "p99_ms", "batch_occupancy", "rows_per_batch",
-                  "serve_warm_s", "device_step_budget_ms",
-                  "compile_cache_misses_steady")
-
-# the tree-serving bench's extra keys (bench.py task_serving_tree
-# emits SERVING_FIELDS plus exactly these, plus a per-request-size
-# p99_ms_by_class map and the shared `roofline` block): the route the
-# service actually served on (SHIFU_TPU_TREE_FUSED resolution), the
-# A/B batch-predict throughput of the fused ensemble kernel vs the
-# interpretive bin_dataset+walk reference, and their ratio —
-# tools/bench_regress.py gates fused_speedup ≥ 1 on TPU records and
-# tools/check_steps_schema.py pins README docs to this tuple the same
-# way it pins SERVING_FIELDS.
-TREE_SERVE_FIELDS = ("tree_route", "fused_rows_per_s",
-                     "xla_rows_per_s", "fused_speedup")
-
-# the fleet bench / FleetService summary schema: serve/fleet.py builds
-# its stats()["fleet"] block (and bench.py task_fleet its JSON record)
-# from exactly these keys — resident model count, LRU evictions, total
-# re-warm seconds, the low-priority shed fraction, and per-priority-
-# class p99 latency. tools/check_steps_schema.py pins README docs to
-# this tuple the same way it pins SERVING_FIELDS.
 FLEET_FIELDS = ("models_resident", "evictions", "rewarm_s",
                 "shed_rate", "p99_ms_by_class", "swaps", "swap_s")
-
-# the continuous-refresh bench record schema: bench.py --task refresh
-# builds its JSON record from exactly these keys — wall seconds from
-# the injected breach to the promoted challenger, the in-place swap
-# vs a cold re-warm of the same version, compile-cache misses during
-# the swap (must be zero — the hot path never recompiles), and the
-# guardrail verdict. tools/check_steps_schema.py pins README docs to
-# this tuple the same way it pins FLEET_FIELDS.
-REFRESH_FIELDS = ("breach_to_promoted_s", "swap_s", "rewarm_s",
-                  "swap_compile_misses", "guardrail")
-
-# the streaming-ingest bench record schema: bench.py --task ingest
-# builds its JSON record from exactly these keys — rows appended,
-# sustained append throughput through the sealing row log, segments
-# sealed, wall seconds from appending a drifted batch to the drift
-# monitor's breach snapshot off a committed read_window, and whether a
-# re-read of the same committed range (fresh RowLog handle) was
-# byte-identical. tools/check_steps_schema.py pins README docs to this
-# tuple the same way it pins REFRESH_FIELDS.
-INGEST_FIELDS = ("rows", "rows_per_s", "segments",
-                 "breach_latency_s", "bitwise_identical")
-
-# the live-promotion bench record schema: bench.py --task canary
-# builds its JSON record from exactly these keys — wall seconds from
-# the injected breach to the live-arm verdict (shadow + canary phases
-# included), wall seconds from a sabotaged canary's breach verdict to
-# the fleet serving the re-pinned incumbent again, requests the
-# concurrent client FAILED during both cycles (tools/bench_regress.py
-# gates this == 0 absolutely and the rollback latency against its
-# trailing median), per-arm request counts, the final
-# score-distribution PSI between arms, and the two verdicts.
-# tools/check_steps_schema.py pins README docs to this tuple the same
-# way it pins REFRESH_FIELDS.
-CANARY_FIELDS = ("breach_to_live_s", "rollback_recovery_s",
-                 "failed_requests", "shadow_requests",
-                 "canary_requests", "arm_psi", "promote_verdict",
-                 "rollback_verdict")
 
 # the pipeline DAG scheduler's record schema: a scheduled step attaches
 # one `dag` block to its steps.jsonl record — DAG_SUMMARY_FIELDS are
@@ -519,19 +460,6 @@ DAG_FIELDS = ("node", "state", "deps", "queue_s", "run_s", "devices",
 DAG_SUMMARY_FIELDS = ("workers", "total_devices", "wall_s",
                       "critical_path_s", "occupancy", "max_concurrent",
                       "failed", "nodes")
-
-# bench task_pipeline's sliced-vs-timeshared A/B block: bench.py builds
-# the record's `slice` sub-dict from exactly this tuple — device slices
-# leased over the whole sliced DAG run, peak concurrently-running
-# device nodes, the slice-weighted occupancy of that run, and the
-# wall-clock speedup of disjoint-slice concurrency over the timeshared
-# sequential schedule (tools/bench_regress.py gates sliced_speedup ≥ 1
-# on TPU records — CPU exempt, the fake devices share cores — and
-# artifact parity between the two legs hard-fails the record's
-# top-level bitwise_identical). tools/check_steps_schema.py pins README
-# docs to this tuple the same way it pins REFRESH_FIELDS.
-SLICE_FIELDS = ("slices_leased", "max_concurrent", "occupancy",
-                "sliced_speedup")
 
 # the span tracer's per-step summary block: obs/trace.py attaches one
 # `trace` block (built from exactly this tuple) to the steps.jsonl
@@ -557,21 +485,6 @@ METRIC_FIELDS = ("ts", "name", "value", "kind", "tags")
 # window. Pinned in README by tools/check_steps_schema.py.
 HEALTH_FIELDS = ("slo", "metric", "state", "value", "warn", "breach",
                  "window_s")
-
-# the pod-scale data plane bench's record schema: bench.py
-# task_dist_stats builds its JSON line from exactly these keys —
-# subprocess-host count, rows processed, N-host and 1-host stats
-# throughput (in-step wall), scaling efficiency c_1 / (N · c_N) over
-# PER-HOST CPU SECONDS of the step (1.0 = perfect work split; CPU
-# basis because the bench rig's simulated hosts timeshare one
-# machine's cores, where wall clock cannot show the split — on a real
-# pod the two bases coincide), seconds spent in the watched merge
-# collectives (dist_merge_s stage timer), and whether the sharded
-# ColumnConfig.json hashed identical to the single-host run. Pinned
-# in README by tools/check_steps_schema.py.
-SHARD_FIELDS = ("hosts", "rows", "rows_per_s", "rows_per_s_1host",
-                "scaling_efficiency", "merge_collective_s",
-                "bitwise_identical")
 
 
 def mlp_row_costs(input_dim: int, hidden_dims, n_out: int = 1,
@@ -613,14 +526,6 @@ def wdl_row_costs(dense_dim: int, n_cat: int, embed_size: int,
     bytes_ += dtype_bytes * int(n_cat) * int(embed_size) * \
         (3 if train else 1)
     return float(flops), float(bytes_)
-
-
-def mtl_row_costs(input_dim: int, hidden_dims, n_tasks: int,
-                  train: bool = True, dtype_bytes: int = 4):
-    """MTL = shared trunk MLP + one linear head per task; exactly an
-    MLP whose output width is the task count."""
-    return mlp_row_costs(input_dim, hidden_dims, int(n_tasks), train,
-                         dtype_bytes)
 
 
 def tree_row_costs(n_cols: int, n_bins: int, max_depth: int,
@@ -668,7 +573,7 @@ def roofline(family: str, flops_per_row: float, bytes_per_row: float,
              peak_flops: Optional[float] = None,
              peak_bytes_per_s: Optional[float] = None) -> Dict:
     """Combine analytic per-row costs with a measured rows/s into the
-    `roofline` block (steps.jsonl + bench JSON): achieved flops_per_s /
+    `roofline` block (steps.jsonl): achieved flops_per_s /
     bytes_per_s, arithmetic intensity vs the ridge point, and MXU/HBM
     utilization that say whether the shape is compute- or
     bandwidth-bound. Peaks come from `device_peaks(device_kind)` — the

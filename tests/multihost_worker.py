@@ -190,18 +190,11 @@ if args.mode in ("stats", "stats-kill", "corr"):
         # mid-merge SIGKILL drill; the survivor must exit through the
         # watchdog/poison machinery, never hang
         os.environ["SHIFU_TPU_FAULT"] = "dist.allreduce_tree:kill:1"
-    import time
-    t0 = time.process_time()
     cmd = ["--dir", args.out, "stats"]
     if args.mode == "corr":
         cmd.append("-correlation")
     try:
         rc = cli_main(cmd)
-        # this process's CPU seconds for the step — bench.py's
-        # dist_stats scaling-efficiency basis (robust to a test rig
-        # with fewer cores than simulated hosts, where wall clock
-        # cannot show the work split)
-        print(f"STATS_CPU_S {time.process_time() - t0:.3f}", flush=True)
     except dist.DistTimeout as e:
         print(f"DIST_TIMEOUT: {e}", file=sys.stderr, flush=True)
         os._exit(17)

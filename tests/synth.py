@@ -178,3 +178,38 @@ def make_model_set(tmp_path, rng, n_rows: int = 2000, norm_type: str = "ZSCALE",
     with atomic_write(os.path.join(root, "ModelConfig.json"), "w") as f:
         json.dump(mc, f, indent=2)
     return root
+
+
+def planted_linear_table(rng, n_rows: int, beta, scale: float = 1.0):
+    """(x, y, w) float32: unit Gaussian features under a planted linear
+    margin — y = [x·beta·scale + N(0, 1) > 0]; `beta` (F,) for one
+    label, (F, T) for T labels a row."""
+    beta = np.asarray(beta, np.float32)
+    x = rng.normal(0, 1, (n_rows, beta.shape[0])).astype(np.float32)
+    margin = x @ beta * np.float32(scale)
+    y = (margin + rng.normal(0, 1, margin.shape) > 0).astype(np.float32)
+    return x, y, np.ones(n_rows, np.float32)
+
+
+def planted_binned_table(rng, n_rows: int, n_cols: int, n_bins: int):
+    """(bins (R, C) int32 in [0, n_bins - 1), y, w): uniform bin ids
+    under a planted linear margin over the ids, half its spread in
+    noise, labels split at the margin's median."""
+    bins = rng.integers(0, n_bins - 1, (n_rows, n_cols)).astype(np.int32)
+    margin = bins.astype(np.float32) @ rng.normal(0, 1, n_cols) \
+        / np.sqrt(n_cols)
+    noise = rng.normal(0, 1, n_rows) * margin.std() * 0.5
+    y = (margin + noise > np.median(margin)).astype(np.float32)
+    return bins, y, np.ones(n_rows, np.float32)
+
+
+def planted_wdl_table(rng, n_rows: int, n_dense: int, n_cat: int,
+                      vocab: int):
+    """(dense, idx, y, w): the label leans on one dense column and on
+    planted effects of the first two categorical columns' ids."""
+    dense = rng.normal(0, 1, (n_rows, n_dense)).astype(np.float32)
+    idx = rng.integers(0, vocab, (n_rows, n_cat)).astype(np.int32)
+    effect = rng.normal(0, 1, vocab).astype(np.float32)
+    margin = dense[:, 0] * 0.8 + effect[idx[:, 0]] + effect[idx[:, 1]] * 0.5
+    y = (margin + rng.normal(0, 1, n_rows) > 0).astype(np.float32)
+    return dense, idx, y, np.ones(n_rows, np.float32)

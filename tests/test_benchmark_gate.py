@@ -1,0 +1,64 @@
+"""Tier-1's view of the one benchmark (`BENCHMARK.json`, `benchmark/`).
+
+The driver judges every PR by `python3 benchmark/run.py` on the chip, and
+its test command runs `tests/` only, so this file is the bridge: a black
+box over the command line the driver uses, one pair of cases a cell, the
+cells read from the manifest. A change to `train_nn`, `build_gbt` or
+`train_wdl` that breaks a cell's entry fails here on the CPU and not at
+the cost of a chip run; and a benchmark that would measure on a CPU,
+which the replay bench this one replaced did, fails here too.
+`benchmark/tests/` holds the harness's own tests; nothing is imported
+from there.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    MANIFEST = json.load(_f)
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def _run(cell, *extra):
+    """One run of the harness as the driver starts it, on a CPU that
+    shows one device (the test rig's own eight would not be the cell's
+    `chips`)."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, *MANIFEST["command"][1:], "--workload", cell,
+         "--seed", "3000000028", "--seconds", "0.5", *extra],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_rehearses_and_is_correct(cell):
+    """`--rehearse` drives the cell's whole path at its rehearsal sizes:
+    data from the seed, the program's entry, the plain reference. The
+    run is `correct`, every compared number is within its limit, and no
+    device metric is printed: a CPU's number never carries one's name."""
+    r = _run(cell, "--rehearse")
+    assert r.returncode == 0, r.stderr[-2000:]
+    result = json.loads(r.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    assert result["metrics"] == {}
+    assert result["device"]["platform"] == "cpu"
+    assert result["checks"]
+    for name, check in result["checks"].items():
+        assert check["value"] <= check["limit"], (name, check)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_is_refused_without_the_chip(cell):
+    """Asked to measure, with no accelerator: a non-zero exit and no
+    result line. There is no CPU fallback to mistake for the chip."""
+    r = _run(cell)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "no accelerator" in r.stderr

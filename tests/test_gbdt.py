@@ -511,9 +511,9 @@ def test_bf16_truncation_bound_on_histograms(rng):
     """The DEFAULT-precision MXU path truncates grad/hess inputs to
     bf16 (the one-hot side is exact). Emulate exactly that truncation
     and bound the histogram error — the CI-side evidence for the
-    '~0.3% relative' claim in ops/pallas_hist.py (ADVICE r2 low #1);
-    the hardware path itself is covered by `bench.py --task hist_pallas`
-    vs `hist_xla` checksums on the real chip."""
+    '~0.3% relative' claim in ops/pallas_hist.py; on the chip the
+    kernel is held to the plain reference by `gbt-higgs.train`'s
+    `correct` (benchmark/families/gbt_reference.py)."""
     import jax.numpy as jnp
 
     from shifu_tpu.models.gbdt import _level_histograms

@@ -459,45 +459,6 @@ def test_health_plane_faults_absorbed(tmp_path, monkeypatch, site):
 
 
 # ---------------------------------------------------------------------------
-# bench-history regression gate (tools/bench_regress.py)
-# ---------------------------------------------------------------------------
-
-def _bench_log(tmp_path, *recs):
-    path = tmp_path / "bench.jsonl"
-    path.write_text("".join(json.dumps(r) + "\n" for r in recs))
-    return str(path)
-
-
-def test_bench_regress_flags_drop_and_bound_flip(tmp_path):
-    import importlib
-    br = importlib.import_module("tools.bench_regress")
-
-    def rec(ts, tput, bound=None):
-        r = {"task": "nn", "backend": "tpu", "ts": ts,
-             "row_epochs_per_sec": tput}
-        if bound:
-            r["roofline"] = {"bound": bound}
-        return r
-
-    # newest holds within threshold → clean
-    log = _bench_log(tmp_path, rec(1, 100.0), rec(2, 110.0),
-                     rec(3, 95.0))
-    assert br.main(["--log", log]) == 0
-    # newest drops >20% below the trailing median → finding
-    log = _bench_log(tmp_path, rec(1, 100.0), rec(2, 110.0),
-                     rec(3, 70.0))
-    assert br.main(["--log", log]) == 1
-    # throughput held but the roofline bound flipped → finding
-    log = _bench_log(tmp_path, rec(1, 100.0, "compute"),
-                     rec(2, 102.0, "compute"), rec(3, 101.0, "memory"))
-    assert br.main(["--log", log]) == 1
-    # a single trailing record is not a baseline; absent log is clean
-    log = _bench_log(tmp_path, rec(1, 100.0), rec(2, 10.0))
-    assert br.main(["--log", log]) == 0
-    assert br.main(["--log", str(tmp_path / "absent.jsonl")]) == 0
-
-
-# ---------------------------------------------------------------------------
 # webhook alert sink: a REAL bounded-timeout HTTP POST, retried through
 # the obs.webhook site, absorbed by the alert fan-out when dead
 # ---------------------------------------------------------------------------
@@ -568,27 +529,3 @@ def test_dead_webhook_never_fails_the_watch_tick(tmp_path, monkeypatch,
     alerts = os.path.join(root, "tmp", "metrics", "alerts.jsonl")
     recs = [json.loads(l) for l in open(alerts) if l.strip()]
     assert recs and recs[-1]["slo"] == "lat"
-
-
-def test_bench_regress_gates_refresh_invariants(tmp_path):
-    """The refresh record's gates are absolute (no trailing history
-    needed): swap cheaper than re-warm, zero swap compile misses,
-    guardrail verdict promote."""
-    import importlib
-    br = importlib.import_module("tools.bench_regress")
-
-    def rec(**kw):
-        r = {"task": "refresh", "backend": "cpu", "ts": 1,
-             "breach_to_promoted_s": 30.0, "swap_s": 0.01,
-             "rewarm_s": 1.2, "swap_compile_misses": 0,
-             "guardrail": {"decision": "promote"}}
-        r.update(kw)
-        return r
-
-    assert br.main(["--log", _bench_log(tmp_path, rec())]) == 0
-    assert br.main(["--log", _bench_log(
-        tmp_path, rec(swap_s=2.0))]) == 1           # lost to re-warm
-    assert br.main(["--log", _bench_log(
-        tmp_path, rec(swap_compile_misses=3))]) == 1  # swap recompiled
-    assert br.main(["--log", _bench_log(
-        tmp_path, rec(guardrail={"decision": "hold"}))]) == 1

@@ -188,6 +188,25 @@ def test_kernel_matches_scatter_at_every_level_width(rng, n_slots):
     np.testing.assert_allclose(np.asarray(h1), h0, rtol=1e-5, atol=1e-3)
 
 
+def test_both_routes_agree_through_level_histograms(rng, monkeypatch):
+    """`gbdt._level_histograms` as a tree build calls it, once on each
+    route `SHIFU_TPU_HIST` selects — the scatter-add and the kernel at
+    the tiles it derives for itself (interpret mode here) — on one
+    input with rows outside the level: the same histograms."""
+    binsT, slot, grad, hess, n_bins = _slot_case(rng, 8, n=5_000, c=8,
+                                                 n_bins=8)
+    args = [jnp.asarray(a) for a in (binsT, slot, grad, hess)]
+    got = {}
+    for mode in ("xla", "pallas"):
+        monkeypatch.setenv("SHIFU_TPU_HIST", mode)
+        got[mode] = gbdt._level_histograms(*args, 0, 8, n_bins)
+    for a, b in zip(got["xla"], got["pallas"]):
+        assert a.shape == (8, 8, n_bins)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=1e-5, atol=1e-3)
+    assert float(jnp.abs(got["xla"][1]).sum()) > 0
+
+
 def _two_contractions(binsT, slot, grad, hess, n_slots, n_bins, row_tile):
     """What the kernel computed before G and H shared an operand: a row
     tile at a time, the gradient-weighted and the hessian-weighted node

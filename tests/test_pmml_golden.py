@@ -10,21 +10,26 @@ semantics change in BOTH writer and evaluator still trips the test.
 Regenerate (after an intentional format change):
     python tests/test_pmml_golden.py regen
 
-The goldens pin the full seeded *training trajectory*, not just the
-writer: any intentional optimizer/trainer change legitimately shifts
-trained weights and requires a regen (last: 2026-08, post-seed trainer
-changes drifted lr/nn weights; gbt structure was unaffected). A regen
-is only trustworthy because three gates validate it independently of
-the pinned trajectory: structural compare at 2e-3 relative tolerance,
-the score sidecar (rtol=2e-3 / atol=2e-4), and the independent
-evaluator in pmml_external_eval.py agreeing with the sidecar at
-rtol=1e-6 / atol=1e-4 — a writer bug that survives all three would
-have to corrupt weights, scores, and an unrelated evaluator the same
-way.
+`test_pmml_matches_golden` is a test of the *writer*: it exports from
+the parameters checked in beside each golden
+(`tests/golden/<kind>.params.npz`, the model file the seeded trainer
+wrote at regen time) on a freshly derived ColumnConfig, so a trainer
+change cannot move it; only the writer, the stats the document quotes
+or the evaluator can. The trained trajectory is guarded apart, by
+`test_fresh_export_scores_with_independent_evaluator`: a fresh
+training run's export must score the same through the built-in and the
+independent evaluator. A regen is trustworthy because three gates
+validate it independently of each other: structural compare at 2e-3
+relative tolerance, the score sidecar (rtol=2e-3 / atol=2e-4), and the
+independent evaluator in pmml_external_eval.py agreeing with the
+sidecar at rtol=1e-6 / atol=1e-4 — a writer bug that survives all
+three would have to corrupt weights, scores, and an unrelated
+evaluator the same way.
 """
 
 import json
 import os
+import shutil
 import sys
 import xml.etree.ElementTree as ET
 
@@ -47,9 +52,10 @@ FIXTURES = {
 }
 
 
-def _build_fixture(tmp_dir, kind):
-    """Deterministic model set + trained model + PMML export. The rng
-    is seeded per-kind, independent of the test session."""
+def _build_fixture(tmp_dir, kind, params=None):
+    """Deterministic model set + model + PMML export. The rng is
+    seeded per-kind, independent of the test session. The model is
+    trained here, or, given `params` (a model file), placed as it is."""
     from tests.synth import make_model_set
     from shifu_tpu.cli import main as cli_main
     from shifu_tpu.processor.base import ProcessorContext
@@ -64,19 +70,27 @@ def _build_fixture(tmp_dir, kind):
     mc = json.load(open(mcp))
     mc["train"]["numTrainEpochs"] = 12
     json.dump(mc, open(mcp, "w"))
-    for cmd in (["init"], ["stats"], ["norm"], ["train"],
-                ["export", "-t", "pmml"]):
-        assert cli_main(["--dir", root] + cmd) == 0
+    steps = ["init", "stats"] + (["norm", "train"] if params is None else [])
+    for step in steps:
+        assert cli_main(["--dir", root, step]) == 0
     ctx = ProcessorContext.load(root)
+    if params is not None:
+        model_path = ctx.path_finder.model_path(0)
+        ctx.path_finder.ensure(model_path)
+        shutil.copyfile(params, model_path)
+    assert cli_main(["--dir", root, "export", "-t", "pmml"]) == 0
     pmml_path = ctx.path_finder.pmml_path(0)
     # expected scores over a fixed probe frame, via the built-in
     # evaluator (sidecar-pinned at generation time)
     from shifu_tpu import pmml as pmml_mod
-    import pandas as pd
     from shifu_tpu.data.reader import read_raw_table
     df = read_raw_table(ctx.model_config).head(25)
     scores = pmml_mod.evaluate_pmml(open(pmml_path).read(), df)
     return root, pmml_path, np.asarray(scores, np.float64)
+
+
+def _golden_params(kind):
+    return os.path.join(GOLDEN, f"{kind}.params.npz")
 
 
 def _canonical(el):
@@ -141,12 +155,13 @@ def _assert_internal_external_agree(xml, df):
 
 
 @pytest.mark.parametrize("kind", sorted(FIXTURES))
-def test_pmml_matches_golden(built_fixtures, kind):
+def test_pmml_matches_golden(tmp_path, kind):
     golden_xml = os.path.join(GOLDEN, f"{kind}.pmml")
     golden_scores = os.path.join(GOLDEN, f"{kind}.scores.json")
     assert os.path.exists(golden_xml), \
         "golden missing — run: python tests/test_pmml_golden.py regen"
-    _, pmml_path, scores = built_fixtures(kind)
+    _, pmml_path, scores = _build_fixture(str(tmp_path), kind,
+                                          params=_golden_params(kind))
     got = ET.parse(pmml_path).getroot()
     want = ET.parse(golden_xml).getroot()
     _assert_same_structure(got, want)
@@ -195,11 +210,13 @@ def regen():
     for kind in sorted(FIXTURES):
         with tempfile.TemporaryDirectory() as td:
             root, pmml_path, scores = _build_fixture(td, kind)
+            ctx = ProcessorContext.load(root)
+            shutil.copyfile(ctx.path_finder.model_path(0),
+                            _golden_params(kind))
             with open(pmml_path) as f:
                 xml = f.read()
             with open(os.path.join(GOLDEN, f"{kind}.pmml"), "w") as f:
                 f.write(xml)
-            ctx = ProcessorContext.load(root)
             df = read_raw_table(ctx.model_config).head(25)
             with open(os.path.join(GOLDEN, f"{kind}.scores.json"),
                       "w") as f:
@@ -213,6 +230,7 @@ def regen():
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "regen":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        sys.path.insert(0, REPO)
         regen()
 
 

@@ -16,27 +16,18 @@ the README describing fields that no longer appear in the logs.
 
 Token heuristic: backticked lowercase identifiers ending in ``_s``,
 ``_hits`` or ``_misses`` are treated as stage fields; ``*per_s`` /
-``*_frac`` tokens are bench.py record keys, not steps.jsonl stages,
-and are skipped.
+``*_frac`` tokens are rates and shares, not steps.jsonl stages, and
+are skipped.
 
-The ``roofline`` block (train-step records + every bench.py task
-record) is pinned the same way: its schema is the single
+The ``roofline`` block (processor/train.py attaches one to the train
+step's record) is pinned the same way: its schema is the single
 ``profiling.ROOFLINE_FIELDS`` tuple (AST-read, no imports), every
-field must be documented in README's Raw speed section, and any
-backticked README token that LOOKS like a roofline field (matches a
-member) is cross-checked so a renamed field fails here before it
-ships stale docs.
-
-The serving bench record is pinned likewise: its schema is
-``profiling.SERVING_FIELDS`` (AST-read), every field must be
-README-documented, and bench.py must build the record from the tuple.
-The tree-serving bench (task_serving_tree) extends that record with
-``profiling.TREE_SERVE_FIELDS``, pinned the same way.
+field must be documented in README, and a live log's block must
+carry exactly those keys.
 
 The fleet summary block is pinned likewise: ``stats()["fleet"]`` from
-serve/fleet.py and the bench.py task_fleet record are both
-``profiling.FLEET_FIELDS``, every field must be README-documented,
-and both builders must reference the tuple.
+serve/fleet.py is ``profiling.FLEET_FIELDS``, every field must be
+README-documented, and the builder must reference the tuple.
 
 The ``dag`` block (every command routed through the pipeline DAG
 scheduler) is pinned the same way: per-node records are
@@ -50,26 +41,6 @@ The ``trace`` block (attached to every step run with
 ``SHIFU_TPU_TRACE=1``) is pinned likewise: its schema is
 ``profiling.TRACE_FIELDS``, every member must be README-documented,
 and obs/trace.py must build the block from the tuple.
-
-The pod-scale data plane bench is pinned likewise: bench.py
-task_dist_stats builds its record from ``profiling.SHARD_FIELDS``,
-every member must be README-documented, and bench.py must reference
-the tuple.
-
-The continuous-refresh bench is pinned likewise: bench.py
-task_refresh builds its record from ``profiling.REFRESH_FIELDS``,
-every member must be README-documented (the Continuous refresh
-section), and bench.py must reference the tuple.
-
-The streaming-ingest bench is pinned likewise: bench.py task_ingest
-builds its record from ``profiling.INGEST_FIELDS``, every member must
-be README-documented (the Streaming ingest section), and bench.py
-must reference the tuple.
-
-The live-promotion bench is pinned likewise: bench.py task_canary
-builds its record from ``profiling.CANARY_FIELDS``, every member must
-be README-documented (the Live promotion section), and bench.py must
-reference the tuple.
 
 The health plane is pinned likewise: every metrics.jsonl point is
 ``profiling.METRIC_FIELDS`` (built by obs/health/store.py), every SLO
@@ -100,26 +71,18 @@ README = os.path.join(REPO, "README.md")
 _TOKEN = re.compile(r"`([a-z][a-z0-9_]*(?:_s|_hits|_misses))`")
 _WRITERS = ("add_stage_time", "add_stage_count")
 
-# bench.py record keys that match the stage-token shape but are not
-# steps.jsonl inputPipeline stages (like the per_s/_frac skips below)
-_BENCH_ONLY = {"fanout_cache_misses"}
-
 
 def documented_fields() -> set:
     with open(README, encoding="utf-8") as f:
         text = f.read()
-    # members of the pinned block schemas (roofline/serving/dag) are
+    # members of the pinned block schemas (roofline/fleet/dag/...) are
     # documented as those blocks' keys, not inputPipeline stages
-    pinned = set(roofline_fields()) | set(serving_fields()) | \
-        set(tree_serve_fields()) | set(fleet_fields()) | set(dag_fields()) | \
-        set(dag_summary_fields()) | set(trace_fields()) | \
-        set(metric_fields()) | set(health_fields()) | \
-        set(shard_fields()) | set(refresh_fields()) | \
-        set(ingest_fields()) | set(canary_fields()) | \
-        set(slice_fields())
+    pinned = set(roofline_fields()) | set(fleet_fields()) | \
+        set(dag_fields()) | set(dag_summary_fields()) | \
+        set(trace_fields()) | set(metric_fields()) | set(health_fields())
     return {tok for tok in _TOKEN.findall(text)
             if "per_s" not in tok and not tok.endswith("_frac")
-            and tok not in pinned and tok not in _BENCH_ONLY}
+            and tok not in pinned}
 
 
 def emitted_fields() -> set:
@@ -177,14 +140,6 @@ def roofline_fields() -> tuple:
     return _profiling_tuple("ROOFLINE_FIELDS")
 
 
-def serving_fields() -> tuple:
-    return _profiling_tuple("SERVING_FIELDS")
-
-
-def tree_serve_fields() -> tuple:
-    return _profiling_tuple("TREE_SERVE_FIELDS")
-
-
 def fleet_fields() -> tuple:
     return _profiling_tuple("FLEET_FIELDS")
 
@@ -209,30 +164,10 @@ def health_fields() -> tuple:
     return _profiling_tuple("HEALTH_FIELDS")
 
 
-def shard_fields() -> tuple:
-    return _profiling_tuple("SHARD_FIELDS")
-
-
-def refresh_fields() -> tuple:
-    return _profiling_tuple("REFRESH_FIELDS")
-
-
-def ingest_fields() -> tuple:
-    return _profiling_tuple("INGEST_FIELDS")
-
-
-def canary_fields() -> tuple:
-    return _profiling_tuple("CANARY_FIELDS")
-
-
-def slice_fields() -> tuple:
-    return _profiling_tuple("SLICE_FIELDS")
-
-
 def check_roofline_docs() -> int:
     """Every ROOFLINE_FIELDS member must be backtick-documented in
-    README (the Raw speed section) — a field added to the block without
-    docs, or renamed out from under them, fails here."""
+    README — a field added to the block without docs, or renamed out
+    from under them, fails here."""
     fields = roofline_fields()
     with open(README, encoding="utf-8") as f:
         documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
@@ -246,67 +181,12 @@ def check_roofline_docs() -> int:
     return 0
 
 
-def check_serving_docs() -> int:
-    """Every SERVING_FIELDS member (bench.py task_serving's record
-    schema) must be backtick-documented in README's Serving section,
-    and task_serving must build its record from the tuple — the AST
-    check asserts bench.py subscripts `profiling.SERVING_FIELDS` (or
-    iterates it) so the record cannot silently drift from the pinned
-    schema."""
-    fields = serving_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("serving schema drift: SERVING_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "SERVING_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the serving record from "
-              "profiling.SERVING_FIELDS", file=sys.stderr)
-        return 1
-    print(f"serving bench: all {len(fields)} SERVING_FIELDS documented "
-          "in README and pinned in bench.py")
-    return 0
-
-
-def check_tree_serve_docs() -> int:
-    """Every TREE_SERVE_FIELDS member (the keys bench.py
-    task_serving_tree adds on top of SERVING_FIELDS) must be
-    backtick-documented in README, and task_serving_tree must build
-    its record from the tuple — the literal check asserts bench.py
-    references `TREE_SERVE_FIELDS` so the record cannot silently
-    drift from the pinned schema."""
-    fields = tree_serve_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("tree-serving schema drift: TREE_SERVE_FIELDS member(s) "
-              f"never documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "TREE_SERVE_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the tree-serving record from "
-              "profiling.TREE_SERVE_FIELDS", file=sys.stderr)
-        return 1
-    print(f"tree serving bench: all {len(fields)} TREE_SERVE_FIELDS "
-          "documented in README and pinned in bench.py")
-    return 0
-
-
 def check_fleet_docs() -> int:
-    """Every FLEET_FIELDS member (the ``stats()["fleet"]`` block and
-    bench.py task_fleet's record schema) must be backtick-documented
-    in README's Model fleet section, and both builders must construct
-    their dicts from the tuple — the literal checks assert
-    serve/fleet.py and bench.py reference `FLEET_FIELDS` so neither
-    can silently drift from the pinned schema."""
+    """Every FLEET_FIELDS member (the ``stats()["fleet"]`` block) must
+    be backtick-documented in README's Model fleet section, and
+    serve/fleet.py must build its dict from the tuple — the literal
+    check asserts it references `FLEET_FIELDS` so the block cannot
+    silently drift from the pinned schema."""
     fields = fleet_fields()
     with open(README, encoding="utf-8") as f:
         documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
@@ -315,16 +195,14 @@ def check_fleet_docs() -> int:
         print("fleet schema drift: FLEET_FIELDS member(s) never "
               f"documented in README: {missing}", file=sys.stderr)
         return 1
-    for path, what in ((os.path.join(PKG, "serve", "fleet.py"),
-                        "shifu_tpu/serve/fleet.py"),
-                       (os.path.join(REPO, "bench.py"), "bench.py")):
-        with open(path, encoding="utf-8") as f:
-            if "FLEET_FIELDS" not in f.read():
-                print(f"{what} no longer builds the fleet block from "
-                      "profiling.FLEET_FIELDS", file=sys.stderr)
-                return 1
+    with open(os.path.join(PKG, "serve", "fleet.py"),
+              encoding="utf-8") as f:
+        if "FLEET_FIELDS" not in f.read():
+            print("shifu_tpu/serve/fleet.py no longer builds the fleet "
+                  "block from profiling.FLEET_FIELDS", file=sys.stderr)
+            return 1
     print(f"model fleet: all {len(fields)} FLEET_FIELDS documented in "
-          "README and pinned in serve/fleet.py + bench.py")
+          "README and pinned in serve/fleet.py")
     return 0
 
 
@@ -415,141 +293,6 @@ def check_health_docs() -> int:
     return 0
 
 
-def check_shard_docs() -> int:
-    """Every SHARD_FIELDS member (bench.py task_dist_stats' record
-    schema, the pod-scale data plane bench) must be backtick-documented
-    in README's Pod-scale data plane section, and task_dist_stats must
-    build its record from the tuple — the literal check asserts
-    bench.py references `SHARD_FIELDS` so the record cannot silently
-    drift from the pinned schema."""
-    fields = shard_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("shard schema drift: SHARD_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "SHARD_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the dist_stats record from "
-              "profiling.SHARD_FIELDS", file=sys.stderr)
-        return 1
-    print(f"pod-scale data plane: all {len(fields)} SHARD_FIELDS "
-          "documented in README and pinned in bench.py")
-    return 0
-
-
-def check_refresh_docs() -> int:
-    """Every REFRESH_FIELDS member (bench.py task_refresh's record
-    schema, the breach→promote closed-loop bench) must be
-    backtick-documented in README's Continuous refresh section, and
-    task_refresh must build its record from the tuple — the literal
-    check asserts bench.py references `REFRESH_FIELDS` so the record
-    cannot silently drift from the pinned schema."""
-    fields = refresh_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("refresh schema drift: REFRESH_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "REFRESH_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the refresh record from "
-              "profiling.REFRESH_FIELDS", file=sys.stderr)
-        return 1
-    print(f"continuous refresh: all {len(fields)} REFRESH_FIELDS "
-          "documented in README and pinned in bench.py")
-    return 0
-
-
-def check_ingest_docs() -> int:
-    """Every INGEST_FIELDS member (bench.py task_ingest's record
-    schema, the streaming row-log bench) must be backtick-documented
-    in README's Streaming ingest section, and task_ingest must build
-    its record from the tuple — the literal check asserts bench.py
-    references `INGEST_FIELDS` so the record cannot silently drift
-    from the pinned schema."""
-    fields = ingest_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("ingest schema drift: INGEST_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "INGEST_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the ingest record from "
-              "profiling.INGEST_FIELDS", file=sys.stderr)
-        return 1
-    print(f"streaming ingest: all {len(fields)} INGEST_FIELDS "
-          "documented in README and pinned in bench.py")
-    return 0
-
-
-def check_canary_docs() -> int:
-    """Every CANARY_FIELDS member (bench.py task_canary's record
-    schema, the live-promotion bench) must be backtick-documented in
-    README's Live promotion section, and task_canary must build its
-    record from the tuple — the literal check asserts bench.py
-    references `CANARY_FIELDS` so the record cannot silently drift
-    from the pinned schema."""
-    fields = canary_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("canary schema drift: CANARY_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "CANARY_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the canary record from "
-              "profiling.CANARY_FIELDS", file=sys.stderr)
-        return 1
-    print(f"live promotion: all {len(fields)} CANARY_FIELDS "
-          "documented in README and pinned in bench.py")
-    return 0
-
-
-def check_slice_docs() -> int:
-    """Every SLICE_FIELDS member (bench.py task_pipeline's sliced-vs-
-    timeshared A/B block) must be backtick-documented in README's
-    Pipeline DAG section, and bench.py must build the block from the
-    tuple — the literal check asserts bench.py references
-    `SLICE_FIELDS` so the record cannot silently drift from the pinned
-    schema."""
-    fields = slice_fields()
-    with open(README, encoding="utf-8") as f:
-        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
-    missing = sorted(set(fields) - documented)
-    if missing:
-        print("slice schema drift: SLICE_FIELDS member(s) never "
-              f"documented in README: {missing}", file=sys.stderr)
-        return 1
-    bench = os.path.join(REPO, "bench.py")
-    with open(bench, encoding="utf-8") as f:
-        uses = "SLICE_FIELDS" in f.read()
-    if not uses:
-        print("bench.py no longer builds the slice A/B block from "
-              "profiling.SLICE_FIELDS", file=sys.stderr)
-        return 1
-    print(f"slice A/B: all {len(fields)} SLICE_FIELDS documented in "
-          "README and pinned in bench.py")
-    return 0
-
-
 def log_fields(path: str) -> set:
     out = set()
     with open(path, encoding="utf-8") as f:
@@ -603,10 +346,6 @@ def main(argv) -> int:
           f"all within the {len(emit)}-key emitted vocabulary")
     if check_roofline_docs():
         return 1
-    if check_serving_docs():
-        return 1
-    if check_tree_serve_docs():
-        return 1
     if check_fleet_docs():
         return 1
     if check_dag_docs():
@@ -614,16 +353,6 @@ def main(argv) -> int:
     if check_trace_docs():
         return 1
     if check_health_docs():
-        return 1
-    if check_shard_docs():
-        return 1
-    if check_refresh_docs():
-        return 1
-    if check_ingest_docs():
-        return 1
-    if check_canary_docs():
-        return 1
-    if check_slice_docs():
         return 1
     if argv:
         seen = log_fields(argv[0])

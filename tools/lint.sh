@@ -1,9 +1,9 @@
 #!/usr/bin/env bash
 # Repo lint gate — exits non-zero on ANY finding. Four passes:
 #
-#   1. `python -m shifu_tpu.analysis` over the package AND the
-#      out-of-package knob readers (bench.py, tools/) — all sixteen
-#      repo-native rules (see README "Static analysis" for the table),
+#   1. `python -m shifu_tpu.analysis` over the package, tools/ and
+#      tests/synth.py — all sixteen repo-native rules (see README
+#      "Static analysis" for the table),
 #      including the whole-program concurrency/atomicity four:
 #      raw-lock, thread-shared-mutation, non-atomic-write,
 #      swallowed-exception. Runs with --timings and a 10s wall budget:
@@ -17,10 +17,6 @@
 #      site that import fails would silently shrink the sweep).
 #   4. steps.jsonl schema: every stage field README documents must be
 #      in the emitted vocabulary (tools/check_steps_schema.py).
-#   5. ADVISORY (never fails lint): bench-history regression check
-#      (tools/bench_regress.py) — BENCH_LOCAL.jsonl exists only where
-#      someone ran bench.py on hardware, so findings here are printed
-#      for a human, not gated on.
 #
 # tests/test_lint.py runs pass 1 in tier-1; this script is the full
 # pre-push/CI gate. Suppress an intentional finding inline with
@@ -38,12 +34,12 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 rc=0
 
 echo "== shifu_tpu.analysis (static rules) =="
-python -m shifu_tpu.analysis shifu_tpu/ bench.py tools/ tests/synth.py \
+python -m shifu_tpu.analysis shifu_tpu/ tools/ tests/synth.py \
   --timings --budget-s 10 \
   || rc=1
 
 echo "== compileall (syntax) =="
-python -m compileall -q shifu_tpu tools tests bench.py || rc=1
+python -m compileall -q shifu_tpu tools tests || rc=1
 
 echo "== hygiene: tracked bytecode =="
 TRACKED_PYC="$(git -C "$REPO" ls-files | grep -E '(\.pyc$|__pycache__/)' || true)"
@@ -69,10 +65,6 @@ PYEOF
 
 echo "== steps.jsonl schema (README vs emitted keys) =="
 python tools/check_steps_schema.py || rc=1
-
-echo "== bench regression (advisory — see ROADMAP perf-claim caveat) =="
-python tools/bench_regress.py \
-  || echo "bench_regress: findings above are ADVISORY (BENCH_LOCAL.jsonl exists only after a bench run on hardware); not failing lint"
 
 if [ "$rc" -ne 0 ]; then
   echo "lint: FAILED" >&2
