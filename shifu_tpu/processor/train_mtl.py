@@ -27,8 +27,8 @@ from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import optimizer_from_params
-from shifu_tpu.train.trainer import (bagging_weights, split_validation,
-                                     train_bags)
+from shifu_tpu.train.trainer import (bagging_weights, objectives,
+                                     split_validation, train_bags)
 
 log = logging.getLogger("shifu_tpu")
 
@@ -102,15 +102,7 @@ def run_mtl(ctx: ProcessorContext, seed: int = 12306):
             bag_keys = jax.random.split(key, n_bags)
             stacked = jax.vmap(lambda k: mtl.init_params(spec, k))(bag_keys)
             grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
-
-            def loss(params, inputs, w_, key_):
-                x_, y_ = inputs
-                return mtl.loss_fn(spec, params, x_, y_, w_)
-
-            def metric(params, inputs, w_):
-                x_, y_ = inputs
-                return mtl.mse(spec, params, x_, y_, w_)
-
+            loss, metric = objectives(mtl, spec)
             optimizer = optimizer_from_params(mc.train.params)
             ew = mc.train.earlyStoppingRounds
             # train_bags shards rows / replicates params over the default mesh

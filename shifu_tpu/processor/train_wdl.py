@@ -20,9 +20,11 @@ from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.parallel import mesh as mesh_mod
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
-from shifu_tpu.train.optimizers import optimizer_from_params
+from shifu_tpu.train.optimizers import (optimizer_from_params,
+                                        program_static)
 from shifu_tpu.train.trainer import (TrainResult, bagging_weights,
-                                     split_validation, train_bags)
+                                     objectives, split_validation,
+                                     train_bags)
 
 log = logging.getLogger("shifu_tpu")
 
@@ -30,12 +32,14 @@ log = logging.getLogger("shifu_tpu")
 TABLE_LEAVES = ("embed", "wide_cat")
 
 
+@program_static
 def _tables_scoped(optimizer: optax.GradientTransformation
                    ) -> optax.GradientTransformation:
     """The same optimizer with the two tables' update under the device
     scope `table_update`, so a trace tells the table pass from the MLP's.
     Every `Propagation` rule is per element, so the two halves update
-    exactly as the whole did."""
+    exactly as the whole did. One wrapper an optimizer, as the optimizer
+    is one object a setting."""
     def scoped_update(grads, state, params=None):
         with jax.named_scope("table_update"):
             return optimizer.update(grads, state, params)
@@ -107,15 +111,7 @@ def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,
             bag_keys = jax.random.split(key, n_bags)
             stacked = jax.vmap(lambda k: wdl.pad_tables(
                 wdl.init_params(spec, k), n_model))(bag_keys)
-
-            def loss(params, inputs, w_, key_):
-                d_, i_, y_ = inputs
-                return wdl.loss_fn(spec, params, d_, i_, y_, w_)
-
-            def metric(params, inputs, w_):
-                d_, i_, y_ = inputs
-                return wdl.mse(spec, params, d_, i_, y_, w_)
-
+            loss, metric = objectives(wdl, spec)
             optimizer = _tables_scoped(
                 optimizer_from_params(train_conf.params))
             ew = train_conf.earlyStoppingRounds

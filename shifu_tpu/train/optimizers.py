@@ -12,11 +12,31 @@ optax) are implemented natively below with the reference's constants
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import optax
+
+
+# distinct settings whose static arguments `program_static` keeps alive
+STATICS_KEPT = 16
+
+
+def program_static(factory):
+    """Memoise a factory of `train_bags_carry`'s static arguments (a
+    loss/metric pair, an optimizer): equal arguments return the SAME
+    object. jit finds a program by its statics' hash and equality, and
+    a function's are its identity, so a loss or an optimizer built anew
+    for every job would be a new program to it every time, traced,
+    lowered and read back from the compile cache with the device
+    waiting. The factory's arguments must be hashable and must hold
+    EVERYTHING the result bakes into a trace: two settings that share a
+    key would share a program. A small LRU: a grid search over a
+    thousand settings keeps the last `STATICS_KEPT` here (what jit's
+    own cache holds of a dropped one goes at jit's own eviction)."""
+    return functools.lru_cache(maxsize=STATICS_KEPT)(factory)
 
 
 class RPropState(NamedTuple):
@@ -96,6 +116,7 @@ def quickprop(learning_rate: float, max_growth: float = 1.75
     return optax.GradientTransformation(init, update)
 
 
+@program_static
 def make_optimizer(propagation: str, learning_rate: float,
                    learning_decay: float = 0.0,
                    momentum: float = 0.5,
@@ -103,7 +124,9 @@ def make_optimizer(propagation: str, learning_rate: float,
                    reg_l2_decay: float = 0.0) -> optax.GradientTransformation:
     """`Weight.calculateWeights` dispatch. learning_decay shrinks the
     rate each epoch: lr_t = lr · (1 − decay)^t (Weight.java
-    learningDecay semantics)."""
+    learningDecay semantics). The same object for the same values
+    (`program_static`): the rate is a constant of the program that
+    trains with it, a decay schedule a lambda inside it."""
     p = (propagation or "Q").strip().upper()
     if learning_decay > 0.0:
         sched = lambda step: learning_rate * (1.0 - learning_decay) ** step  # noqa: E731

@@ -24,16 +24,23 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
 
 
-def _run(cell, *extra):
-    """One run of the harness as the driver starts it, on a CPU that
-    shows one device (the test rig's own eight would not be the cell's
-    `chips`)."""
+def _python(*args):
+    """A child on a CPU that shows one device (the test rig's own eight
+    would not be the cell's `chips`)."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.run(
-        [sys.executable, *MANIFEST["command"][1:], "--workload", cell,
-         "--seed", "3000000028", "--seconds", "0.5", *extra],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    return subprocess.run([sys.executable, *args], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+def _arguments(cell, *extra):
+    return ["--workload", cell, "--seed", "3000000028", "--seconds", "0.5",
+            *extra]
+
+
+def _run(cell, *extra):
+    """One run of the harness as the driver starts it."""
+    return _python(*MANIFEST["command"][1:], *_arguments(cell, *extra))
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -62,3 +69,37 @@ def test_cell_is_refused_without_the_chip(cell):
     assert r.returncode != 0
     assert r.stdout.strip() == ""
     assert "no accelerator" in r.stderr
+
+
+# The harness's main() under its own counter of the window's programs:
+# `compiles` is what a traced run on the chip prints as `window_compiles`,
+# `loads` as `window_program_loads` (a rehearsal's line carries neither).
+_WINDOW_PROGRAMS = """
+import json, sys
+sys.path.insert(0, {repo!r})
+from benchmark import run as harness
+made = []
+class Kept(harness.CompileCounter):
+    def __init__(self, jax):
+        super().__init__(jax)
+        made.append(self)
+harness.CompileCounter = Kept
+rc = harness.main(sys.argv[1:])
+print(json.dumps({{"rc": rc, "compiles": made[0].compiles,
+                  "loads": made[0].loads}}))
+"""
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_window_builds_and_loads_no_program(cell):
+    """The warm-up call leaves every program the window's calls run in
+    jit's own cache: a window call compiles nothing and traces, lowers
+    and reads back nothing (`program_loads_per_call` 0). A static
+    argument of `train_bags_carry` that is made anew a call again (a
+    closure, an optax transformation) fails here, before the chip."""
+    r = _python("-c", _WINDOW_PROGRAMS.format(repo=REPO),
+                *_arguments(cell, "--rehearse"))
+    assert r.returncode == 0, r.stderr[-2000:]
+    lines = r.stdout.strip().splitlines()
+    assert json.loads(lines[-2])["attempted"] >= 1
+    assert json.loads(lines[-1]) == {"rc": 0, "compiles": 0, "loads": 0}
