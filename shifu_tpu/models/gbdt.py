@@ -182,6 +182,54 @@ def make_fused_inputs(tables: Dict[str, np.ndarray],
                      np.ascontiguousarray(np.concatenate(cut_parts)))
 
 
+def _data_size(mesh) -> int:
+    return 1 if mesh is None else int(mesh.shape.get("data", 1))
+
+
+def _row_sharding(mesh, ndim: int):
+    """Rows (the last of `ndim` axes) over `mesh`'s 'data' axis, every
+    other axis whole: how the bin matrix and all per-row state lie."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(mesh, P(*[None] * (ndim - 1), "data"))
+
+
+def _by_row(mesh, x):
+    """`x`, whose last axis is the rows, STATED to lie as the bin matrix
+    lies: divided over `mesh`'s 'data' axis, every other axis whole. The
+    row state of a build (predictions, gradients, node ids, slots) is
+    made inside the jitted round, where nothing else says where it
+    lives; one replicated copy of it is 4 bytes a row on every chip and
+    an all-gather a round. No mesh, or one chip on its data axis: `x`
+    itself, and the program is the one-device program."""
+    if _data_size(mesh) == 1:
+        return x
+    return jax.lax.with_sharding_constraint(x, _row_sharding(mesh, x.ndim))
+
+
+def _replicated(mesh, tree):
+    """The (small) tree arrays stated whole on every chip of `mesh`."""
+    if _data_size(mesh) == 1:
+        return tree
+    from shifu_tpu.parallel.mesh import replicated
+    return jax.lax.with_sharding_constraint(tree, replicated(mesh))
+
+
+def psum_bytes(cfg: "TreeConfig", n_cols: int, mesh, subtract: bool,
+               n_trees: int = 1) -> int:
+    """Bytes a step (one tree; a lockstep level of `n_trees` trees)
+    hands its all-reduces, from shapes alone: float32 G and H over the
+    slots each level's kernel call covers (`_kernel_slots`, the growth
+    loop's own schedule) by columns by bins, the leaf level on its
+    `_LEAF_COLUMNS`; tests/test_gbt_mesh.py holds it to the operands of
+    the compiled round's all-reduces. 0 on one chip."""
+    if _data_size(mesh) == 1:
+        return 0
+    slots = [_kernel_slots(d, subtract) for d in range(cfg.max_depth + 1)]
+    cells = sum(slots[:-1]) * n_cols + slots[-1] * min(n_cols,
+                                                       _LEAF_COLUMNS)
+    return 2 * 4 * cells * cfg.n_bins * n_trees
+
+
 def _local_level_histograms(binsT, slot, grad, hess, n_level_nodes, n_bins):
     """Single-shard histogram kernel (slot already computed, incl. the
     trailing dump slot for inactive rows). binsT is TRANSPOSED (C, R) —
@@ -226,7 +274,9 @@ def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
     and H.
 
     With a multi-device `mesh`, rows shard over the 'data' axis and each
-    device builds its local histogram which a psum reduces — exactly the
+    device builds its local histogram which ONE psum reduces (G and H
+    as one stacked block: a level is one rendezvous of the chips, a
+    tree max_depth + 1) — exactly the
     DTWorker per-split accumulation + DTMaster aggregation
     (`dt/DTWorker.java:914-944`, `dt/DTMaster.java:276`), explicit via
     shard_map so no silent all-gather of the row-sharded bin matrix can
@@ -235,9 +285,9 @@ def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
     """
     local = node_of_row - level_offset  # (R,)
     valid = (local >= 0) & (local < n_level_nodes)
-    slot = jnp.where(valid, local, n_level_nodes)  # dump slot
+    slot = _by_row(mesh, jnp.where(valid, local, n_level_nodes))  # dump slot
 
-    if mesh is not None and mesh.shape.get("data", 1) > 1:
+    if _data_size(mesh) > 1:
         from jax.sharding import PartitionSpec as P
 
         # FusedBins: rows of valuesT shard like binsT; the small (C, K)
@@ -249,9 +299,10 @@ def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
                  in_specs=(bspec, P("data"), P("data"), P("data")),
                  out_specs=(P(), P()), check_vma=False)
         def sharded(b, s, g, h):
-            gh_, hh_ = _local_level_histograms(b, s, g, h, n_level_nodes,
-                                               n_bins)
-            return (jax.lax.psum(gh_, "data"), jax.lax.psum(hh_, "data"))
+            local = jnp.stack(_local_level_histograms(
+                b, s, g, h, n_level_nodes, n_bins))
+            with jax.named_scope("allreduce"):
+                return tuple(jax.lax.psum(local, "data"))
 
         return sharded(binsT, slot, grad, hess)
 
@@ -269,7 +320,8 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
 
     Same explicit shard_map + psum structure as _level_histograms —
     rows shard over 'data', each device builds local histograms for
-    ALL trees (vmap over the tree axis), one psum reduces. RF used to
+    ALL trees (vmap over the tree axis), one psum reduces G and H of
+    all of them, stacked. RF used to
     rely on GSPMD partitioning a vmapped scatter here; that both risks
     a silent all-gather of the row-sharded bins AND compiles
     pathologically slowly (>9 min for a toy shape on the 8-device CPU
@@ -277,13 +329,13 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
     """
     local = node_T - level_offset                       # (T, R)
     valid = (local >= 0) & (local < n_level_nodes)
-    slot_T = jnp.where(valid, local, n_level_nodes)
+    slot_T = _by_row(mesh, jnp.where(valid, local, n_level_nodes))
 
     def local_hists(b, s, g, h):
         return jax.vmap(lambda s_, g_, h_: _local_level_histograms(
             b, s_, g_, h_, n_level_nodes, n_bins))(s, g, h)
 
-    if mesh is not None and mesh.shape.get("data", 1) > 1:
+    if _data_size(mesh) > 1:
         from jax.sharding import PartitionSpec as P
 
         @partial(jax.shard_map, mesh=mesh,
@@ -291,8 +343,9 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
                            P(None, "data"), P(None, "data")),
                  out_specs=(P(), P()), check_vma=False)
         def sharded(b, s, g, h):
-            gh_, hh_ = local_hists(b, s, g, h)
-            return (jax.lax.psum(gh_, "data"), jax.lax.psum(hh_, "data"))
+            local = jnp.stack(local_hists(b, s, g, h))
+            with jax.named_scope("allreduce"):
+                return tuple(jax.lax.psum(local, "data"))
 
         return sharded(binsT, slot_T, grad_T, hess_T)
 
@@ -316,24 +369,27 @@ def build_forest(cfg: TreeConfig, binsT, grad_T, hess_T, feature_masks,
     trees = jax.tree.map(
         lambda a: jnp.broadcast_to(a, (n_trees,) + a.shape),
         _empty_tree(cfg))
-    node_T = jnp.zeros((n_trees, r), jnp.int32)
+    grad_T, hess_T = _by_row(mesh, grad_T), _by_row(mesh, hess_T)
+    node_T = _by_row(mesh, jnp.zeros((n_trees, r), jnp.int32))
 
-    prev_g = prev_h = None
+    prev_g = prev_h = side_T = half_T = None
     for depth in range(cfg.max_depth):
-        g, h = _forest_child_histograms(cfg, binsT, node_T, grad_T,
+        g, h = _forest_child_histograms(cfg, binsT, node_T, half_T, grad_T,
                                         hess_T, depth, prev_g, prev_h,
-                                        trees, mesh, subtract)
+                                        trees, mesh, subtract, side_T)
         trees = _forest_apply_level(cfg, trees, g, h, feature_masks,
                                     depth, mesh=mesh)
-        node_T = jax.vmap(
-            lambda t, n: _route_level(cfg, t, binsT, n, depth)
-        )(trees, node_T)
+        side_T = jax.vmap(lambda t, hh: _smaller_child(cfg, t, hh, depth)
+                          )(trees, h)
+        node_T, half_T = (_by_row(mesh, n) for n in jax.vmap(
+            lambda t, n, sd: _route_level(cfg, t, binsT, n, depth, sd)
+        )(trees, node_T, side_T))
         prev_g, prev_h = g, h
 
     g, h = _forest_child_histograms(
-        cfg, _leaf_columns(binsT), node_T, grad_T, hess_T, cfg.max_depth,
-        _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2), trees, mesh,
-        subtract)
+        cfg, _leaf_columns(binsT), node_T, half_T, grad_T, hess_T,
+        cfg.max_depth, _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2),
+        trees, mesh, subtract, side_T)
     trees = jax.vmap(lambda t, gh, hh: _final_leaves(cfg, t, gh, hh)
                      )(trees, g, h)
     if return_nodes:
@@ -341,26 +397,27 @@ def build_forest(cfg: TreeConfig, binsT, grad_T, hess_T, feature_masks,
     return trees
 
 
-def _forest_child_histograms(cfg: TreeConfig, binsT, node_T, grad_T,
+def _forest_child_histograms(cfg: TreeConfig, binsT, node_T, half_T, grad_T,
                              hess_T, depth: int, prev_g, prev_h, trees,
-                             mesh, subtract=None):
+                             mesh, subtract, side_T):
     """Sibling-subtraction for the lockstep forest build (see
-    _child_level_histograms): left children through the kernel, right
-    children by parent − left, per tree."""
+    _child_level_histograms): each parent's smaller child (`side_T`,
+    (T, P); its rows `half_T`, (T, R)) through the kernel, its sibling
+    by parent − built, per tree."""
     level_offset = 2 ** depth - 1
     n_level = 2 ** depth
     use = _use_hist_subtract() if subtract is None else subtract
-    if depth == 0 or prev_g is None or not use:
+    slots = _kernel_slots(depth, use)
+    if slots == n_level:
         return _forest_level_histograms(binsT, node_T, grad_T, hess_T,
                                         level_offset, n_level,
                                         cfg.n_bins, mesh=mesh)
-    half_node = _left_half_nodes(node_T, level_offset, n_level)  # (T, R)
-    gl, hl = _forest_level_histograms(binsT, half_node, grad_T, hess_T,
-                                      level_offset, n_level // 2,
-                                      cfg.n_bins, mesh=mesh)
+    gb, hb = _forest_level_histograms(binsT, half_T, grad_T, hess_T,
+                                      level_offset, slots, cfg.n_bins,
+                                      mesh=mesh)
     split = _parent_split_mask(trees["is_leaf"], trees["feature"],
                                depth)                    # (T, P)
-    return _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level)
+    return _subtract_siblings(prev_g, prev_h, gb, hb, split, side_T)
 
 
 @jax.named_scope("split")
@@ -576,16 +633,21 @@ def _pick_row(matT, idx):
     return _select(idx[None, :] == rows[:, None], matT)
 
 
-def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int):
+def _route_level(cfg: TreeConfig, tree, binsT, node_of_row, depth: int,
+                 side):
     """Advance rows one level: bin <= split_bin → left child (2i+1);
-    missing uses the node's default direction. binsT: (C, R)."""
+    missing uses the node's default direction. binsT: (C, R). `side`
+    (2^depth,): the child of each of the level's nodes that the next
+    level's half-width histogram pass builds (`_smaller_child`).
+    Returns (the rows' nodes a level down, the node that pass counts
+    each row under: `_route_level_at`)."""
     return _route_level_at(cfg, tree, binsT, node_of_row,
-                           2 ** depth - 1, 2 ** depth)
+                           2 ** depth - 1, 2 ** depth, side)
 
 
 @jax.named_scope("route")
 def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
-                    level_offset: int, n_level: int):
+                    level_offset: int, n_level: int, side):
     """_route_level's core, for the level that holds the n_level nodes
     from level_offset on: both Python ints, so the selects below run
     over that level's own width (1 at the root, 2^d at depth d) and
@@ -594,17 +656,28 @@ def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
     that split's feature) are selects (`_lookup`, `_pick_row`), all in
     integers: exact, so every builder routes bitwise alike. Rows
     outside the level (parked at a leaf, -1 pad rows) match no slot,
-    read feature -1 and stay where they are."""
+    read feature -1 and stay where they are.
+
+    The second result is sibling subtraction's row selection, made here
+    because the row's node is already looked up: a row that went to the
+    child `side` names for its node carries that node's slot at the
+    child level's offset (the id `_level_histograms` takes for a pass
+    of n_level slots over the 2 * n_level children), every other row
+    -1, the dump slot. `side` rides the default direction's lookup, so
+    it costs no pass of its own; a caller that does not subtract drops
+    the result and XLA the arithmetic."""
+    level = slice(level_offset, level_offset + n_level)
+
     def of_node(table):
-        # the level's nodes of the table (a static slice, under vmap
-        # over trees too), then each row's slot of those
-        return _lookup(table[level_offset:level_offset + n_level],
-                       node_of_row - level_offset)
+        # each row's slot of the level's own (n_level,) table (a static
+        # slice of the tree's, under vmap over trees too)
+        return _lookup(table, node_of_row - level_offset)
 
     # feature + 1, so that a row no slot matched reads feature -1
-    node_feat = of_node(tree["feature"] + 1) - 1
-    node_bin = of_node(tree["bin"])
-    node_dl = of_node(tree["default_left"])
+    node_feat = of_node(tree["feature"][level] + 1) - 1
+    node_bin = of_node(tree["bin"][level])
+    dl_side = of_node(tree["default_left"][level] + 2 * side)
+    node_dl, node_side = dl_side % 2 == 1, dl_side // 2
     if isinstance(binsT, FusedBins):
         # bin the routed feature's raw value on the fly: the row's
         # value and its feature's K cuts, then a boundary compare; no
@@ -619,9 +692,16 @@ def _route_level_at(cfg: TreeConfig, tree, binsT, node_of_row,
         row_bin = _pick_row(binsT, node_feat)
     miss = row_bin == (cfg.n_bins - 1)
     go_left = jnp.where(miss, node_dl, row_bin <= node_bin)
-    return jnp.where(node_feat >= 0,
-                     2 * node_of_row + jnp.where(go_left, 1, 2),
-                     node_of_row)
+    child = jnp.where(go_left, 0, 1)
+    moved = node_feat >= 0
+    # the barrier has both results written where they are made: without
+    # it XLA hands the three masks on and redoes this arithmetic inside
+    # the fusion that writes the kernel's (1, R) slot row, where an
+    # elementwise op fills an eighth of a vector register (+0.9 ms a
+    # level at 2^24 rows; PERF.md, PR 30)
+    return jax.lax.optimization_barrier(
+        (jnp.where(moved, 2 * node_of_row + 1 + child, node_of_row),
+         jnp.where(moved & (child == node_side), node_of_row + n_level, -1)))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract",
@@ -661,30 +741,37 @@ def _grow_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh,
                subtract, node0=None):
     """THE growth loop of a single tree, unrolled over depth when the
     caller's jit traces it, so every level's shapes are that level's
-    own: its histogram pass covers the slots it reads (the root 1,
-    level d the 2^(d-1) left children under sibling subtraction, 2^d
-    without), its split search and its routing selects the 2^d nodes
-    it holds. node0: the rows' starting nodes (the resident streaming
-    tier parks its pad rows at -1, which no level's slots match).
+    own: its histogram pass covers the slots it reads
+    (`_kernel_slots`), its split search and its routing selects the 2^d
+    nodes it holds. node0: the rows' starting nodes (the resident
+    streaming tier parks its pad rows at -1, which no level's slots
+    match). On a
+    data mesh the loop's row state (gradients, node ids; the slot row in
+    `_level_histograms`) is held to the rows' layout level by level
+    (`_by_row`), not left to sharding propagation.
     Returns (tree, landing node of every row)."""
     tree = _empty_tree(cfg)
-    node_of_row = (jnp.zeros(binsT.shape[1], jnp.int32) if node0 is None
-                   else node0)
+    grad, hess = _by_row(mesh, grad), _by_row(mesh, hess)
+    node_of_row = _by_row(mesh, jnp.zeros(binsT.shape[1], jnp.int32)
+                          if node0 is None else node0)
 
-    prev_g = prev_h = None
+    prev_g = prev_h = side = half_node = None
     for depth in range(cfg.max_depth):
         g_hist, h_hist = _child_level_histograms(
-            cfg, binsT, node_of_row, grad, hess, depth, prev_g, prev_h,
-            tree["is_leaf"], tree["feature"], mesh, subtract)
+            cfg, binsT, node_of_row, half_node, grad, hess, depth, prev_g,
+            prev_h, tree["is_leaf"], tree["feature"], mesh, subtract, side)
         tree = _apply_level(cfg, tree, g_hist, h_hist, feature_mask, depth,
                             mesh=mesh)
-        node_of_row = _route_level(cfg, tree, binsT, node_of_row, depth)
+        side = _smaller_child(cfg, tree, h_hist, depth)
+        node_of_row, half_node = (
+            _by_row(mesh, n) for n in _route_level(cfg, tree, binsT,
+                                                   node_of_row, depth, side))
         prev_g, prev_h = g_hist, h_hist
 
     g_hist, h_hist = _child_level_histograms(
-        cfg, _leaf_columns(binsT), node_of_row, grad, hess, cfg.max_depth,
-        _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2),
-        tree["is_leaf"], tree["feature"], mesh, subtract)
+        cfg, _leaf_columns(binsT), node_of_row, half_node, grad, hess,
+        cfg.max_depth, _leaf_columns(prev_g, -2), _leaf_columns(prev_h, -2),
+        tree["is_leaf"], tree["feature"], mesh, subtract, side)
     return _final_leaves(cfg, tree, g_hist, h_hist), node_of_row
 
 
@@ -693,42 +780,66 @@ def _use_hist_subtract() -> bool:
     return knob_bool("SHIFU_TPU_HIST_SUBTRACT")
 
 
-def _child_level_histograms(cfg: TreeConfig, binsT, node_of_row, grad,
-                            hess, depth: int, prev_g, prev_h,
-                            is_leaf, feature, mesh, subtract=None):
+def _kernel_slots(depth: int, subtract: bool) -> int:
+    """Slots the histogram pass of level `depth` covers: one child of
+    every parent under sibling subtraction (2^(depth-1); the root has no
+    parent), the level's 2^depth nodes without."""
+    return 2 ** (depth - 1) if subtract and depth > 0 else 2 ** depth
+
+
+def _child_level_histograms(cfg: TreeConfig, binsT, node_of_row, half_node,
+                            grad, hess, depth: int, prev_g, prev_h,
+                            is_leaf, feature, mesh, subtract, side):
     """Level histograms with the sibling-subtraction trick: at depth
-    d ≥ 1 only LEFT children (even level-local slots — children of
-    parent k land at local 2k/2k+1) go through the histogram kernel,
-    and right = parent − left from the previous level's histograms.
-    Kernel work per level halves (Σ 2^d slot-levels → Σ 2^(d-1)), the
-    standard GBDT histogram-subtraction optimization; children of
-    leaf parents are masked to zero (the subtraction would otherwise
-    resurrect the parent's rows as a phantom right child).
-    Disable with SHIFU_TPU_HIST_SUBTRACT=0."""
+    d ≥ 1 only ONE child of every parent goes through the histogram
+    kernel, at its parent's slot (children of parent k land at
+    level-local 2k/2k+1), and the sibling = parent − built from the
+    previous level's histograms. `side` (P,) says which: 0 the left
+    child, 1 the right (`_smaller_child`: the one of less hessian), and
+    `half_node` is the rows' selection for that pass, as the routing
+    into this level made it (`_route_level`). Kernel work per level
+    halves (Σ 2^d slot-levels → Σ 2^(d-1)), the standard GBDT
+    histogram-subtraction optimization, and building the SMALLER child
+    is the standard form of it: a float32 histogram over 10^8 rows is
+    off by a few units a bin, which a subtraction hands on whole, so it
+    has to land on the larger sibling, where it is nothing, and never
+    on a sibling of a few rows, where it would be everything (PERF.md,
+    PR 30). Children of leaf parents are masked to zero (the
+    subtraction would otherwise resurrect the parent's rows as a
+    phantom child). Disable with SHIFU_TPU_HIST_SUBTRACT=0 (`subtract`
+    None reads it)."""
     level_offset = 2 ** depth - 1
     n_level = 2 ** depth
     use = _use_hist_subtract() if subtract is None else subtract
-    if depth == 0 or prev_g is None or not use:
+    slots = _kernel_slots(depth, use)
+    if slots == n_level:
         return _level_histograms(binsT, node_of_row, grad, hess,
                                  level_offset, n_level, cfg.n_bins,
                                  mesh=mesh)
-    half_node = _left_half_nodes(node_of_row, level_offset, n_level)
-    gl, hl = _level_histograms(binsT, half_node, grad, hess,
-                               level_offset, n_level // 2, cfg.n_bins,
-                               mesh=mesh)
+    gb, hb = _level_histograms(binsT, half_node, grad, hess,
+                               level_offset, slots, cfg.n_bins, mesh=mesh)
     split = _parent_split_mask(is_leaf, feature, depth)
-    return _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level)
+    return _subtract_siblings(prev_g, prev_h, gb, hb, split, side)
 
 
+@partial(jax.jit, static_argnames=("cfg", "depth"))
 @jax.named_scope("hist")
-def _left_half_nodes(node, level_offset, n_level):
-    """Map rows at LEFT children (even level-local slots) to their
-    parent's slot id for the half-width kernel; everything else → -1
-    (dumped). Shared by all three subtraction call sites so child
-    ordering can never desynchronize between them."""
-    local = node - level_offset
-    left = (local >= 0) & (local < n_level) & (local % 2 == 0)
-    return jnp.where(left, level_offset + local // 2, -1)
+def _smaller_child(cfg: TreeConfig, tree, h_hist, depth: int):
+    """(2^depth,) int32: for every node of the level `depth`, whose
+    splits `tree` now holds and whose hessian histograms are h_hist
+    (P, C, B), the child that gets less of the node's hessian: 0 left,
+    1 right. Read off the split feature's own bins, as selects over the
+    small histogram block (no gather)."""
+    level = slice(2 ** depth - 1, 2 ** (depth + 1) - 1)
+    feat, split_bin = tree["feature"][level], tree["bin"][level]
+    cols = jnp.arange(h_hist.shape[1], dtype=jnp.int32)
+    h_f = jnp.sum(jnp.where(feat[:, None, None] == cols[None, :, None],
+                            h_hist, 0.0), axis=1)              # (P, B)
+    bins = jnp.arange(cfg.n_bins - 1, dtype=jnp.int32)
+    h_left = jnp.sum(jnp.where(bins[None, :] <= split_bin[:, None],
+                               h_f[:, :-1], 0.0), axis=1) \
+        + jnp.where(tree["default_left"][level], h_f[:, -1], 0.0)
+    return (jnp.sum(h_f, axis=1) - h_left < h_left).astype(jnp.int32)
 
 
 @jax.named_scope("hist")
@@ -741,20 +852,25 @@ def _parent_split_mask(is_leaf, feature, depth):
 
 
 @jax.named_scope("hist")
-def _subtract_siblings(prev_g, prev_h, gl, hl, split, n_level):
+def _subtract_siblings(prev_g, prev_h, gb, hb, split, side):
     """Shared sibling-subtraction core (single tree (P, C, B) or
-    lockstep forest (T, P, C, B) — `split` carries the matching leading
-    dims): mask leaf parents, derive right = parent − left, interleave
-    (left0, right0, left1, ...) back into a full level."""
+    lockstep forest (T, P, C, B); `split` and `side` carry the matching
+    leading dims): mask leaf parents, derive sibling = parent − built,
+    where the built child (gb, hb) is `side`'s, and interleave (left0,
+    right0, left1, ...) back into the full level of 2 P nodes. The one
+    subtraction rule of every builder, so child ordering can never
+    desynchronize between them."""
     m = split[..., None, None]
-    gl = jnp.where(m, gl, 0.0)
-    hl = jnp.where(m, hl, 0.0)
-    gr = jnp.where(m, prev_g - gl, 0.0)
-    hr = jnp.where(m, prev_h - hl, 0.0)
-    lead = gl.shape[:-3]
-    c, b = gl.shape[-2], gl.shape[-1]
-    g = jnp.stack([gl, gr], axis=-3).reshape(lead + (n_level, c, b))
-    h = jnp.stack([hl, hr], axis=-3).reshape(lead + (n_level, c, b))
+    gb = jnp.where(m, gb, 0.0)
+    hb = jnp.where(m, hb, 0.0)
+    go = jnp.where(m, prev_g - gb, 0.0)
+    ho = jnp.where(m, prev_h - hb, 0.0)
+    left_built = (side == 0)[..., None, None]
+    gl, gr = jnp.where(left_built, gb, go), jnp.where(left_built, go, gb)
+    hl, hr = jnp.where(left_built, hb, ho), jnp.where(left_built, ho, hb)
+    lead, (p, c, b) = gl.shape[:-3], gl.shape[-3:]
+    g = jnp.stack([gl, gr], axis=-3).reshape(lead + (2 * p, c, b))
+    h = jnp.stack([hl, hr], axis=-3).reshape(lead + (2 * p, c, b))
     return g, h
 
 
@@ -847,7 +963,8 @@ def _pace_dispatch(x) -> None:
 
 def _gbt_round_core(cfg: TreeConfig, binsT, y, weights, pred_raw,
                     feature_mask, mesh=None, subtract=None):
-    grad, hess = gbt_gradients(y, pred_raw, weights, cfg.loss)
+    grad, hess = gbt_gradients(y, _by_row(mesh, pred_raw), weights,
+                               cfg.loss)
     # growth already landed every row on its leaf: one (R,) lookup of
     # leaf_value replaces a full predict_trees re-walk (max_depth
     # passes over the (C, R) bin matrix) for the boosting update
@@ -856,7 +973,8 @@ def _gbt_round_core(cfg: TreeConfig, binsT, y, weights, pred_raw,
                                    return_nodes=True)
     with jax.named_scope("leaf"):
         contrib = _lookup(tree["leaf_value"], node_of_row)
-        return tree, pred_raw + cfg.learning_rate * contrib
+        return (_replicated(mesh, tree),
+                _by_row(mesh, pred_raw + cfg.learning_rate * contrib))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract"))
@@ -886,6 +1004,40 @@ def _gbt_rounds(cfg: TreeConfig, binsT, y, weights, pred_raw,
     return trees, pred_out
 
 
+def _build_meshes(bins):
+    """(mesh that host inputs are placed over, histogram mesh or None)
+    of a resident build, from what the builder is handed. Host inputs:
+    the process's default data mesh. A device input says where it
+    lives: rows over the 'data' axis of its own mesh make that mesh
+    the histogram mesh (`rows.rows_mesh`; any other layout over several
+    devices raises), and an array on one device is built on that
+    device, whatever else the host holds. The histogram mesh is None
+    where its data axis is one chip: the one-device program."""
+    from shifu_tpu.parallel import mesh as mesh_mod, rows
+    if isinstance(bins, jax.Array):
+        hist_mesh = rows.rows_mesh(bins, axis=1)
+        return hist_mesh or mesh_mod.make_mesh(
+            n_data=1, devices=list(bins.sharding.device_set)), hist_mesh
+    mesh = mesh_mod.default_mesh()
+    return mesh, (mesh if _data_size(mesh) > 1 else None)
+
+
+def _n_columns(bins) -> int:
+    """Columns of a builder's bins: axis 0 of the (C, R) device and
+    FusedBins layouts, axis 1 of row-major host bins."""
+    if isinstance(bins, (jax.Array, FusedBins)):
+        return int(bins.shape[0])
+    return int(np.shape(bins)[1])
+
+
+def _zeros_by_row(shape, mesh):
+    """float32 zeros of a per-row shape, made where the rows lie."""
+    if mesh is None:
+        return jnp.zeros(shape, jnp.float32)
+    return jnp.zeros(shape, jnp.float32,
+                     device=_row_sharding(mesh, len(shape)))
+
+
 def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
               weights: np.ndarray, n_trees: int,
               feature_mask: Optional[np.ndarray] = None,
@@ -897,22 +1049,42 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     resumes a previous ensemble (GBT continuous training appends
     trees, TrainModelProcessor.java:1064-1073).
 
-    Rows shard over the default data mesh; zero-weight padding keeps
+    Host inputs ((R, C) bins, row-major) shard by row over the
+    process's default data mesh; zero-weight padding keeps
     gradients/hessians (and hence histograms and leaf values) exact.
+
+    A device input (`bins` a jax.Array) is taken as ALREADY transposed,
+    (C, R), and placed, and the build runs where it lies
+    (`_build_meshes`): on its one device, or, with its rows (axis 1)
+    divided over the 'data' axis of a mesh and nothing else divided
+    (`NamedSharding(mesh, P(None, "data"))`, so R is a multiple of the
+    axis size), on that mesh: per-chip histograms, one all-reduce a
+    level, row state sharded as the rows are. Any other layout over
+    several devices raises; nothing is padded, moved or gathered. `y`
+    and `weights` are placed by row beside it, or must lie so already.
     """
-    from shifu_tpu.parallel import mesh as mesh_mod
+    from shifu_tpu.parallel import mesh as mesh_mod, rows
+    mesh, hist_mesh = _build_meshes(bins)
+    n_cols = _n_columns(bins)
+    # env resolved HERE, outside jit: subtract is a static jit arg, so
+    # an env flip after first compile must produce a fresh trace, not a
+    # silent cache hit on whatever was compiled first
+    subtract = _use_hist_subtract()
     with obs_trace.span("train.job", family="gbt", rows=int(y.shape[0]),
-                        steps=n_trees, bags=1):
+                        steps=n_trees, bags=1, chips=_data_size(hist_mesh),
+                        psum_bytes=psum_bytes(cfg, n_cols, hist_mesh,
+                                              subtract)):
         with obs_trace.span("train.prepare"):
-            mesh = mesh_mod.default_mesh()
-            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
+            fm = jnp.asarray(feature_mask if feature_mask is not None
+                             else np.ones(n_cols, np.float32))
         with obs_trace.span("train.place"):
             # device bins are TRANSPOSED (C, R): rows on the lane axis, so a
-            # narrow feature matrix doesn't lane-pad to 128 columns in HBM.
-            # jax.Array inputs are taken as ALREADY transposed + placed (lets
-            # device-resident data skip the host round-trip entirely).
+            # narrow feature matrix doesn't lane-pad to 128 columns in HBM
+            # (device-resident data skips the host round-trip entirely).
             if isinstance(bins, jax.Array):
-                jb, jy, jw = bins, jnp.asarray(y), jnp.asarray(weights)
+                jb = bins
+                jy = rows.rows_over(hist_mesh, y)
+                jw = rows.rows_over(hist_mesh, weights)
             elif isinstance(bins, FusedBins):
                 # fused path (SHIFU_TPU_HIST_FUSED): raw values shard like the
                 # bin matrix would (NaN pad rows land in the missing bin with
@@ -933,16 +1105,8 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                     pad_value=0)
                 jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
                                              np.asarray(weights, np.float32))
-            # feature count: axis 0 of the (C, R) device layout, axis 1
-            # row-major
-            fm = jnp.asarray(feature_mask if feature_mask is not None
-                             else np.ones(int(jb.shape[0]), np.float32))
-            # env resolved HERE, outside jit: subtract is a static jit arg, so
-            # an env flip after first compile must produce a fresh trace, not a
-            # silent cache hit on whatever was compiled first
-            subtract = _use_hist_subtract()
             trees: List[Any] = []
-            pred = jnp.zeros(jb.shape[1], jnp.float32)
+            pred = _zeros_by_row((jb.shape[1],), hist_mesh)
             if init_trees is not None:
                 n_prev = init_trees["feature"].shape[0]
                 trees = [jax.tree.map(lambda a, i=i: a[i], init_trees)
@@ -1031,14 +1195,16 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
 
 def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w_T, pred_T,
                            fm_T, mesh=None, subtract=None):
-    grad_T, hess_T = gbt_gradients(y[None, :], pred_T, w_T, cfg.loss)
+    grad_T, hess_T = gbt_gradients(y[None, :], _by_row(mesh, pred_T), w_T,
+                                   cfg.loss)
     trees_T, node_T = build_forest(cfg, binsT, grad_T, hess_T, fm_T,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
     with jax.named_scope("leaf"):
         contrib_T = jax.vmap(lambda tr, n: _lookup(tr["leaf_value"], n)
                              )(trees_T, node_T)
-        return trees_T, pred_T + cfg.learning_rate * contrib_T
+        return (_replicated(mesh, trees_T),
+                _by_row(mesh, pred_T + cfg.learning_rate * contrib_T))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract"))
@@ -1078,18 +1244,26 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     dispatch) and its ensemble/val history is truncated to its own
     stop round afterwards, which is exactly what the sequential loop
     would have kept. Returns a list of (stacked trees pytree,
-    val_errs) per bag."""
-    from shifu_tpu.parallel import mesh as mesh_mod
+    val_errs) per bag. Device inputs as `build_gbt` takes them
+    (`weights_T` with its rows on axis 1)."""
+    from shifu_tpu.parallel import mesh as mesh_mod, rows
     n_bags = int(weights_T.shape[0])
+    mesh, hist_mesh = _build_meshes(bins)
+    n_cols = _n_columns(bins)
+    subtract = _use_hist_subtract()
     with obs_trace.span("train.job", family="gbt", rows=int(y.shape[0]),
-                        steps=n_trees, bags=n_bags):
+                        steps=n_trees, bags=n_bags,
+                        chips=_data_size(hist_mesh),
+                        psum_bytes=psum_bytes(cfg, n_cols, hist_mesh,
+                                              subtract, n_bags)):
         with obs_trace.span("train.prepare"):
-            mesh = mesh_mod.default_mesh()
-            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
+            fm = np.asarray(feature_mask if feature_mask is not None
+                            else np.ones(n_cols, np.float32), np.float32)
         with obs_trace.span("train.place"):
             if isinstance(bins, jax.Array):
-                jb, jy = bins, jnp.asarray(y)
-                jw_T = jnp.asarray(weights_T)
+                jb = bins
+                jy = rows.rows_over(hist_mesh, y)
+                jw_T = rows.rows_over(hist_mesh, weights_T, axis=1)
             else:
                 jb = mesh_mod.shard_axis(
                     mesh,
@@ -1098,13 +1272,9 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                 jy = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32))
                 jw_T = mesh_mod.shard_axis(
                     mesh, np.asarray(weights_T, np.float32), 1)
-            fm = np.asarray(feature_mask if feature_mask is not None
-                            else np.ones(int(jb.shape[0]), np.float32),
-                            np.float32)
             fm_T = jnp.asarray(np.broadcast_to(fm[None, :],
                                                (n_bags, fm.size)))
-            subtract = _use_hist_subtract()
-            pred_T = jnp.zeros((n_bags, jb.shape[1]), jnp.float32)
+            pred_T = _zeros_by_row((n_bags, jb.shape[1]), hist_mesh)
 
         if val_data is None and n_trees > 0:
             # no per-round host decision → scan rounds device-side in
@@ -1197,8 +1367,11 @@ def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
     reuses the NN path's bagging_weights semantics."""
     from shifu_tpu.parallel import mesh as mesh_mod
     r, c = bins.shape
+    mesh, hist_mesh = _build_meshes(bins)
+    subtract = _use_hist_subtract()
     with obs_trace.span("train.job", family="rf", rows=r, steps=n_trees,
-                        bags=1):
+                        bags=1, chips=_data_size(hist_mesh),
+                        psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract)):
         with obs_trace.span("train.prepare"):
             rng = np.random.default_rng(seed)
             if stratified or neg_only:
@@ -1217,8 +1390,6 @@ def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                 masks[t, rng.choice(c, size=k, replace=False)] = 1.0
 
         with obs_trace.span("train.place"):
-            mesh = mesh_mod.default_mesh()
-            hist_mesh = mesh if mesh.shape.get("data", 1) > 1 else None
             jb = mesh_mod.shard_axis(
                 mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1)
             jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
@@ -1230,8 +1401,7 @@ def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
             grad_T = -(jy * jw * d_inst_w)
             hess_T = jw * d_inst_w
             stacked = build_forest(cfg, jb, grad_T, hess_T,
-                                   jnp.asarray(masks),
-                                   subtract=_use_hist_subtract(),
+                                   jnp.asarray(masks), subtract=subtract,
                                    mesh=hist_mesh)
         with obs_trace.span("train.wait"):
             jax.block_until_ready(stacked)
@@ -1262,7 +1432,7 @@ def gbt_resident_state_mode(n_train: int, n_val: int = 0) -> bool:
 
 @partial(jax.jit, static_argnames=("cfg", "depth", "mesh", "half"))
 def _stream_level_chunk(cfg: TreeConfig, tree, binsT_c, node_c, grad_c,
-                        hess_c, depth: int, mesh=None, half=False):
+                        hess_c, side, depth: int, mesh=None, half=False):
     """One chunk's work for one level: lazily route the chunk's rows
     through the PREVIOUS level's just-decided splits, then build this
     level's partial histograms — histograms are additive over row
@@ -1271,22 +1441,20 @@ def _stream_level_chunk(cfg: TreeConfig, tree, binsT_c, node_c, grad_c,
     workers, dt/DTWorker.java:914-944). Fusing route+hist keeps disk
     IO at one bins pass per level. binsT_c: (C, chunk) transposed.
 
-    half=True: sibling-subtraction mode — only LEFT children (even
-    level-local slots) through the kernel at parent-slot positions;
-    the caller reconstructs right siblings from the previous level's
-    accumulated histograms (_subtract_siblings)."""
+    half=True: sibling-subtraction mode — of every parent only the
+    child that `side` names (the previous level's `_smaller_child`;
+    None at the root, which routes nothing) through the kernel at
+    parent-slot positions; the caller reconstructs the siblings from
+    the previous level's accumulated histograms (_subtract_siblings)."""
     binsT_c = binsT_c.astype(jnp.int32)
-    if depth > 0:
-        node_c = _route_level(cfg, tree, binsT_c, node_c, depth - 1)
-    level_offset = 2 ** depth - 1
-    n_level = 2 ** depth
     hist_node = node_c
-    if half:
-        hist_node = _left_half_nodes(node_c, level_offset, n_level)
-        n_level //= 2
+    if depth > 0:
+        node_c, half_c = _route_level(cfg, tree, binsT_c, node_c,
+                                      depth - 1, side)
+        hist_node = half_c if half else node_c
     g, h = _level_histograms(binsT_c, hist_node, grad_c, hess_c,
-                             level_offset, n_level, cfg.n_bins,
-                             mesh=mesh)
+                             2 ** depth - 1, _kernel_slots(depth, half),
+                             cfg.n_bins, mesh=mesh)
     return node_c, g, h
 
 
@@ -1380,10 +1548,10 @@ def _build_tree_streaming(cfg: TreeConfig, bins_mm, grad_of_chunk,
                 mesh_mod.shard_axis(mesh, grad_c, 0),
                 mesh_mod.shard_axis(mesh, hess_c, 0))
 
-    prev_g = prev_h = None
+    prev_g = prev_h = side = None
     subtract = _use_hist_subtract()
     for depth in range(cfg.max_depth + 1):
-        half = subtract and depth > 0 and prev_g is not None
+        half = subtract and depth > 0
         g_acc = h_acc = None
         cur = put(bounds[0])
         for ci, (a, b) in enumerate(bounds):
@@ -1391,7 +1559,8 @@ def _build_tree_streaming(cfg: TreeConfig, bins_mm, grad_of_chunk,
             # THEN prepare the next one so host-side transpose/pad/put
             # overlaps device compute, THEN sync on the routed nodes
             node_c, g, h = _stream_level_chunk(
-                cfg, tree, *cur, depth=depth, mesh=hist_mesh, half=half)
+                cfg, tree, *cur, side, depth=depth, mesh=hist_mesh,
+                half=half)
             add_stage_count("tree_build_dispatches")
             if ci + 1 < len(bounds):
                 cur = put(bounds[ci + 1])
@@ -1403,7 +1572,7 @@ def _build_tree_streaming(cfg: TreeConfig, bins_mm, grad_of_chunk,
             split = _parent_split_mask(tree["is_leaf"], tree["feature"],
                                        depth)
             g_acc, h_acc = _subtract_siblings(prev_g, prev_h, g_acc,
-                                              h_acc, split, 2 ** depth)
+                                              h_acc, split, side)
         # only the subtraction mode needs last level's histograms; with
         # it disabled, holding them would pin extra HBM on exactly the
         # memory-scarce path this builder exists for
@@ -1411,6 +1580,7 @@ def _build_tree_streaming(cfg: TreeConfig, bins_mm, grad_of_chunk,
         if depth < cfg.max_depth:
             tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth,
                                 mesh=hist_mesh)
+            side = _smaller_child(cfg, tree, h_acc, depth)
         else:
             tree = _final_leaves(cfg, tree, g_acc, h_acc)
     return tree
@@ -1429,16 +1599,17 @@ def _build_tree_streaming_device(cfg: TreeConfig, bins_put, n_chunks: int,
     routing so the caller can gather leaf contributions afterwards."""
     tree = _empty_tree(cfg)
     fm = jnp.asarray(feature_mask)
-    prev_g = prev_h = None
+    prev_g = prev_h = side = None
     subtract = _use_hist_subtract()
     for depth in range(cfg.max_depth + 1):
-        half = subtract and depth > 0 and prev_g is not None
+        half = subtract and depth > 0
         g_acc = h_acc = None
         cur = bins_put(0)
         for ci in range(n_chunks):
             node_c, g, h = _stream_level_chunk(
                 cfg, tree, cur, node_state[ci], grad_state[ci],
-                hess_state[ci], depth=depth, mesh=hist_mesh, half=half)
+                hess_state[ci], side, depth=depth, mesh=hist_mesh,
+                half=half)
             add_stage_count("tree_build_dispatches")
             if ci + 1 < n_chunks:
                 cur = bins_put(ci + 1)  # h2d overlaps device compute
@@ -1449,11 +1620,12 @@ def _build_tree_streaming_device(cfg: TreeConfig, bins_put, n_chunks: int,
             split = _parent_split_mask(tree["is_leaf"], tree["feature"],
                                        depth)
             g_acc, h_acc = _subtract_siblings(prev_g, prev_h, g_acc,
-                                              h_acc, split, 2 ** depth)
+                                              h_acc, split, side)
         prev_g, prev_h = (g_acc, h_acc) if subtract else (None, None)
         if depth < cfg.max_depth:
             tree = _apply_level(cfg, tree, g_acc, h_acc, fm, depth,
                                 mesh=hist_mesh)
+            side = _smaller_child(cfg, tree, h_acc, depth)
         else:
             tree = _final_leaves(cfg, tree, g_acc, h_acc)
     return tree

@@ -146,10 +146,13 @@ ANNOTATION_PREFIX = "shifu:"
 # (`models/gbdt.py`): gradients, hist (level histograms and sibling
 # subtraction), split (best splits and their fold into the tree),
 # route (rows to their child nodes), leaf (final leaf values, the
-# per-row leaf gather and the prediction update).
+# per-row leaf gather and the prediction update); inside hist, on a
+# data mesh only, allreduce (the level's one psum of the chips' local
+# histograms).
 DEVICE_SCOPES = ("forward_loss", "update", "validate", "select",
                  "embed", "wide", "deep", "table_update",
-                 "gradients", "hist", "split", "route", "leaf")
+                 "gradients", "hist", "split", "route", "leaf",
+                 "allreduce")
 _LAYER_SCOPE = re.compile(r"layer\d+")
 _WORD = re.compile(r"[A-Za-z_]\w*")
 

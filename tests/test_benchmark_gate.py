@@ -21,14 +21,20 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
-CELLS = [w["name"] for w in MANIFEST["workloads"]]
+CHIPS = {w["name"]: w["chips"] for w in MANIFEST["workloads"]}
+CELLS = list(CHIPS)
 
 
-def _python(*args):
-    """A child on a CPU that shows one device (the test rig's own eight
-    would not be the cell's `chips`)."""
+def _python(cell, *args):
+    """A child on a CPU that shows as many devices as the cell's `chips`
+    (the test rig's own eight would not be them): one for a one-chip
+    cell, with no flag at all, and four virtual ones for a cell of a
+    four-chip host."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
+    if CHIPS[cell] > 1:
+        env["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={CHIPS[cell]}"
     return subprocess.run([sys.executable, *args], cwd=REPO, env=env,
                           capture_output=True, text=True, timeout=600)
 
@@ -40,7 +46,7 @@ def _arguments(cell, *extra):
 
 def _run(cell, *extra):
     """One run of the harness as the driver starts it."""
-    return _python(*MANIFEST["command"][1:], *_arguments(cell, *extra))
+    return _python(cell, *MANIFEST["command"][1:], *_arguments(cell, *extra))
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -97,7 +103,7 @@ def test_a_window_builds_and_loads_no_program(cell):
     and reads back nothing (`program_loads_per_call` 0). A static
     argument of `train_bags_carry` that is made anew a call again (a
     closure, an optax transformation) fails here, before the chip."""
-    r = _python("-c", _WINDOW_PROGRAMS.format(repo=REPO),
+    r = _python(cell, "-c", _WINDOW_PROGRAMS.format(repo=REPO),
                 *_arguments(cell, "--rehearse"))
     assert r.returncode == 0, r.stderr[-2000:]
     lines = r.stdout.strip().splitlines()

@@ -157,8 +157,9 @@ def test_route_level_streams_without_gather(one_chip, c, r, depth, b, trees):
     cfg = gbdt.TreeConfig(max_depth=depth, n_bins=b)
 
     def one(tree, binsT, node):
-        node = gbdt._route_level(cfg, tree, binsT, node, depth - 1)
-        return node, gbdt._lookup(tree["leaf_value"], node)
+        node, half = gbdt._route_level(cfg, tree, binsT, node, depth - 1,
+                                       tree["side"])
+        return node, half, gbdt._lookup(tree["leaf_value"], node)
 
     def level(tree, binsT, node):
         if trees == 1:
@@ -173,7 +174,8 @@ def test_route_level_streams_without_gather(one_chip, c, r, depth, b, trees):
 
     tree = {"feature": shape(lead + (n,), I32), "bin": shape(lead + (n,), I32),
             "default_left": shape(lead + (n,), jnp.bool_),
-            "leaf_value": shape(lead + (n,), F32)}
+            "leaf_value": shape(lead + (n,), F32),
+            "side": shape(lead + (2 ** (depth - 1),), I32)}
     compiled = jax.jit(level).lower(
         tree, shape((c, r), I32), shape(lead + (r,), I32)).compile()
     assert not re.search(r"= \S+ gather\(", compiled.as_text())
@@ -271,6 +273,43 @@ def test_whole_gbt_round_compiles_on_four_chip_mesh(topo, monkeypatch):
         shape((28,), F32), mesh=mesh, subtract=None).compile().as_text()
     assert text.count("tpu_custom_call") >= 2      # histogram AND split
     assert "all-reduce" in text and "all-gather" not in text
+
+
+def test_gbt_higgs_x4_rounds_compile_sharded_with_one_all_reduce_a_level(
+        topo, monkeypatch):
+    """The benchmark's `gbt-higgs-x4.train` call as `build_gbt` makes it:
+    two rounds of depth 8 over 2^27 rows divided over the four chips, at
+    the real size. Nine all-reduces (one a level, the leaf level's too)
+    and no other collective: an all-gather would be a row-sized array
+    left replicated. A chip holds its quarter of the rows and of the row
+    state: 4.70 GB of arguments (the 28 columns lie in 32 sublanes),
+    under 2.5 GB of temporaries."""
+    from shifu_tpu.models import gbdt
+    from tests.test_gbt_mesh import _collectives
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+
+    def shape(dims, dtype, *spec):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    r, depth = 2 ** 27, 8
+    cfg = gbdt.TreeConfig(max_depth=depth, n_bins=64, loss="log",
+                          learning_rate=0.1, min_instances_per_node=1)
+    compiled = gbdt._gbt_rounds.lower(
+        cfg, shape((28, r), I32, None, "data"), shape((r,), F32, "data"),
+        shape((r,), F32, "data"), shape((r,), F32, "data"),
+        shape((28,), F32), 2, mesh=mesh, subtract=True).compile()
+    text = compiled.as_text()
+    assert _collectives(text) == {
+        "all-reduce": depth + 1, "all-gather": 0, "reduce-scatter": 0,
+        "all-to-all": 0, "collective-permute": 0}
+    assert text.count("tpu_custom_call") >= 2 * depth + 1
+    memory = compiled.memory_analysis()
+    # bins (28 columns in 32 sublanes: the (8, 128) tile), y, w, pred
+    quarter = (32 * 4 + 3 * 4) * (r // 4)
+    assert memory.argument_size_in_bytes < 1.01 * quarter
+    assert memory.temp_size_in_bytes < 2.5e9
 
 
 def test_wdl_table_step_compiles_and_fits(one_chip):

@@ -191,9 +191,10 @@ def test_route_level_matches_numpy_walk(rng, name, level):
     if not isinstance(given, gbdt.FusedBins):
         given = jnp.asarray(given)
 
-    def route(t, n):
-        return gbdt._route_level(cfg, t, given, n, depth)
+    def route(t, n, sd):
+        return gbdt._route_level(cfg, t, given, n, depth, sd)
 
+    side = rng.integers(0, 2, node.shape[:-1] + (n_level,)).astype(np.int32)
     if name == "vmap":
         fn = jax.jit(jax.vmap(route))
         want = np.stack([_numpy_route({k: v[i] for k, v in tree.items()},
@@ -202,9 +203,17 @@ def test_route_level_matches_numpy_walk(rng, name, level):
     else:
         fn = jax.jit(route)
         want = _numpy_route(tree, bins, node, offset, n_level, cfg.n_bins)
-    got = np.asarray(fn(jtree, jnp.asarray(node)))
+    got, half = (np.asarray(a) for a in fn(jtree, jnp.asarray(node),
+                                           jnp.asarray(side)))
     assert (want != node).any() and (want == node).any()
     np.testing.assert_array_equal(got, want)
+    # the half-width pass's rows: those that went to the child `side`
+    # names for their node, under that node's slot a level down
+    slot = np.clip(node - offset, 0, n_level - 1)
+    went = want - (2 * node + 1)                    # 0 left, 1 right
+    built = (want != node) & (went == np.take_along_axis(side, slot, -1))
+    np.testing.assert_array_equal(half, np.where(built, node + n_level, -1))
+    assert built.any() and ((want != node) & ~built).any()
 
 
 @pytest.mark.parametrize("name", ["mixed", "vmap", "fused"])
@@ -215,7 +224,8 @@ def test_route_lowers_without_gather(rng, name):
     cfg, tree, _, given, node, depth = _route_case(rng, name)
 
     def route(t, n):
-        return gbdt._route_level_at(cfg, t, given, n, 3, 4)
+        return gbdt._route_level_at(cfg, t, given, n, 3, 4,
+                                    jnp.zeros(4, jnp.int32))
 
     fn = route if name != "vmap" else jax.vmap(route)
     text = jax.jit(fn).lower(tree, node).as_text()

@@ -265,7 +265,7 @@ def _kernel_calls(monkeypatch):
 
 def _slots_a_level(depth, subtract):
     """What each level reads: with sibling subtraction the root and
-    then the left children, 1, 1, 2, ..., 2^(D-1); without, the whole
+    then one child of every parent, 1, 1, 2, ..., 2^(D-1); without, the whole
     level, 1, 2, ..., 2^D."""
     if subtract:
         return [1] + [2 ** (d - 1) for d in range(1, depth + 1)]
@@ -313,7 +313,8 @@ def test_one_jit_tree_bitwise_matches_level_dispatches(
     _tree_bitwise(t_jit, t_lvl, f"d{depth}/sub{subtract}")
     # the level dispatches route lazily: the last level's routing is
     # the caller's, as build_gbt_streaming does it
-    n_lvl = gbdt._route_level(cfg, t_lvl, binsT, node_state[0], depth - 1)
+    n_lvl, _ = gbdt._route_level(cfg, t_lvl, binsT, node_state[0], depth - 1,
+                                 jnp.zeros(2 ** (depth - 1), jnp.int32))
     np.testing.assert_array_equal(np.asarray(n_jit), np.asarray(n_lvl))
 
 
