@@ -301,6 +301,17 @@ def forward(spec: MLPSpec, params: Params, x: jax.Array,
         return out[..., 0] if spec.output_dim == 1 else out
 
 
+def penalty(spec: MLPSpec, params: Params):
+    """The L1/L2 regularization terms of the loss (`Weight.java` reg
+    terms); 0.0 where the spec sets neither."""
+    reg = 0.0
+    if spec.l2 > 0.0:
+        reg = reg + spec.l2 * sum(jnp.sum(jnp.square(p["w"])) for p in params)
+    if spec.l1 > 0.0:
+        reg = reg + spec.l1 * sum(jnp.sum(jnp.abs(p["w"])) for p in params)
+    return reg
+
+
 def loss_fn(spec: MLPSpec, params: Params, x: jax.Array, y: jax.Array,
             w: jax.Array, dropout_key: Optional[jax.Array] = None) -> jax.Array:
     """Weighted loss (`core/dtrain/loss/*`: squared / log / absolute) +
@@ -316,14 +327,7 @@ def loss_fn(spec: MLPSpec, params: Params, x: jax.Array, y: jax.Array,
         else:
             per = 0.5 * jnp.sum(jnp.square(onehot - pred), axis=-1)
         total_w = jnp.maximum(jnp.sum(w), 1e-12)
-        loss = jnp.sum(per * w) / total_w
-        if spec.l2 > 0.0:
-            loss = loss + spec.l2 * sum(jnp.sum(jnp.square(p["w"]))
-                                        for p in params)
-        if spec.l1 > 0.0:
-            loss = loss + spec.l1 * sum(jnp.sum(jnp.abs(p["w"]))
-                                        for p in params)
-        return loss
+        return jnp.sum(per * w) / total_w + penalty(spec, params)
     if spec.loss.startswith("log"):
         eps = 1e-7
         per = -(y * jnp.log(pred + eps) + (1 - y) * jnp.log(1 - pred + eps))
@@ -332,12 +336,7 @@ def loss_fn(spec: MLPSpec, params: Params, x: jax.Array, y: jax.Array,
     else:
         per = 0.5 * jnp.square(y - pred)
     total_w = jnp.maximum(jnp.sum(w), 1e-12)
-    loss = jnp.sum(per * w) / total_w
-    if spec.l2 > 0.0:
-        loss = loss + spec.l2 * sum(jnp.sum(jnp.square(p["w"])) for p in params)
-    if spec.l1 > 0.0:
-        loss = loss + spec.l1 * sum(jnp.sum(jnp.abs(p["w"])) for p in params)
-    return loss
+    return jnp.sum(per * w) / total_w + penalty(spec, params)
 
 
 def mse(spec: MLPSpec, params: Params, x: jax.Array, y: jax.Array,

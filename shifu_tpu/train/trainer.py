@@ -41,6 +41,7 @@ from shifu_tpu.config.model_config import ModelTrainConf
 from shifu_tpu.data import pipeline as pipe
 from shifu_tpu.models import nn as nn_mod
 from shifu_tpu.obs import trace as obs_trace
+from shifu_tpu.ops import pallas_mlp
 from shifu_tpu.parallel import mesh as mesh_mod
 from shifu_tpu.train.optimizers import (optimizer_from_params,
                                         program_static)
@@ -413,7 +414,7 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
                checkpoint_dir: Optional[str] = None,
                checkpoint_interval: int = 0,
                batch_rows: int = 0, perm_seed: int = 0,
-               param_shardings=None):
+               param_shardings=None, row_layout=None):
     """Non-resumable façade over train_bags_carry, with optional
     checkpointing: when checkpoint_dir is set, training runs in
     `checkpoint_interval`-epoch chunks, saving the full carry after each
@@ -435,7 +436,11 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
     read-back (`shifu:train.shuffle`) — the within-batch row axis shards
     over the mesh, and the epoch becomes an in-graph scan over shuffled
     batches (see train_bags_carry) — activation memory scales with
-    batch_rows × bags instead of rows × bags."""
+    batch_rows × bags instead of rows × bags.
+
+    `row_layout` (full batch on one device only: `train_nn`'s kernel
+    path) takes `(*train_inputs, w_train_bags)` and returns them as
+    `loss_fn` reads them, once a job, in place of the row sharding."""
     mesh = mesh_mod.default_mesh()
     # .shape, not np.asarray(...).shape: the inputs can be device arrays
     # (on-device data generation), and asarray would pull the whole
@@ -443,6 +448,9 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
     n_rows = int(train_inputs[0].shape[0])
     n_batches = 1
     if batch_rows and 0 < batch_rows < n_rows:
+        if row_layout is not None:
+            raise ValueError("row_layout lays out a full batch; "
+                             f"MiniBatchRows={batch_rows} cuts it")
         n_batches = -(-n_rows // batch_rows)
         with obs_trace.span("train.shuffle", rows=n_rows, batches=n_batches):
             # break any on-disk row ordering (sorted/grouped data would
@@ -469,6 +477,10 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
             train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 1)
                                  for t in train_inputs)
             w_train_bags = mesh_mod.shard_axis(mesh, w_train_bags, axis=2)
+        elif row_layout is not None:
+            *train_inputs, w_train_bags = row_layout(*train_inputs,
+                                                     w_train_bags)
+            train_inputs = tuple(train_inputs)
         else:
             train_inputs = tuple(mesh_mod.shard_axis(mesh, t, 0)
                                  for t in train_inputs)
@@ -578,12 +590,30 @@ def train_bags(loss_fn, metric_fn, optimizer, n_epochs: int,
     return best["params"], train_errs, val_errs, best["val"], best_epoch
 
 
+def mlp_kernel_serves(spec: nn_mod.MLPSpec, full_batch: bool) -> bool:
+    """Whether a job's epoch program is the fused loss-and-gradient
+    kernel (`ops/pallas_mlp.py`) or XLA's: the kernel on a TPU, for a
+    net it implements (one output, hidden layers of at most 128, no
+    dropout, float32), full batch, the rows on one device (a Mosaic
+    kernel is not partitioned over a mesh by itself). Decided from what
+    the job can observe; there is no knob."""
+    return (pallas_mlp.on_chip() and full_batch
+            and pallas_mlp.serves(spec)
+            and mesh_mod.default_mesh().size == 1)
+
+
 @program_static
-def nn_objectives(spec: nn_mod.MLPSpec):
+def nn_objectives(spec: nn_mod.MLPSpec, kernel: bool = False):
     """(loss_fn, metric_fn) of an NN/LR job as `train_bags_carry` takes
-    them, one pair a spec: the spec is all either reads."""
+    them, one pair a spec and path: the spec is all either reads. With
+    `kernel` the loss reads rows laid out by `pallas_mlp.lay_rows`, and
+    its value and gradient are one kernel call."""
     def nn_loss(params, inputs, w, key):
         x_, y_ = inputs
+        if kernel:
+            return pallas_mlp.loss(
+                spec, params, x_, y_, w,
+                interpret=jax.default_backend() != "tpu")
         dkey = key if spec.dropout_rate > 0 else None
         return nn_mod.loss_fn(spec, params, x_, y_, w, dkey)
 
@@ -632,9 +662,15 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
     spec = spec or nn_mod.MLPSpec.from_train_params(
         train_conf.params, input_dim=x.shape[1])
     n_bags = max(train_conf.baggingNum, 1)
+    # train#params MiniBatchRows: mini-batch SGD for data whose
+    # bags × activations exceed HBM full-batch (0 = full batch)
+    batch_rows = int(train_conf.get_param("MiniBatchRows", 0) or 0)
+    kernel = mlp_kernel_serves(
+        spec, full_batch=not 0 < batch_rows < int(x.shape[0]))
 
     with obs_trace.span("train.job", family="nn", rows=int(x.shape[0]),
-                        steps=train_conf.numTrainEpochs, bags=n_bags):
+                        steps=train_conf.numTrainEpochs, bags=n_bags,
+                        mlp_kernel=int(kernel)):
         with obs_trace.span("train.prepare"):
             if val_data is not None:
                 x_tr, y_tr, w_tr = x, y, w
@@ -698,11 +734,7 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
 
             optimizer = optimizer_from_params(train_conf.params)
             early_window = train_conf.earlyStoppingRounds
-            nn_loss, nn_metric = nn_objectives(spec)
-
-            # train#params MiniBatchRows: mini-batch SGD for data whose
-            # bags × activations exceed HBM full-batch (0 = full batch)
-            batch_rows = int(train_conf.get_param("MiniBatchRows", 0) or 0)
+            nn_loss, nn_metric = nn_objectives(spec, kernel)
 
         best_params, train_errs, val_errs, best_val, best_epoch = train_bags(
             nn_loss, nn_metric, optimizer, train_conf.numTrainEpochs,
@@ -713,7 +745,8 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
             bag_keys[:-1], grad_mask,
             checkpoint_dir=checkpoint_dir,
             checkpoint_interval=checkpoint_interval,
-            batch_rows=batch_rows, perm_seed=seed)
+            batch_rows=batch_rows, perm_seed=seed,
+            row_layout=pallas_mlp.lay_rows if kernel else None)
 
         with obs_trace.span("train.fetch"):
             params_per_bag = [

@@ -338,3 +338,92 @@ def test_wdl_table_step_compiles_and_fits(one_chip):
     assert "f32[%d,%d]{1,0" % packed in text, "the table is not row-major"
     table_bytes = packed[0] * packed[1] * 4
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * table_bytes
+
+
+# (hidden widths, activations, loss, output activation, bags): the
+# benchmark's nn-higgs net, two narrow layers, the widest the kernel
+# serves, three bags under vmap, a width that is no sublane multiple
+MLP_CASES = [((64,), ("tanh",), "squared", "sigmoid", 1),
+             ((16, 8), ("relu", "relu"), "log", "sigmoid", 1),
+             ((128, 128), ("sigmoid", "leakyrelu"), "absolute", "linear", 1),
+             ((64,), ("tanh",), "squared", "sigmoid", 3),
+             ((50,), ("tanh",), "log", "tanh", 1)]
+
+
+@pytest.mark.parametrize("hidden,acts,loss,out_act,bags", MLP_CASES)
+def test_mlp_kernel_compiles(one_chip, hidden, acts, loss, out_act, bags):
+    """`ops/pallas_mlp.py`: loss and gradient of a narrow MLP over 10^6
+    rows laid out by `lay_rows`, as `train_bags_carry` differentiates
+    it under its vmap over bags: the (F8, rows) matrix shared, the
+    parameters and the weights a bag each."""
+    from shifu_tpu.models import nn as nn_mod
+    from shifu_tpu.ops import pallas_mlp
+    spec = nn_mod.MLPSpec(28, hidden, acts, loss=loss,
+                          output_activation=out_act)
+    assert pallas_mlp.serves(spec)
+    rp = -(-1_000_000 // pallas_mlp.ROW_TILE) * pallas_mlp.ROW_TILE
+    chunks = (rp // pallas_mlp.CHUNK, pallas_mlp.CHUNK)
+    params = jax.eval_shape(lambda: jax.vmap(
+        lambda k: nn_mod.init_params(spec, k))(
+            jax.random.split(jax.random.PRNGKey(0), bags)))
+
+    def step(params, xT, y, w):
+        return jax.vmap(lambda p, ww: jax.value_and_grad(
+            lambda q: pallas_mlp.loss(spec, q, xT, y, ww))(p))(params, w)
+
+    shapes = [(a.shape, a.dtype) for a in jax.tree.leaves(params)]
+    flat = jax.tree.structure(params)
+    _compile(lambda xT, y, w, *leaves: step(
+        jax.tree.unflatten(flat, leaves), xT, y, w), one_chip,
+        ((32, rp), F32), (chunks, F32), ((bags,) + chunks, F32), *shapes)
+
+
+def test_nn_higgs_epoch_program_holds_the_kernel_and_no_activation(
+        one_chip, monkeypatch):
+    """The benchmark's `nn-higgs.train` job as `train_nn` builds it on a
+    one-chip TPU: 100 full-batch epochs of 28-64-1 over 10.5 M rows. The
+    epoch's loss and gradient are ONE Mosaic call, named after the
+    kernel inside the scope `forward_loss` (what a profiler trace and
+    `benchmark/layer_metrics/mlp_kernel_share.py` find it by), and no
+    (rows, 64) activation lives in HBM: XLA's own program keeps 3.52 GB
+    of temporaries there (PERF.md section 4), this one under 0.6 GB."""
+    import re
+    from shifu_tpu.models import nn as nn_mod
+    from shifu_tpu.ops import pallas_mlp
+    from shifu_tpu.train import trainer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = nn_mod.MLPSpec(28, (64,), ("tanh",))
+    rows, val_rows, bags = 10_500_000, 500_000, 1
+
+    def shape(dims, dtype=F32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    optimizer = trainer.optimizer_from_params({"Propagation": "ADAM",
+                                               "LearningRate": 0.05})
+    keys = jax.random.split(jax.random.PRNGKey(0), bags)
+    carry = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: trainer.init_train_carry(
+            optimizer, jax.vmap(lambda k: nn_mod.init_params(spec, k))(keys),
+            keys)))
+    loss_fn, metric_fn = trainer.nn_objectives(spec, True)
+    rp = -(-rows // pallas_mlp.ROW_TILE) * pallas_mlp.ROW_TILE
+    chunks = (rp // pallas_mlp.CHUNK, pallas_mlp.CHUNK)
+    compiled = trainer.train_bags_carry.lower(
+        loss_fn, metric_fn, optimizer, 100, 0, 0.0, carry,
+        (shape((32, rp)), shape(chunks)), shape((bags,) + chunks),
+        (shape((val_rows, 28)), shape((val_rows,))), shape((val_rows,)),
+        None).compile()
+    text = compiled.as_text()
+    calls = {m.group(1): m.group(2) for m in re.finditer(
+        r'%([\w.-]+) = [^\n]*custom_call_target="tpu_custom_call"'
+        r'[^\n]*op_name="([^"]*)"', text)}
+    assert len(calls) == 1, calls
+    (name, op_name), = calls.items()
+    assert "shifu_mlp_loss_grad" in name
+    assert "/forward_loss/" in op_name and op_name.endswith("/pallas_call")
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.6e9
+    # xT in 32 sublanes, y, w, the validation rows: no second copy
+    assert memory.argument_size_in_bytes < 1.01 * 4 * (
+        34 * rp + 30 * val_rows)

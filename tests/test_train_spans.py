@@ -107,6 +107,24 @@ def test_profiler_trace_holds_the_job_and_its_phases(tmp_path, job, family,
     assert sum(int(e[3]["steps"]) for e in program) == steps
 
 
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["cpu", "tpu"])
+def test_train_job_says_whether_its_program_holds_the_mlp_kernel(
+        tmp_path, monkeypatch, on_tpu):
+    """`shifu:train.job` of an NN job carries `mlp_kernel`: 1 where the
+    epoch program is the fused loss-and-gradient kernel (a narrow net,
+    full batch, one TPU device), else 0."""
+    from shifu_tpu.ops import pallas_mlp
+    monkeypatch.setenv("SHIFU_TPU_MESH_DEVICES", "1")
+    monkeypatch.setattr(pallas_mlp, "on_chip", lambda: on_tpu)
+    monkeypatch.setattr(pallas_mlp, "CHUNKS", 8)
+    monkeypatch.setattr(pallas_mlp, "ROW_TILE", 8 * pallas_mlp.CHUNK)
+    by_line = _profiled_spans(tmp_path, _nn_job)
+    jobs = [e for evs in by_line.values() for e in evs
+            if e[0] == "shifu:train.job"]
+    assert len(jobs) == 1
+    assert int(jobs[0][3]["mlp_kernel"]) == int(on_tpu)
+
+
 def _op_names(compiled_text):
     return set(re.findall(r'op_name="([^"]*)"', compiled_text))
 
@@ -221,6 +239,15 @@ def _score(x, mean, std, w, b):
                                           mode="pallas", interpret=True)
 
 
+def _mlp(xT, y, w):
+    from shifu_tpu.ops import pallas_mlp
+    spec = nn_mod.MLPSpec(input_dim=4, hidden_dims=(6,),
+                          activations=("tanh",))
+    params = nn_mod.init_params(spec, jax.random.PRNGKey(0))
+    return jax.value_and_grad(lambda p: pallas_mlp.loss(
+        spec, p, xT, y, w, interpret=True))(params)
+
+
 F32, I32 = jnp.float32, jnp.int32
 KERNELS = [
     ("shifu_level_histograms", _hist,
@@ -234,6 +261,9 @@ KERNELS = [
      [((8, 16), F32), ((4, 128), F32), ((4, 7), F32)]),
     ("shifu_first_layer", _score,
      [((16, 4), F32), ((4,), F32), ((4,), F32), ((4, 8), F32), ((8,), F32)]),
+    # one row tile of `pallas_mlp.lay_rows`: (F8, 16384), 64 chunks of 256
+    ("shifu_mlp_loss_grad", _mlp,
+     [((8, 16384), F32), ((64, 256), F32), ((64, 256), F32)]),
 ]
 
 
