@@ -1350,62 +1350,184 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                      val_errs[b]) for b in range(n_bags)]
 
 
+# ---------------------------------------------------------------------------
+# Random forest: lockstep groups, bags and feature subsets drawn on the
+# device (models/rf_draw.py)
+# ---------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnames=("cfg", "mesh", "subtract"))
+def _rf_grow(cfg: TreeConfig, binsT, y, w, inst_w, masks, mesh=None,
+             subtract=None):
+    """One lockstep group of a random forest: the trees whose instance
+    weights are `inst_w` (G, R) and feature masks `masks` (G, C). Leaf
+    value = weighted mean label: grad = -y·w·iw, hess = w·iw."""
+    with jax.named_scope("gradients"):
+        inst_w = _by_row(mesh, inst_w)
+        grad_T = -(y * w * inst_w)
+        hess_T = w * inst_w
+    return _replicated(mesh, build_forest(cfg, binsT, grad_T, hess_T, masks,
+                                          mesh=mesh, subtract=subtract))
+
+
+# What a lockstep group of G trees holds beside the table at the deepest
+# level of `_rf_grow`, in bytes a row, as the chip's compiler lays it out
+# (tests/test_chip_compile.py holds the compiled program to it). A
+# (G, rows) array lies in sublane tiles of 1, 2, 4 or 8 trees, so G pads
+# to `_sublane_tile(G)`: ten such 4-byte arrays are live at once (grad,
+# hess, node, the half-level node, the kernel's slot row, routing's two
+# results beside the two they replace, the masks they are selected by):
+# 40 B a padded tree. The kernel's packed (8, rows) [slot, grad, hess]
+# operand is 32 B a tree, and under `vmap` its three row writes keep a
+# second copy alive beside it: 72 B a tree with the level's small change.
+_RF_ROW_BYTES_A_PADDED_TREE = 40
+_RF_ROW_BYTES_A_TREE = 72
+# The share of the device's memory a group may plan to fill: the rest is
+# the allocator's fragmentation and the caller's other arrays.
+_RF_MEMORY_SHARE = 0.75
+
+
+def _sublane_tile(n_trees: int) -> int:
+    """Trees a (G, rows) 32-bit array of n_trees is padded to: the
+    sublane tile (1, 2, 4, then multiples of 8)."""
+    return next((t for t in (1, 2, 4) if n_trees <= t), -(-n_trees // 8) * 8)
+
+
+def rf_group_bytes(n_trees: int, rows_a_chip: int, n_cols: int) -> int:
+    """Bytes a chip holds while a lockstep group of n_trees grows: the
+    table ((columns padded to 8 sublanes) x rows int32 bins, labels,
+    weights), the group's instance weights, and its row state."""
+    tile = _sublane_tile(n_trees)
+    table = 4 * (-(-n_cols // 8) * 8 + 2)
+    return rows_a_chip * (table + (4 + _RF_ROW_BYTES_A_PADDED_TREE) * tile
+                          + _RF_ROW_BYTES_A_TREE * n_trees)
+
+
+def _rf_group_trees(n_trees: int, rows_a_chip: int, n_cols: int,
+                    device) -> int:
+    """Trees `build_rf` grows in lockstep at a time, from bytes alone:
+    the largest whole sublane tile (8 and its multiples, else 4, 2, 1:
+    a (G, rows) array pads G to its tile, so a group between tiles pays
+    for trees it does not grow) whose `rf_group_bytes` stay inside
+    `_RF_MEMORY_SHARE` of the device's memory
+    (`memory_stats()["bytes_limit"]`). A backend that reports no limit
+    (the CPU) takes the whole forest as one group."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return n_trees
+    room = _RF_MEMORY_SHARE * limit
+
+    def fits(g):
+        return rf_group_bytes(g, rows_a_chip, n_cols) <= room
+
+    if fits(n_trees):
+        return n_trees
+    group, tile = 1, 2
+    while tile < n_trees and fits(tile):
+        group, tile = tile, _sublane_tile(tile + 1)
+    return group
+
+
 def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
              weights: np.ndarray, n_trees: int, subset_strategy: str,
              bagging_rate: float, seed: int,
              stratified: bool = False, neg_only: bool = False):
-    """Random forest: all trees independent → ONE lockstep build
-    (build_forest) with per-tree Poisson instance weights (DTWorker
-    Poisson sampling) and Bernoulli feature-subset masks. The
-    histograms go through the same explicit shard_map + psum collective
-    as GBT — no GSPMD-partitioned scatter (silent-gather risk +
-    pathological compile time).
+    """Random forest: independent trees grown in lockstep groups
+    (build_forest: one histogram pass and one split search a level cover
+    a group) with per-tree Poisson instance weights (DTWorker's Poisson
+    sampling) and feature subsets. The histograms go through the same
+    explicit shard_map + psum collective as GBT.
+
+    Inputs as `build_gbt` takes them: host (R, C) bins are placed by row
+    over the process's default data mesh; a device input is taken as
+    ALREADY transposed, (C, R), and placed, and the forest is built
+    where it lies (`_build_meshes`), `y` and `weights` laid by row
+    beside it; nothing is fetched, transposed or gathered.
+
+    **The draw** is the same for host and device inputs and is made on
+    the device (`models/rf_draw.py`, whose docstring is the rule: tree
+    t's bag and subset hang on `fold_in(jax.random.key(seed), t)`; a
+    bag is Poisson(`bagging_rate`) by inversion of 32 uniform bits a
+    row, a subset the `feature_subset_count(subset_strategy, C)`
+    columns of smallest drawn bits; scope `bag`, span `train.bag`). A
+    tree's gradients are `-y·w·iw`, its hessians `w·iw`, made inside
+    the group's program (`_rf_grow`).
 
     `stratified`/`neg_only` (train.stratifiedSample / sampleNegOnly)
-    shape the per-TREE draws — the reference DTWorker honors both for
-    RF (`dt/DTWorker.java:530,660,1390,1550`); per-class balancing
-    reuses the NN path's bagging_weights semantics."""
-    from shifu_tpu.parallel import mesh as mesh_mod
-    r, c = bins.shape
+    shape the per-TREE instance weights — the reference DTWorker honors
+    both for RF (`dt/DTWorker.java:530,660,1390,1550`): exact per-class
+    counts need the labels on the host, so these two keep
+    `trainer.bagging_weights`' host draw (its semantics, its numpy
+    generator) and upload a group's weights at a time; the feature
+    subsets are the device's either way.
+
+    **Groups.** The forest grows `_rf_group_trees` trees at a time, a
+    number read from the rows, the columns and the device's memory
+    (`rf_group_bytes`): a lockstep group holds about 112 bytes a row for
+    every tree, so on a 16 GB chip 2^24 rows take 4 trees at a time and
+    2^23 rows 8. Trees are independent and every tree's draw hangs on
+    its own index t, so the grouping changes no tree. One group's
+    program is in flight at a time."""
+    from shifu_tpu.models import rf_draw
+    from shifu_tpu.parallel import mesh as mesh_mod, rows
     mesh, hist_mesh = _build_meshes(bins)
+    on_device = isinstance(bins, jax.Array)
+    c, r = _n_columns(bins), int(y.shape[0])
     subtract = _use_hist_subtract()
+    chips = _data_size(hist_mesh)
+    k = feature_subset_count(subset_strategy, c)
+    group = _rf_group_trees(n_trees, -(-r // chips), c,
+                            next(iter(mesh.devices.flat)))
+    starts = range(0, n_trees, group)
+    by_row = _row_sharding(hist_mesh, 2) if chips > 1 else None
     with obs_trace.span("train.job", family="rf", rows=r, steps=n_trees,
-                        bags=1, chips=_data_size(hist_mesh),
-                        psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract)):
+                        bags=1, chips=chips, trees=n_trees,
+                        group_trees=group, groups=len(starts),
+                        subset_cols=k, bag_rate=float(bagging_rate),
+                        psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract,
+                                              group)):
         with obs_trace.span("train.prepare"):
-            rng = np.random.default_rng(seed)
+            key = jax.random.key(int(seed))
+            edges = rf_draw.poisson_thresholds(bagging_rate)
+            host_w = None
             if stratified or neg_only:
                 from shifu_tpu.train.trainer import bagging_weights
-                inst_w = bagging_weights(
+                host_w = bagging_weights(
                     r, n_trees, bagging_rate, with_replacement=True,
                     seed=seed, labels=np.asarray(y, np.float32),
                     stratified=stratified, neg_only=neg_only)
-            else:
-                inst_w = rng.poisson(max(bagging_rate, 1e-6),
-                                     size=(n_trees, r)).astype(np.float32)
-            inst_w[inst_w.sum(axis=1) == 0] = 1.0
-            k = feature_subset_count(subset_strategy, c)
-            masks = np.zeros((n_trees, c), np.float32)
-            for t in range(n_trees):
-                masks[t, rng.choice(c, size=k, replace=False)] = 1.0
 
         with obs_trace.span("train.place"):
-            jb = mesh_mod.shard_axis(
-                mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T), 1)
-            jy, jw = mesh_mod.shard_rows(mesh, np.asarray(y, np.float32),
-                                         np.asarray(weights, np.float32))
-            d_inst_w = mesh_mod.shard_axis(mesh, inst_w, axis=1)
+            if on_device:
+                jb = bins
+                jy = rows.rows_over(hist_mesh, y)
+                jw = rows.rows_over(hist_mesh, weights)
+            else:
+                jb = mesh_mod.shard_axis(
+                    mesh, np.ascontiguousarray(np.asarray(bins, np.int32).T),
+                    1)
+                jy, jw = mesh_mod.shard_rows(
+                    mesh, np.asarray(y, np.float32),
+                    np.asarray(weights, np.float32))
 
-        with obs_trace.span("train.program", steps=n_trees):
-            # leaf value = weighted mean label: grad = -y·w·iw, hess = w·iw
-            grad_T = -(jy * jw * d_inst_w)
-            hess_T = jw * d_inst_w
-            stacked = build_forest(cfg, jb, grad_T, hess_T,
-                                   jnp.asarray(masks), subtract=subtract,
-                                   mesh=hist_mesh)
-        with obs_trace.span("train.wait"):
-            jax.block_until_ready(stacked)
+        parts = []
+        for start in starts:
+            ids = np.arange(start, min(start + group, n_trees),
+                            dtype=np.int32)
+            with obs_trace.span("train.bag", trees=len(ids)):
+                masks = rf_draw.masks(key, ids, c, k)
+                if host_w is None:
+                    inst_w = rf_draw.bags(key, ids, int(jb.shape[1]), edges,
+                                          sharding=by_row)
+                else:
+                    inst_w = mesh_mod.shard_axis(mesh, host_w[ids], axis=1)
+            with obs_trace.span("train.program", steps=len(ids)):
+                parts.append(_rf_grow(cfg, jb, jy, jw, inst_w, masks,
+                                      mesh=hist_mesh, subtract=subtract))
+            with obs_trace.span("train.wait"):
+                jax.block_until_ready(parts[-1])
         with obs_trace.span("train.fetch"):
+            stacked = parts[0] if len(parts) == 1 else jax.tree.map(
+                lambda *a: jnp.concatenate(a), *parts)
             return jax.tree.map(np.asarray, stacked)
 
 
@@ -1890,8 +2012,13 @@ def build_rf_streaming(cfg: TreeConfig, bins_mm, y_mm, w_mm, n_trees: int,
                        seed: int, chunk_rows: int = 1 << 20):
     """Out-of-core random forest: trees build sequentially (the
     resident path vmaps them — that needs the whole matrix in HBM),
-    each with counter-based Poisson instance weights and a Bernoulli
-    feature subset, streaming the bin matrix like build_gbt_streaming."""
+    each with counter-based Poisson instance weights and a feature
+    subset of `feature_subset_count` columns, streaming the bin matrix
+    like build_gbt_streaming. Its draws are NOT `build_rf`'s
+    (`models/rf_draw.py`): a chunk's weights come from numpy's Philox
+    keyed by the tree and the chunk's first row, a tree's columns from
+    numpy's `choice`, both on the host, so the same seed gives another
+    forest here than on the resident path."""
     from shifu_tpu.parallel import mesh as mesh_mod
     r, c = bins_mm.shape
     rng = np.random.default_rng(seed)

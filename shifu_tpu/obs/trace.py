@@ -115,13 +115,15 @@ SPAN_FAMILIES: Dict[str, Tuple[str, ...]] = {
     # is placed), shuffle (mini-batch mode only: the rows put in the
     # job seed's order, padded and cut into batches, on the host for
     # host inputs and on the device for device inputs), place (uploads
-    # and the fresh carry), program (the
+    # and the fresh carry), bag (build_rf only: the dispatch of a
+    # lockstep group's instance weights and feature masks, drawn on
+    # the device, or the upload of host-drawn ones), program (the
     # call into the jitted program until it returns to Python: trace,
     # lower, cache read or compile, dispatch), wait (the first
     # blocking read of its results: the host waiting on the device),
     # fetch (the remaining device→host copies and result assembly)
-    "train": ("job", "prepare", "shuffle", "place", "program", "wait",
-              "fetch"),
+    "train": ("job", "prepare", "shuffle", "place", "bag", "program",
+              "wait", "fetch"),
     # the one sanctioned device→host sync, data/pipeline.host_fetch
     "host": ("sync",),
 }
@@ -148,11 +150,12 @@ ANNOTATION_PREFIX = "shifu:"
 # route (rows to their child nodes), leaf (final leaf values, the
 # per-row leaf gather and the prediction update); inside hist, on a
 # data mesh only, allreduce (the level's one psum of the chips' local
-# histograms).
+# histograms). A random forest's draw (`models/rf_draw.py`: `bags`, `masks`):
+# bag (a group's Poisson instance weights and feature subsets).
 DEVICE_SCOPES = ("forward_loss", "update", "validate", "select",
                  "embed", "wide", "deep", "table_update",
                  "gradients", "hist", "split", "route", "leaf",
-                 "allreduce")
+                 "allreduce", "bag")
 _LAYER_SCOPE = re.compile(r"layer\d+")
 _WORD = re.compile(r"[A-Za-z_]\w*")
 

@@ -427,3 +427,49 @@ def test_nn_higgs_epoch_program_holds_the_kernel_and_no_activation(
     # xT in 32 sublanes, y, w, the validation rows: no second copy
     assert memory.argument_size_in_bytes < 1.01 * 4 * (
         34 * rp + 30 * val_rows)
+
+
+def test_rf_higgs_group_compiles_with_one_bin_matrix_inside_its_bytes(
+        one_chip, monkeypatch):
+    """The benchmark's `rf-higgs.train` lockstep group as `build_rf`
+    makes it on a 16 GB chip: four depth-10 trees over 2^24 rows
+    (`gbdt._rf_grow`), and the draw beside it (`rf_draw.bags`). Mosaic
+    takes the histogram kernel under `vmap` at every level's slots, 256
+    and the leaf level's 512 on one column among them: 21 calls, a
+    histogram pass and a split search a level and the leaf pass. The bin
+    matrix is an unbatched operand of the batched `pallas_call` and stays
+    ONE copy (no (4, 28|32, rows) array outside a fusion; the arguments
+    are the table, the group's instance weights and nothing else). The
+    program's bytes are what `gbdt.rf_group_bytes` reckons, from which
+    `build_rf` sizes its groups: 8 trees would need 17.9 GB."""
+    import re
+    from shifu_tpu.models import gbdt, rf_draw
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    r, c, depth, group = 2 ** 24, 28, 10, 4
+
+    def shape(dims, dtype=F32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    cfg = gbdt.TreeConfig(max_depth=depth, n_bins=64)
+    compiled = gbdt._rf_grow.lower(
+        cfg, shape((c, r), I32), shape((r,)), shape((r,)),
+        shape((group, r)), shape((group, c)), mesh=None,
+        subtract=True).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * depth + 1
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(rf"= s32\[{group},(28|32),{r}\]", entry), \
+        "the bin matrix was copied a tree"
+    memory = compiled.memory_analysis()
+    table = (32 * 4 + 2 * 4) * r
+    assert memory.argument_size_in_bytes < 1.01 * (table + group * 4 * r)
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    reckoned = gbdt.rf_group_bytes(group, r, c)
+    assert 0.9 * reckoned < held < 1.02 * reckoned, (held, reckoned)
+    assert gbdt.rf_group_bytes(8, r, c) > 16_909_336_064
+
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=one_chip)
+    drawn = rf_draw.bags.lower(key, shape((group,), I32), r,
+                               rf_draw.poisson_thresholds(1.0)).compile()
+    assert drawn.memory_analysis().temp_size_in_bytes < 4 * r
