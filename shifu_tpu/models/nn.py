@@ -184,10 +184,13 @@ def init_params(spec: MLPSpec, key: jax.Array) -> Params:
     for i in range(len(dims) - 1):
         key, sub = jax.random.split(key)
         fan_in, fan_out = dims[i], dims[i + 1]
-        if spec.weight_init == "he":
-            w = jax.random.normal(sub, (fan_in, fan_out)) * math.sqrt(2.0 / fan_in)
-        elif spec.weight_init == "lecun":
-            w = jax.random.normal(sub, (fan_in, fan_out)) * math.sqrt(1.0 / fan_in)
+        if spec.weight_init in ("he", "lecun"):
+            # the barrier keeps the scale a multiply of its own inside a
+            # jit too (`trainer.fresh_nn_state`), where XLA would fold it
+            # into the draw's own constant: the eager bits either way
+            w = jax.lax.optimization_barrier(
+                jax.random.normal(sub, (fan_in, fan_out))) * math.sqrt(
+                    (2.0 if spec.weight_init == "he" else 1.0) / fan_in)
         elif spec.weight_init == "zero":
             w = jnp.zeros((fan_in, fan_out))
         else:  # xavier / default

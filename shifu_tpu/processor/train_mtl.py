@@ -27,7 +27,7 @@ from shifu_tpu.obs import trace as obs_trace
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import optimizer_from_params
-from shifu_tpu.train.trainer import (bagging_weights, objectives,
+from shifu_tpu.train.trainer import (bag_row_weights, bags_drawn, objectives,
                                      split_validation, train_bags)
 
 log = logging.getLogger("shifu_tpu")
@@ -60,6 +60,13 @@ def load_task_targets(ctx: ProcessorContext, data: dict) -> np.ndarray:
 
 
 def run_mtl(ctx: ProcessorContext, seed: int = 12306):
+    """Train `baggingNum` multi-task models over `norm`'s resident
+    matrix and save them (`train#trainOnDisk` streams instead). Bags
+    are stratified or neg-sampled on the primary task's label; the bag
+    weights are `trainer.bag_row_weights`': one bag at rate >= 1.0
+    without replacement is the training rows' `w` itself with a leading
+    axis (a view, never written to), and the labels are read only for
+    a stratified or neg-only draw."""
     t0 = time.time()
     mc = ctx.model_config
     path = ctx.path_finder.normalized_data_path()
@@ -84,19 +91,16 @@ def run_mtl(ctx: ProcessorContext, seed: int = 12306):
 
     n_bags = max(mc.train.baggingNum, 1)
     with obs_trace.span("train.job", family="mtl", rows=len(y),
-                        steps=mc.train.numTrainEpochs, bags=n_bags):
+                        steps=mc.train.numTrainEpochs, bags=n_bags,
+                        bags_drawn=int(bags_drawn(mc.train, n_bags))):
         with obs_trace.span("train.prepare"):
             tr_mask, val_mask = split_validation(
                 len(y), mc.train.validSetRate, seed)
             # stratify/neg-sample on the primary task's label (task 0 — the
             # same label upSampleWeight keys on above)
-            bag_w = bagging_weights(int(tr_mask.sum()), n_bags,
-                                    mc.train.baggingSampleRate,
-                                    mc.train.baggingWithReplacement, seed,
-                                    labels=np.asarray(y[tr_mask][:, 0]),
-                                    stratified=mc.train.stratifiedSample,
-                                    neg_only=mc.train.sampleNegOnly) \
-                * w[tr_mask][None, :]
+            y_tr = y[tr_mask]
+            bag_w = bag_row_weights(mc.train, y_tr[:, 0], w[tr_mask],
+                                    n_bags, seed)
 
             key = jax.random.PRNGKey(seed)
             bag_keys = jax.random.split(key, n_bags)
@@ -118,7 +122,7 @@ def run_mtl(ctx: ProcessorContext, seed: int = 12306):
             loss, metric, optimizer, mc.train.numTrainEpochs,
             ew if ew and ew > 0 else 0,
             float(mc.train.convergenceThreshold or 0.0),
-            stacked, (dense[tr_mask], y[tr_mask]),
+            stacked, (dense[tr_mask], y_tr),
             bag_w,
             (dense[val_mask], y[val_mask]),
             w[val_mask], bag_keys, grad_mask, param_shardings=shardings)

@@ -22,9 +22,9 @@ from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext
 from shifu_tpu.train.optimizers import (optimizer_from_params,
                                         program_static)
-from shifu_tpu.train.trainer import (TrainResult, bagging_weights,
-                                     objectives, split_validation,
-                                     train_bags)
+from shifu_tpu.train.trainer import (TrainResult, bag_row_weights,
+                                     bags_drawn, objectives,
+                                     split_validation, train_bags)
 
 log = logging.getLogger("shifu_tpu")
 
@@ -67,7 +67,11 @@ def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,
     slot included (`norm`'s `indexVocabSizes`). `val_data` = (dense,
     idx, y, w) overrides the random validSetRate split. train#params
     MiniBatchRows > 0 trains in shuffled mini-batches (see
-    `train_bags`); device inputs then stay on the device."""
+    `train_bags`); device inputs then stay on the device. The bag
+    weights are `trainer.bag_row_weights`': one bag at rate >= 1.0
+    without replacement is `w` itself with a leading axis (a view of a
+    host array), and `y` comes to the host only for a stratified or
+    neg-only draw."""
     t0 = time.time()
     spec = wdl.WDLSpec.from_train_params(train_conf.params, dense.shape[1],
                                          idx.shape[1], vocab_sizes)
@@ -76,6 +80,7 @@ def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,
     n_rows = int(y.shape[0])
     with obs_trace.span("train.job", family="wdl", rows=n_rows,
                         steps=train_conf.numTrainEpochs, bags=n_bags,
+                        bags_drawn=int(bags_drawn(train_conf, n_bags)),
                         batches=(-(-n_rows // batch_rows)
                                  if 0 < batch_rows < n_rows else 1),
                         lookups=n_rows * spec.n_cat
@@ -91,15 +96,7 @@ def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,
                                           for a in (dense, idx, y, w))
                 d_v, i_v, y_v, w_v = (a[val_mask]
                                       for a in (dense, idx, y, w))
-            by_label = train_conf.stratifiedSample or train_conf.sampleNegOnly
-            bag_w = bagging_weights(int(y_tr.shape[0]), n_bags,
-                                    train_conf.baggingSampleRate,
-                                    train_conf.baggingWithReplacement, seed,
-                                    labels=(np.asarray(y_tr) if by_label
-                                            else None),
-                                    stratified=train_conf.stratifiedSample,
-                                    neg_only=train_conf.sampleNegOnly) \
-                * w_tr[None, :]
+            bag_w = bag_row_weights(train_conf, y_tr, w_tr, n_bags, seed)
 
             # rows shard over 'data'; with SHIFU_TPU_MESH_MODEL > 1 the
             # embedding + wide tables additionally shard over 'model' by

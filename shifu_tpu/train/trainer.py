@@ -94,6 +94,11 @@ def bagging_weights(n: int, n_bags: int, sample_rate: float,
     The reference's fixInitialInput (hash-range sampling so resumed
     runs see identical bags) is always-on here: weights derive from a
     fixed seed, so every resume replays the same bags.
+
+    One bag at rate >= 1.0 without replacement is all ones in every
+    branch: the trainers ask `bag_row_weights`, which takes that case
+    without calling here, and which hands over `labels` only where
+    `stratified` or `neg_only` reads them.
     """
     rng = np.random.default_rng(seed)
     if neg_only and stratified and labels is not None:
@@ -179,6 +184,50 @@ def _rescue_empty_bags(w: np.ndarray) -> np.ndarray:
     empty = w.sum(axis=1) == 0
     w[empty] = 1.0
     return w
+
+
+def bags_drawn(train_conf: ModelTrainConf, n_bags: int) -> bool:
+    """Whether a job's bags are a draw at all. One bag at
+    `baggingSampleRate` >= 1.0 without replacement is the rows
+    themselves whatever `stratifiedSample` and `sampleNegOnly` say
+    (`bagging_weights` returns all ones in each of its branches): read
+    from what the job states alone, it is `shifu:train.job`'s
+    `bags_drawn`."""
+    return not (n_bags == 1 and train_conf.baggingSampleRate >= 1.0
+                and not train_conf.baggingWithReplacement)
+
+
+def bag_row_weights(train_conf: ModelTrainConf, y_tr, w_tr, n_bags: int,
+                    seed: int, neg_only: Optional[bool] = None):
+    """(bags, n) training weight of each row in each bag, as
+    `train_bags` takes it: `bagging_weights(...) * w_tr[None, :]`, bit
+    for bit, for the three resident trainers (`train_nn`, `train_wdl`,
+    `run_mtl`). `y_tr`, `w_tr` (n,) host or device arrays; `neg_only`
+    overrides `train_conf.sampleNegOnly` (`train_nn` drops it for
+    native multi-class).
+
+    Where no bag is drawn (`bags_drawn`) the product is `w_tr` itself
+    (`1.0 * w == w`), so `w_tr` goes back with a leading axis, in the
+    product's dtype: a VIEW of a host array, which the caller must not
+    write to, a reshape on the device for a device array; no row-sized
+    array is built, multiplied or uploaded, and `y_tr` is not read.
+    Every other bagging is `bagging_weights`' host draw, and `y_tr`
+    comes to the host only where `stratifiedSample` or `neg_only` will
+    read it."""
+    if not bags_drawn(train_conf, n_bags):
+        result_type = (jnp.result_type if isinstance(w_tr, jax.Array)
+                       else np.result_type)
+        return w_tr.reshape(1, -1).astype(
+            result_type(np.float32, w_tr.dtype), copy=False)
+    if neg_only is None:
+        neg_only = train_conf.sampleNegOnly
+    by_label = train_conf.stratifiedSample or neg_only
+    return bagging_weights(
+        int(w_tr.shape[0]), n_bags, train_conf.baggingSampleRate,
+        train_conf.baggingWithReplacement, seed,
+        labels=np.asarray(y_tr) if by_label else None,
+        stratified=train_conf.stratifiedSample,
+        neg_only=neg_only) * w_tr[None, :]
 
 
 @partial(jax.jit, static_argnames=("loss_fn", "metric_fn", "optimizer",
@@ -639,6 +688,26 @@ def objectives(model, spec):
     return loss, metric
 
 
+@partial(jax.jit, static_argnames=("spec", "n_bags"))
+def fresh_nn_state(key, spec: nn_mod.MLPSpec, n_bags: int):
+    """(dropout keys, stacked initial parameters, all-ones gradient
+    mask) of a `train_nn` job that starts from its seed's key: what
+    `train_nn` computes op by op for a job with `init_params`,
+    `fixed_layers` or a `grad_mask`, as ONE program a (spec, bags),
+    found again by jit's own cache on the hashable spec, so a repeat
+    job dispatches once where it ran a split, a draw and a fill a
+    layer. The bits are the eager lines': each draw is the program
+    `jax.random` compiles by itself, only joined here. The bags are a
+    `lax.map`, not a `vmap`: a draw on a batch of keys is lowered as a
+    vmap of threefry, op by op in Python, which made the first call of
+    a process seconds longer; one bag's draws on one key lower like the
+    eager programs, and compute the same numbers."""
+    bag_keys = jax.random.split(key, n_bags + 1)[:-1]
+    stacked = jax.lax.map(lambda k: nn_mod.init_params(spec, k), bag_keys)
+    grad_mask = jax.tree.map(lambda l: jnp.ones_like(l[0]), stacked)
+    return bag_keys, stacked, grad_mask
+
+
 def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
              w: np.ndarray, seed: int = 12306,
              spec: Optional[nn_mod.MLPSpec] = None,
@@ -657,6 +726,13 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
     freezes those 1-BASED layers (FixedLayers=[1] = the input→hidden1
     weights, `NNMaster.getFixedWights:611-624`); grad_mask overrides
     with an element-wise {0,1} pytree (structure-growth absorption).
+
+    `shifu:train.prepare` is a few dispatches: the bag weights come
+    from `bag_row_weights` (one bag at rate >= 1.0 without replacement
+    is `w` itself with a leading axis, a view of a host array; labels
+    come to the host only for a stratified or neg-only draw), and a
+    job with none of `init_params`, `fixed_layers` and `grad_mask`
+    takes its keys, parameters and mask from `fresh_nn_state`.
     """
     t0 = time.time()
     spec = spec or nn_mod.MLPSpec.from_train_params(
@@ -670,6 +746,7 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
 
     with obs_trace.span("train.job", family="nn", rows=int(x.shape[0]),
                         steps=train_conf.numTrainEpochs, bags=n_bags,
+                        bags_drawn=int(bags_drawn(train_conf, n_bags)),
                         mlp_kernel=int(kernel)):
         with obs_trace.span("train.prepare"):
             if val_data is not None:
@@ -699,38 +776,42 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
                 log.warning("sampleNegOnly ignored for native multi-class "
                             "training (binary/one-vs-all semantics only)")
                 neg_only = False
-            bag_w = bagging_weights(
-                len(y_tr), n_bags, train_conf.baggingSampleRate,
-                train_conf.baggingWithReplacement, seed,
-                labels=np.asarray(y_tr),
-                stratified=train_conf.stratifiedSample,
-                neg_only=neg_only) * w_tr[None, :]
+            bag_w = bag_row_weights(train_conf, y_tr, w_tr, n_bags, seed,
+                                    neg_only=neg_only)
 
             key = jax.random.PRNGKey(seed)
-            bag_keys = jax.random.split(key, n_bags + 1)
-            if init_params is not None:
-                stacked = jax.tree.map(
-                    lambda p: jnp.broadcast_to(p, (n_bags,) + p.shape),
-                    init_params)
+            if init_params is None and grad_mask is None \
+                    and not fixed_layers:
+                dropout_keys, stacked, grad_mask = fresh_nn_state(
+                    key, spec, n_bags)
             else:
-                stacked = jax.vmap(
-                    lambda k: nn_mod.init_params(spec, k))(bag_keys[:-1])
+                # continuous training, frozen layers, structure growth:
+                # op by op, as fresh_nn_state's lines are for its case
+                dropout_keys = jax.random.split(key, n_bags + 1)[:-1]
+                if init_params is not None:
+                    stacked = jax.tree.map(
+                        lambda p: jnp.broadcast_to(p, (n_bags,) + p.shape),
+                        init_params)
+                else:
+                    stacked = jax.vmap(
+                        lambda k: nn_mod.init_params(spec, k))(dropout_keys)
 
-            if grad_mask is None:
-                grad_mask = jax.tree.map(
-                    jnp.ones_like, jax.tree.map(lambda l: l[0], stacked)
-                    if init_params is None else init_params)
-                if fixed_layers:
-                    # 1-based like the reference's FixedLayers: 1 freezes the
-                    # input→hidden1 weight matrix (NNMaster.getFixedWights)
-                    mask_list = []
-                    for i, layer in enumerate(grad_mask):
-                        z = 0.0 if (i + 1) in fixed_layers else 1.0
-                        mask_list.append({k: jnp.full_like(v, z)
-                                          for k, v in layer.items()})
-                    grad_mask = mask_list
-            else:
-                grad_mask = jax.tree.map(jnp.asarray, grad_mask)
+                if grad_mask is None:
+                    grad_mask = jax.tree.map(
+                        jnp.ones_like, jax.tree.map(lambda l: l[0], stacked)
+                        if init_params is None else init_params)
+                    if fixed_layers:
+                        # 1-based like the reference's FixedLayers: 1
+                        # freezes the input→hidden1 weight matrix
+                        # (NNMaster.getFixedWights)
+                        mask_list = []
+                        for i, layer in enumerate(grad_mask):
+                            z = 0.0 if (i + 1) in fixed_layers else 1.0
+                            mask_list.append({k: jnp.full_like(v, z)
+                                              for k, v in layer.items()})
+                        grad_mask = mask_list
+                else:
+                    grad_mask = jax.tree.map(jnp.asarray, grad_mask)
 
             optimizer = optimizer_from_params(train_conf.params)
             early_window = train_conf.earlyStoppingRounds
@@ -742,7 +823,7 @@ def train_nn(train_conf: ModelTrainConf, x: np.ndarray, y: np.ndarray,
             float(train_conf.convergenceThreshold or 0.0),
             stacked, (x_tr, y_tr), bag_w,
             (x_v, y_v), w_v,
-            bag_keys[:-1], grad_mask,
+            dropout_keys, grad_mask,
             checkpoint_dir=checkpoint_dir,
             checkpoint_interval=checkpoint_interval,
             batch_rows=batch_rows, perm_seed=seed,
