@@ -20,6 +20,18 @@ measured (the scheduler's `ready_t`/`start_t`, the serving plane's
 batch splits, the `input.*` stage timers); a profiler session cannot
 take an event after the fact, so those reach the ring buffer only.
 
+A `train.job` span is the one span that always leaves something
+behind: when it closes, one job record (`job_records()`): its attrs, its
+start since the process started, its seconds, and what jax built
+inside it. `profiling`'s listeners hear jax's own build events
+(`jax.monitoring`: a function traced, a jaxpr lowered, an executable
+read back from the persistent cache or compiled) on the thread that
+builds, and a context variable names the job open there; an event with
+no job open is booked to `outside_builds()`. The process's first record
+is kept for good, the newest 64 in a deque. With `SHIFU_TPU_TRACE=1`
+each build stage is also a ring-buffer span `train.build` under the
+span open on that thread (attrs `stage`, `fun`).
+
 Per step, `trace_run` (entered by `cli.main` around every command):
 
 - generates the run_id that also names the `maybe_profile` device
@@ -52,6 +64,8 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import contextvars
+import functools
 import glob
 import json
 import logging
@@ -62,6 +76,7 @@ import time
 import zlib
 from typing import Dict, List, Optional, Tuple
 
+from shifu_tpu import profiling
 from shifu_tpu.analysis.lockcheck import make_lock
 from shifu_tpu.config.environment import knob_bool, knob_int, knob_str
 
@@ -121,9 +136,12 @@ SPAN_FAMILIES: Dict[str, Tuple[str, ...]] = {
     # call into the jitted program until it returns to Python: trace,
     # lower, cache read or compile, dispatch), wait (the first
     # blocking read of its results: the host waiting on the device),
-    # fetch (the remaining device→host copies and result assembly)
+    # fetch (the remaining device→host copies and result assembly);
+    # build (ring buffer only, backfilled from jax's own events: one
+    # stage of building a program, `stage` = trace|lower|load|compile
+    # of function `fun`, under the span open on the building thread)
     "train": ("job", "prepare", "shuffle", "place", "bag", "program",
-              "wait", "fetch"),
+              "wait", "fetch", "build"),
     # the one sanctioned device→host sync, data/pipeline.host_fetch
     "host": ("sync",),
 }
@@ -256,6 +274,22 @@ class Tracer:
                 self._dropped += 1
             self._spans.append(rec)
 
+    def adopt(self, parent: str, children) -> None:
+        """Make `parent` the parent of spans that were recorded before
+        it: a backfilled span whose children closed, and were recorded,
+        first. The children are among the newest records."""
+        want = set(children)
+        with self._lock:
+            for rec in reversed(self._spans):
+                if not want:
+                    break
+                if rec["id"] in want:
+                    want.discard(rec["id"])
+                    if rec["parent"] is not None:
+                        self._child_s[rec["parent"]] -= rec["dur"]
+                    rec["parent"] = parent
+                    self._child_s[parent] += rec["dur"]
+
     def spans(self) -> List[dict]:
         with self._lock:
             return list(self._spans)
@@ -269,7 +303,6 @@ class Tracer:
 
     def summary(self) -> Dict:
         """The steps.jsonl `trace` block, keyed by TRACE_FIELDS."""
-        from shifu_tpu import profiling
         with self._lock:
             retained = list(self._spans)
             total, dropped = self._total, self._dropped
@@ -352,6 +385,172 @@ class _Span:
         return False
 
 
+# ---------------------------------------------------------------------------
+# job records: what a `train.job` took, and what jax built inside it
+# ---------------------------------------------------------------------------
+
+_JOB_SPAN = "train.job"
+# the stages of building a program, as `profiling`'s listeners book
+# them: a function traced, its jaxpr lowered to MLIR, the executable
+# read back from the persistent cache, or compiled
+BUILD_STAGES = ("trace", "lower", "load", "compile")
+_KEPT_JOBS = 64
+_TOP_FUNCTIONS = 8
+
+
+class Builds:
+    """What jax built for one job, or outside every job: self seconds
+    by stage, programs counted, and the same seconds by function. A
+    job's is written by the thread that opened the job and by no other;
+    `outside` under `_jobs_lock`."""
+    __slots__ = ("seconds", "counts", "by_fun")
+
+    def __init__(self):
+        self.seconds = [0.0, 0.0, 0.0, 0.0]
+        self.counts = [0, 0, 0, 0]
+        self.by_fun: Dict[str, List[float]] = {}
+
+    def book(self, stage: int, fun: str, self_s: float) -> None:
+        """One build event: `self_s` of `stage` for `fun`."""
+        self.seconds[stage] += self_s
+        self.counts[stage] += 1
+        row = self.by_fun.get(fun)
+        if row is None:
+            row = self.by_fun[fun] = [0.0, 0.0, 0.0, 0.0]
+        row[stage] += self_s
+
+    def as_dict(self) -> Dict:
+        """The `builds` block, keyed by `profiling.BUILD_FIELDS`."""
+        fields = profiling.BUILD_FIELDS
+        top = sorted(self.by_fun.items(),
+                     key=lambda kv: -sum(kv[1]))[:_TOP_FUNCTIONS]
+        functions = [dict(zip(("fun",) + fields[:4],
+                              [fun] + [round(v, 6) for v in row]))
+                     for fun, row in top]
+        # a program traced is one that was lowered: jax also reports
+        # the trace of a jitted helper inside another function's, and
+        # the re-trace of an eager primitive whose program it then
+        # finds in memory; their seconds count, and they are no program
+        _, traced, loaded, compiled = self.counts
+        return dict(zip(fields, [round(v, 6) for v in self.seconds]
+                        + [traced, loaded, compiled, functions]))
+
+
+# the job open on this thread (jax raises its build events on the
+# thread that builds); a thread started inside a job starts with none
+_OPEN_JOB: contextvars.ContextVar = contextvars.ContextVar(
+    "shifu_tpu_open_job", default=None)
+_jobs_lock = make_lock("obs.jobs")
+_first_job: Optional[dict] = None
+_jobs: collections.deque = collections.deque(maxlen=_KEPT_JOBS)
+_outside = Builds()
+_NO_BUILDS = _outside.as_dict()     # what a job that built nothing holds
+_listening = False
+
+
+@functools.lru_cache(maxsize=1)
+def _process_start_mono() -> Optional[float]:
+    """`time.monotonic()` at the moment the OS started this process:
+    field 22 of `/proc/self/stat` (clock ticks since boot) against the
+    boot clock; None where there is no such file. Read once."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            after_comm = f.read().rsplit(b")", 1)[1].split()
+        since_boot = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - since_boot
+        return time.monotonic() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def _listen() -> None:
+    """The first job of a process makes sure jax's build events are
+    heard (`cli.main` has done it already; `train_nn` called from the
+    benchmark, a notebook or `varselect` has not)."""
+    global _listening
+    _listening = True
+    try:
+        profiling.register_build_listeners()
+    except Exception as e:  # noqa: BLE001 — the account never fails a job
+        log.warning("build listeners unavailable: %s", e)
+
+
+class _Job:
+    """`train.job`: the span itself (`_inner`: the profiler annotation,
+    or the ring-buffer span around it) and the job record it leaves."""
+    __slots__ = ("_inner", "attrs", "builds", "_token", "_t0")
+
+    def __init__(self, inner, attrs: Dict):
+        self._inner = inner
+        self.attrs = attrs
+        self.builds: Optional[Builds] = None
+
+    def __enter__(self):
+        if not _listening:
+            _listen()
+        self._token = _OPEN_JOB.set(self)
+        self._t0 = time.monotonic()
+        self._inner.__enter__()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        global _first_job
+        self._inner.__exit__(et, ev, tb)
+        t1 = time.monotonic()
+        _OPEN_JOB.reset(self._token)
+        born = _process_start_mono()
+        builds = dict(_NO_BUILDS, functions=[]) if self.builds is None \
+            else self.builds.as_dict()
+        rec = dict(zip(profiling.JOB_FIELDS, (
+            self.attrs,
+            None if born is None else round(self._t0 - born, 6),
+            round(t1 - self._t0, 6), builds)))
+        with _jobs_lock:
+            if _first_job is None:
+                _first_job = rec
+            _jobs.append(rec)
+        return False
+
+
+def book_build(stage: int, fun: str, t0_wall: float, t1_wall: float,
+               self_s: float, children=()) -> Optional[str]:
+    """One build event of jax's, from `profiling`'s listener on the
+    building thread: booked to the job open there, else to `outside`;
+    under `SHIFU_TPU_TRACE=1` also a `train.build` span from the
+    event's own start and end, the build spans that closed inside it
+    (`children`) its children. Returns that span's id, or None."""
+    job = _OPEN_JOB.get()
+    if job is not None:
+        if job.builds is None:
+            job.builds = Builds()
+        job.builds.book(stage, fun, self_s)
+    else:
+        with _jobs_lock:
+            _outside.book(stage, fun, self_s)
+    if not active():
+        return None
+    return record_span("train.build", t0_wall - _MONO_OFFSET,
+                       t1_wall - _MONO_OFFSET, children=children,
+                       stage=BUILD_STAGES[stage], fun=fun)
+
+
+def job_records() -> List[dict]:
+    """The job records of this process, each keyed by
+    `profiling.JOB_FIELDS`: the first job's, kept for good, then the
+    newest 64 that are not it, oldest first."""
+    with _jobs_lock:
+        first, newest = _first_job, list(_jobs)
+    if first is None:
+        return []
+    return [first] + [r for r in newest if r is not first]
+
+
+def outside_builds() -> Dict:
+    """What jax built with no job open on the building thread."""
+    with _jobs_lock:
+        return _outside.as_dict()
+
+
 class _Run:
     __slots__ = ("root", "step", "run_id", "enabled", "tracer")
 
@@ -380,19 +579,26 @@ def span(name: str, **attrs):
     `SHIFU_TPU_TRACE=1` is active."""
     run = _RUN
     if run is None or not run.enabled:
-        return _annotation(name, attrs)
-    return _Span(run.tracer, name, attrs)
+        inner = _annotation(name, attrs)
+    else:
+        inner = _Span(run.tracer, name, attrs)
+    if name == _JOB_SPAN:
+        return _Job(inner, attrs)
+    return inner
 
 
 def record_span(name: str, t0_mono: float, t1_mono: float,
                 parent: Optional[str] = None,
-                track: Optional[str] = None, **attrs) -> Optional[str]:
+                track: Optional[str] = None, children=(),
+                **attrs) -> Optional[str]:
     """Backfill one span from monotonic timestamps a layer already
     measured, into the ring buffer only (a profiler session takes no
     event after the fact). `parent` defaults to the calling thread's
     open span (or the run root); `track` groups the event onto a named
-    synthetic Perfetto track instead of the recording thread's. Returns
-    the span id (for parenting children), or None when tracing is off."""
+    synthetic Perfetto track instead of the recording thread's;
+    `children` are ids of spans recorded before this one that lie
+    inside it, and take it as their parent. Returns the span id (for
+    parenting children), or None when tracing is off."""
     run = _RUN
     if run is None or not run.enabled:
         return None
@@ -401,6 +607,8 @@ def record_span(name: str, t0_mono: float, t1_mono: float,
         st = _stack()
         parent = st[-1] if st else tr.root_id
     sid = tr.new_id()
+    if children:
+        tr.adopt(sid, children)
     tr.closed(sid, name, parent, t0_mono, t1_mono, attrs, track=track)
     return sid
 
@@ -461,7 +669,6 @@ def trace_run(root: str, step: str):
     finally:
         root_span.__exit__(None, None, None)
         try:
-            from shifu_tpu import profiling
             profiling.set_step_extra("trace", tracer.summary())
         except Exception as e:  # noqa: BLE001 — never fail the step
             log.warning("trace summary failed: %s", e)

@@ -32,14 +32,35 @@ log = logging.getLogger("shifu_tpu")
 TABLE_LEAVES = ("embed", "wide_cat")
 
 
+class _TablesScoped(optax.GradientTransformation):
+    """An optimizer that also says how its update is applied:
+    `train_bags_carry` adds the update through `apply_updates` where an
+    optimizer has one. The two tables' add lies under the scope their
+    update lies under, so the one fused pass XLA makes of both (value,
+    accumulator and gradient read, value and accumulator written) reads
+    as `update/table_update` and not as the MLP's `update`. Metadata
+    only: the adds are `optax.apply_updates`' own."""
+
+    @staticmethod
+    def apply_updates(params, updates):
+        out = {}
+        for k, v in params.items():
+            if k in TABLE_LEAVES:
+                with jax.named_scope("table_update"):
+                    out[k] = optax.apply_updates(v, updates[k])
+            else:
+                out[k] = optax.apply_updates(v, updates[k])
+        return out
+
+
 @program_static
 def _tables_scoped(optimizer: optax.GradientTransformation
                    ) -> optax.GradientTransformation:
-    """The same optimizer with the two tables' update under the device
-    scope `table_update`, so a trace tells the table pass from the MLP's.
-    Every `Propagation` rule is per element, so the two halves update
-    exactly as the whole did. One wrapper an optimizer, as the optimizer
-    is one object a setting."""
+    """The same optimizer with the two tables' update, and the add that
+    applies it, under the device scope `table_update`, so a trace tells
+    the table pass from the MLP's. Every `Propagation` rule is per
+    element, so the two halves update exactly as the whole did. One
+    wrapper an optimizer, as the optimizer is one object a setting."""
     def scoped_update(grads, state, params=None):
         with jax.named_scope("table_update"):
             return optimizer.update(grads, state, params)
@@ -49,10 +70,10 @@ def _tables_scoped(optimizer: optax.GradientTransformation
             lambda _: "tables" if k in TABLE_LEAVES else "rest", v)
             for k, v in params.items()}
 
-    return optax.multi_transform(
+    return _TablesScoped(*optax.multi_transform(
         {"tables": optax.GradientTransformation(optimizer.init,
                                                 scoped_update),
-         "rest": optimizer}, labels)
+         "rest": optimizer}, labels))
 
 
 def train_wdl(train_conf: ModelTrainConf, dense, idx, y, w, vocab_sizes,

@@ -18,16 +18,28 @@ metrics plus `jax.profiler` traces.
   device command) turns on jax's persistent compilation cache — at
   `JAX_COMPILATION_CACHE_DIR` when the environment sets it, else
   `SHIFU_TPU_COMPILE_CACHE_DIR` (`0`/`off` disables), else the fixed
-  `<checkout>/.jax_cache` — and registers `jax.monitoring` listeners so
-  per-jit compile time and cache hit/miss counts land in the stage
-  timers (`compile_s`, `compile_cache_hits`, `compile_cache_misses` — a
-  miss is a program compiled AND written to the cache) and thence in
-  `steps.jsonl` — restart / resume / supervise / grid-search paths stop
-  re-paying XLA compiles. A thread inside `background_compiles()`
-  counts under `background_compile_*` instead: a build hosted next to
-  a serving fleet (the refresh retrain, the watch loop's drift pass)
-  compiles its own programs for the first time, and those are not the
-  serving path recompiling;
+  `<checkout>/.jax_cache` — and registers the build listeners, so
+  restart / resume / supervise / grid-search paths stop re-paying XLA
+  compiles and `steps.jsonl` shows that they did;
+- `register_build_listeners()` (once a process: from
+  `enable_compile_cache()` or from the first `train.job` span,
+  whichever comes first) hears what jax reports of building a program
+  (`jax.monitoring`) and books each stage's self seconds to the stage
+  timers and so to `steps.jsonl`: `trace_s` (jax tracing the
+  program's Python), `lower_s` (jaxpr to MLIR), `compile_cache_read_s`
+  (a build request the persistent cache answered: key, read,
+  deserialise) and `compile_s` (one it did not: the COMPILER, with the
+  failed lookup and the write), beside the counts `compile_cache_hits`
+  / `compile_cache_misses` (a miss is a program compiled AND written to
+  the cache). So a warm run reads `compile_s` 0 and a read time that
+  grows with the program. The same events are booked to the job that
+  caused them (`obs.trace.job_records()`: by job and by function), and
+  the step's record carries the process's first job under `first_job`
+  (`JOB_FIELDS`, `BUILD_FIELDS`). A thread inside
+  `background_compiles()` counts under `background_*` instead: a build
+  hosted next to a serving fleet (the refresh retrain, the watch
+  loop's drift pass) compiles its own programs for the first time, and
+  those are not the serving path recompiling;
 - `SHIFU_TPU_COMPILE_CACHE_SHARED` names a cluster-shared cache dir (a
   mounted path or a `scheme://` URL; a `scheme://`
   SHIFU_TPU_COMPILE_CACHE_DIR auto-routes here too): entries pull into
@@ -39,11 +51,13 @@ metrics plus `jax.profiler` traces.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import json
 import logging
 import os
+import threading
 import time
 from typing import Dict, Optional, Tuple
 
@@ -51,7 +65,7 @@ log = logging.getLogger("shifu_tpu")
 
 _DISABLED_VALUES = ("0", "off", "none", "disabled", "false", "no")
 _CACHE_MAX_BYTES = 16 << 30   # LRU bound; its real job is the file lock
-_compile_listeners_on = False
+_build_listeners_on = False
 _cache_push_registered: Optional[tuple] = None
 
 # enrichments queued by deeper layers (e.g. the train processor's
@@ -92,12 +106,110 @@ def background_compiles():
         _background.reset(token)
 
 
-def _register_compile_listeners() -> None:
-    """Route jax's compile-time monitoring events into the pipeline
-    stage timers (idempotent; safe on jax builds without the events)."""
-    global _compile_listeners_on
-    if _compile_listeners_on:
+# jax's timed build events (`jax.monitoring`, raised on the thread
+# that builds) and the stage each is booked as, an index into
+# `obs.trace.BUILD_STAGES`. The third wraps the persistent cache's
+# lookup as well as the compiler: it is `load`, whole, where the cache
+# answered, which `_CACHE_READ_EVENT`, raised inside it, says; else
+# `compile`, whole. So a warm run's `compile` is 0, not the cache's
+# bookkeeping.
+_TRACE, _LOWER, _LOAD, _COMPILE = range(4)
+_BUILD_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": _TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": _LOWER,
+    "/jax/core/compile/backend_compile_duration": _COMPILE,
+}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# build events that closed on this thread and that no enclosing event
+# has claimed yet: (start, seconds, ring-buffer span id)
+_UNCLAIMED_MAX = 1 << 14
+_build_tls = threading.local()
+
+
+def _book_stage_time(stage: int, secs: float) -> None:
+    """A build stage's self seconds into the step's stage timers."""
+    from shifu_tpu.data import pipeline as pipe
+    # literal keys: tools/check_steps_schema.py enumerates them
+    if _background.get():
+        if stage == _TRACE:
+            pipe.add_stage_time("background_trace_s", secs)
+        elif stage == _LOWER:
+            pipe.add_stage_time("background_lower_s", secs)
+        elif stage == _LOAD:
+            pipe.add_stage_time("background_compile_cache_read_s", secs)
+        else:
+            pipe.add_stage_time("background_compile_s", secs)
+    elif stage == _TRACE:
+        pipe.add_stage_time("trace_s", secs)
+    elif stage == _LOWER:
+        pipe.add_stage_time("lower_s", secs)
+    elif stage == _LOAD:
+        pipe.add_stage_time("compile_cache_read_s", secs)
+    else:
+        pipe.add_stage_time("compile_s", secs)
+
+
+def _program_name(fun_name: str) -> str:
+    """Tracing names the function (`f`), lowering and the compiler the
+    module (`jit(f)`, `pmap(f)`): one name for the three."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _on_cache_read(event: str, secs: float, **kw) -> None:  # noqa: ARG001
+    """The persistent cache answered a build request on this thread:
+    raised inside the `backend_compile_duration` span that closes next."""
+    if event == _CACHE_READ_EVENT:
+        _build_tls.read_back = True
+
+
+def _on_build_span(event: str, start: float, end: float,
+                   fun_name: str = "", **kw) -> None:  # noqa: ARG001
+    """One of jax's three timed build stages closed on this thread.
+    They nest (a jitted helper traced inside another function's trace
+    or lowering closes first, with its own event), so an event is
+    booked with its self time: its seconds less those of the events
+    that started after it did, which are the ones inside it."""
+    stage = _BUILD_EVENTS.get(event)
+    if stage is None:
         return
+    tls = _build_tls
+    closed = getattr(tls, "closed", None)
+    if closed is None:
+        closed = tls.closed = collections.deque(maxlen=_UNCLAIMED_MAX)
+    secs = max(end - start, 0.0)
+    inside_s, children = 0.0, []
+    while closed and closed[-1][0] >= start:
+        _, child_s, child_id = closed.pop()
+        inside_s += child_s
+        if child_id is not None:
+            children.append(child_id)
+    if stage == _COMPILE:
+        if getattr(tls, "read_back", False):
+            stage = _LOAD       # the cache answered: no compiler ran
+        tls.read_back = False
+    self_s = max(secs - inside_s, 0.0)
+    _book_stage_time(stage, self_s)
+    from shifu_tpu.obs import trace as obs_trace
+    span_id = obs_trace.book_build(stage, _program_name(fun_name), start,
+                                   end, self_s, children)
+    closed.append((start, secs, span_id))
+
+
+def register_build_listeners() -> None:
+    """Hear jax's build events, once a process (idempotent; called by
+    `enable_compile_cache` and by the first `train.job` span, whichever
+    comes first): the three timed stages and the cache's read-back go
+    to the stage timers (`trace_s`, `lower_s`, `compile_cache_read_s`,
+    `compile_s`) and to the account of the job open on the building
+    thread (`obs.trace.book_build`); the cache's hits and misses to
+    `compile_cache_hits` / `compile_cache_misses`. A jax build without
+    these events raises here and the caller carries on without."""
+    global _build_listeners_on
+    if _build_listeners_on:
+        return
+    _build_listeners_on = True
     import jax
     from shifu_tpu.data import pipeline as pipe
 
@@ -114,16 +226,9 @@ def _register_compile_listeners() -> None:
             else:
                 pipe.add_stage_count("compile_cache_misses", 1)
 
-    def _on_duration(event: str, secs: float, **kw) -> None:  # noqa: ARG001
-        if event.endswith("/backend_compile_duration"):
-            if _background.get():
-                pipe.add_stage_time("background_compile_s", secs)
-            else:
-                pipe.add_stage_time("compile_s", secs)
-
     jax.monitoring.register_event_listener(_on_event)
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
-    _compile_listeners_on = True
+    jax.monitoring.register_event_duration_secs_listener(_on_cache_read)
+    jax.monitoring.register_event_time_span_listener(_on_build_span)
 
 
 def _cache_listing(path: str) -> Dict[str, int]:
@@ -239,7 +344,7 @@ def enable_compile_cache() -> Optional[str]:
     exit. Returns the active cache dir or None when disabled. Never
     raises — a cache failure must not take down a run."""
     try:
-        _register_compile_listeners()
+        register_build_listeners()
     except Exception as e:  # noqa: BLE001 — metrics must never fail a run
         log.warning("compile-time listeners unavailable: %s", e)
     try:
@@ -330,6 +435,8 @@ def step_metrics(root: str, step: str, extra: Optional[Dict] = None):
     if extra:
         rec.update(extra)
     _step_extras.clear()   # the interval belongs to THIS step
+    from shifu_tpu.obs import trace as obs_trace
+    jobs_before = bool(obs_trace.job_records())
     try:
         # the interval belongs to THIS step: drop whatever an earlier
         # caller in the same process left behind
@@ -350,6 +457,12 @@ def step_metrics(root: str, step: str, extra: Optional[Dict] = None):
         if _step_extras:
             rec.update(_step_extras)
             _step_extras.clear()
+        if not jobs_before:
+            # the process's first job, where this step ran it: what the
+            # step's first program cost to build (JOB_FIELDS)
+            jobs = obs_trace.job_records()
+            if jobs:
+                rec["first_job"] = jobs[0]
         try:
             from shifu_tpu.data.pipeline import drain_stage_timers
             stages = drain_stage_timers()
@@ -468,6 +581,24 @@ DAG_SUMMARY_FIELDS = ("workers", "total_devices", "wall_s",
 # tools/check_steps_schema.py pins README docs to this tuple the same
 # way it pins ROOFLINE_FIELDS.
 TRACE_FIELDS = ("span_count", "dropped_spans", "top_self")
+
+# the job record's schema: every closed `train.job` span leaves one
+# (`obs.trace.job_records()`, built from exactly these tuples), and a
+# step's steps.jsonl record carries the process's first under
+# `first_job` when that job ran inside the step. JOB_FIELDS: the
+# span's attrs, the job's start in seconds since the OS started the
+# process (null where there is no /proc), its seconds, and `builds`.
+# BUILD_FIELDS: the self seconds jax spent inside the job tracing,
+# lowering, reading executables back from the persistent cache and
+# compiling; the programs traced (and lowered: a helper traced inside
+# another function is part of that program, and the re-trace of an
+# eager primitive whose program is found in memory is none), read back
+# and compiled; and the (at most eight) functions that took most of
+# those seconds, each `fun` with its own four. Pinned in README by
+# tools/check_steps_schema.py like ROOFLINE_FIELDS.
+JOB_FIELDS = ("attrs", "start_s", "seconds", "builds")
+BUILD_FIELDS = ("trace_s", "lower_s", "load_s", "compile_s", "traced",
+                "loaded", "compiled", "functions")
 
 # the metrics store's point schema: every line of tmp/metrics/
 # metrics.jsonl is built from exactly this tuple

@@ -282,6 +282,11 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
             return grads
         return jax.tree.map(lambda g, m: g * m, grads, grad_mask)
 
+    # an optimizer may say under which device scope each leaf's update
+    # is added (`train_wdl._tables_scoped`); the adds are the same
+    apply_updates = getattr(optimizer, "apply_updates",
+                            optax.apply_updates)
+
     def one_bag(carry_in, w_train):
 
         def epoch_step(carry, e):
@@ -301,7 +306,7 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                     with jax.named_scope("update"):
                         grads_b = masked(grads_b)
                         upd, o2 = optimizer.update(grads_b, o, p)
-                        p2 = optax.apply_updates(p, upd)
+                        p2 = apply_updates(p, upd)
                     return (p2, o2, k), (loss_b, jnp.sum(w_train[bi]))
 
                 key, pkey = jax.random.split(key)
@@ -323,7 +328,7 @@ def train_bags_carry(loss_fn, metric_fn, optimizer, n_epochs: int,
                     grads = masked(grads)
                     updates, new_opt_state = optimizer.update(
                         grads, opt_state, params)
-                    new_params = optax.apply_updates(params, updates)
+                    new_params = apply_updates(params, updates)
             with jax.named_scope("update"):
                 # freeze when stopped (scan must run to fixed length)
                 keep = lambda new, old: jax.tree.map(  # noqa: E731

@@ -132,6 +132,67 @@ def test_background_compiles_move_this_threads_events_and_no_others():
     assert requests(after) >= 1 and requests(after, "background_") == 0
 
 
+def test_compile_s_is_the_compiler_alone_and_a_cache_read_is_its_own_key(
+        tmp_path, monkeypatch, restore_cache_config):
+    """The stage timers of one program built twice: first by the compiler
+    (`compile_s`, a miss), then, jit's own caches forgotten, traced and
+    lowered again and read back from the persistent cache (`trace_s`,
+    `lower_s`, `compile_cache_read_s`, a hit) with no `compile_s` at all:
+    what a warm run's `steps.jsonl` shows."""
+    import jax
+    import jax.numpy as jnp
+    from shifu_tpu import profiling
+    from shifu_tpu.data import pipeline
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("SHIFU_TPU_COMPILE_CACHE_MIN_S", "0")
+    was = jax.config.jax_persistent_cache_min_compile_time_secs
+    assert profiling.enable_compile_cache() == str(tmp_path / "cc")
+    salt = int.from_bytes(os.urandom(2), "big") + 2.5
+
+    ones = jnp.ones(9)
+
+    def build():
+        return jax.jit(lambda v: jnp.tanh(v * salt).sum())(ones)
+
+    try:
+        pipeline.drain_stage_timers()
+        build()
+        cold = pipeline.drain_stage_timers()
+        jax.clear_caches()
+        build()
+        warm = pipeline.drain_stage_timers()
+    finally:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", was)
+    assert cold["compile_s"] > 0 and cold["compile_cache_misses"] >= 1
+    assert cold["trace_s"] > 0 and cold["lower_s"] > 0
+    assert warm["compile_cache_hits"] >= 1
+    assert "compile_s" not in warm and "compile_cache_misses" not in warm
+    assert warm["compile_cache_read_s"] > 0
+    assert warm["trace_s"] > 0 and warm["lower_s"] > 0
+
+
+def test_background_builds_keep_every_stage_off_the_bare_timers():
+    """`background_compiles()` keeps its meaning for the stages the
+    listeners hear since they hear all of them: trace and lowering of a
+    background build are `background_*` too."""
+    import jax
+    import jax.numpy as jnp
+    from shifu_tpu import profiling
+    from shifu_tpu.data import pipeline
+    profiling.enable_compile_cache()
+    salt = int.from_bytes(os.urandom(2), "big") + 7.5
+    pipeline.drain_stage_timers()
+    with profiling.background_compiles():
+        jax.jit(lambda v: jnp.cos(v * salt))(jnp.ones(11))
+    stages = pipeline.drain_stage_timers()
+    assert stages["background_trace_s"] > 0
+    assert stages["background_lower_s"] > 0
+    assert stages.get("background_compile_s", 0) + \
+        stages.get("background_compile_cache_read_s", 0) > 0
+    assert not {"trace_s", "lower_s", "compile_s",
+                "compile_cache_read_s"} & set(stages)
+
+
 def test_device_command_records_its_device_whatever_route_it_takes(
         model_set):
     """`cli.main` takes the devices through the lease seam for every

@@ -169,6 +169,56 @@ def test_gbt_program_carries_its_scopes():
                                            "route", "leaf"}
 
 
+def test_wdl_program_carries_its_scopes():
+    """The mini-batch program of a WDL job as jax hands it to the
+    compiler: lookup and its gradient's scatter-add under `embed` and
+    `wide`, the MLP under `deep`, and the tables' optimizer pass AND the
+    add that applies it under `update/table_update`. (What the TPU
+    compiler builds anew while it rewrites a scatter, the id sort and the
+    sorted scatter itself, carries no `op_name` at all: no scope of the
+    program's reaches it.)"""
+    from shifu_tpu.models import wdl
+    from shifu_tpu.processor import train_wdl
+    vocab, n_dense, batch, n_batches = (11, 300, 5), 3, 32, 4
+    spec = wdl.WDLSpec.from_train_params(
+        {"NumHiddenNodes": [8, 4], "ActivationFunc": ["relu", "relu"],
+         "EmbedSize": 4}, n_dense, len(vocab), vocab)
+    optimizer = train_wdl._tables_scoped(trainer.optimizer_from_params(
+        {"Propagation": "ADAGRAD", "LearningRate": 0.01}))
+    loss, metric = trainer.objectives(wdl, spec)
+    keys = jax.random.split(jax.random.PRNGKey(0), 1)
+    stacked = jax.vmap(lambda k: wdl.pad_tables(
+        wdl.init_params(spec, k), 1))(keys)
+    carry = trainer.init_train_carry(optimizer, stacked, keys)
+    rows = (jnp.zeros((n_batches, batch, n_dense)),
+            jnp.zeros((n_batches, batch, len(vocab)), jnp.int32),
+            jnp.zeros((n_batches, batch)))
+    val = (jnp.zeros((batch, n_dense)),
+           jnp.zeros((batch, len(vocab)), jnp.int32), jnp.zeros(batch))
+    lowered = trainer.train_bags_carry.lower(
+        loss, metric, optimizer, 2, 0, 0.0, carry, rows,
+        jnp.ones((1, n_batches, batch)), val, jnp.ones(batch), None,
+        n_batches=n_batches)
+    names = _op_names(lowered.compile().as_text())
+    scopes = _scopes_of(names)
+    assert {"forward_loss", "update", "validate", "select", "embed", "wide",
+            "deep", "table_update"} <= scopes
+    assert scopes <= {"forward_loss", "update", "validate", "select",
+                      "embed", "wide", "deep", "table_update", "layer0",
+                      "layer1", "layer2"}
+    # both scatter-adds are the program's, under the scope of their lookup
+    for table in ("embed", "wide"):
+        assert any(n.endswith(f"forward_loss/transpose(jvp({table}))/"
+                              "scatter-add") for n in names), table
+    # the optimizer's pass over the tables and the add that applies it
+    under = [n for n in names if "/update/table_update/" in n]
+    assert any(n.endswith("/add") for n in under)
+    assert any(n.endswith("/rsqrt") for n in under)
+    # and the MLP's update stays outside it: its adds are `update`'s own
+    adds = [n for n in names if n.endswith("/update/add")]
+    assert adds, "the deep layers' update is added under `update` alone"
+
+
 @pytest.mark.parametrize("op_name,scopes", [
     ("jit(_gbt_rounds)/while/body/closed_call/jit(build_tree)/route/"
      "jit(take_along_axis)/gather", ("route",)),
@@ -308,6 +358,31 @@ def test_disabled_span_touches_no_ring_no_lock_and_no_file(tmp_path,
         assert obs_trace.open_spans() == []
     assert obs_trace._RUN is None
     assert os.listdir(tmp_path) == []
+
+
+def test_disabled_phase_span_is_the_annotation_and_the_job_adds_a_record(
+        monkeypatch):
+    """With every knob unset a phase span is a `TraceAnnotation` and
+    nothing else; `train.job` alone is wrapped, and what the wrapper adds
+    is one record when the span closes."""
+    from jax.profiler import TraceAnnotation
+    monkeypatch.delenv("SHIFU_TPU_TRACE", raising=False)
+    assert obs_trace._RUN is None
+    for stage in obs_trace.SPAN_FAMILIES["train"]:
+        if stage not in ("job", "build"):
+            assert type(obs_trace.span(f"train.{stage}")) is TraceAnnotation
+    assert type(obs_trace.span("host.sync")) is TraceAnnotation
+    job = obs_trace.span("train.job", family="nn", rows=3, steps=1, bags=1)
+    assert type(job._inner) is TraceAnnotation
+    kept = len(obs_trace.job_records())
+    with job:
+        with obs_trace.span("train.program", steps=1):
+            pass
+        assert len(obs_trace.job_records()) == kept
+    newest = obs_trace.job_records()[-1]
+    assert newest["attrs"] == {"family": "nn", "rows": 3, "steps": 1,
+                               "bags": 1}
+    assert newest["seconds"] >= 0 and newest["builds"]["traced"] == 0
 
 
 def test_enabled_span_lands_in_ring_and_profiler_alike(tmp_path,

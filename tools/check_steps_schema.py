@@ -42,6 +42,14 @@ The ``trace`` block (attached to every step run with
 ``profiling.TRACE_FIELDS``, every member must be README-documented,
 and obs/trace.py must build the block from the tuple.
 
+The job record (what a closed ``train.job`` span leaves in
+``obs.trace.job_records()``, and the ``first_job`` block of the step
+that ran the process's first job) is pinned likewise: its schema is
+``profiling.JOB_FIELDS`` with ``profiling.BUILD_FIELDS`` for its
+``builds``, every member must be README-documented, and obs/trace.py
+must build the record from the tuples. Members that are also stage
+keys (`trace_s`, `lower_s`, `compile_s`) stay under the stage check.
+
 The health plane is pinned likewise: every metrics.jsonl point is
 ``profiling.METRIC_FIELDS`` (built by obs/health/store.py), every SLO
 record is ``profiling.HEALTH_FIELDS`` (built by obs/health/slo.py),
@@ -59,6 +67,7 @@ Optionally pass a real steps.jsonl to ALSO verify against a live log
 from __future__ import annotations
 
 import ast
+import functools
 import json
 import os
 import re
@@ -79,12 +88,14 @@ def documented_fields() -> set:
     # documented as those blocks' keys, not inputPipeline stages
     pinned = set(roofline_fields()) | set(fleet_fields()) | \
         set(dag_fields()) | set(dag_summary_fields()) | \
-        set(trace_fields()) | set(metric_fields()) | set(health_fields())
+        set(trace_fields()) | set(metric_fields()) | \
+        set(health_fields()) | (set(job_fields()) - emitted_fields())
     return {tok for tok in _TOKEN.findall(text)
             if "per_s" not in tok and not tok.endswith("_frac")
             and tok not in pinned}
 
 
+@functools.lru_cache(maxsize=None)
 def emitted_fields() -> set:
     out = set()
     for dirpath, dirs, files in os.walk(PKG):
@@ -154,6 +165,10 @@ def dag_summary_fields() -> tuple:
 
 def trace_fields() -> tuple:
     return _profiling_tuple("TRACE_FIELDS")
+
+
+def job_fields() -> tuple:
+    return _profiling_tuple("JOB_FIELDS") + _profiling_tuple("BUILD_FIELDS")
 
 
 def metric_fields() -> tuple:
@@ -262,6 +277,33 @@ def check_trace_docs() -> int:
     return 0
 
 
+def check_job_docs() -> int:
+    """Every JOB_FIELDS / BUILD_FIELDS member (the job record a closed
+    `train.job` span leaves, and the steps.jsonl ``first_job`` block)
+    must be backtick-documented in README's Observability section, and
+    obs/trace.py must build the record from the tuples."""
+    fields = job_fields()
+    with open(README, encoding="utf-8") as f:
+        documented = set(re.findall(r"`([a-z][a-z0-9_]*)`", f.read()))
+    missing = sorted(set(fields) - documented)
+    if missing:
+        print("job record schema drift: JOB_FIELDS/BUILD_FIELDS "
+              f"member(s) never documented in README: {missing}",
+              file=sys.stderr)
+        return 1
+    with open(os.path.join(PKG, "obs", "trace.py"),
+              encoding="utf-8") as f:
+        text = f.read()
+    for tup in ("JOB_FIELDS", "BUILD_FIELDS"):
+        if tup not in text:
+            print("obs/trace.py no longer builds the job record from "
+                  f"profiling.{tup}", file=sys.stderr)
+            return 1
+    print(f"job records: all {len(fields)} JOB_FIELDS + BUILD_FIELDS "
+          "documented in README and pinned in obs/trace.py")
+    return 0
+
+
 def check_health_docs() -> int:
     """Every METRIC_FIELDS member (the metrics.jsonl point schema) and
     HEALTH_FIELDS member (the SLO evaluator's record schema) must be
@@ -351,6 +393,8 @@ def main(argv) -> int:
     if check_dag_docs():
         return 1
     if check_trace_docs():
+        return 1
+    if check_job_docs():
         return 1
     if check_health_docs():
         return 1
