@@ -26,7 +26,7 @@ histogram formulation XLA compiles well:
 
 Trees are flat arrays in a perfect-binary-tree layout (node i's
 children are 2i+1 / 2i+2), so prediction is `max_depth` vectorized
-gathers — no per-row recursion.
+lookups — no per-row recursion.
 """
 
 from __future__ import annotations
@@ -726,9 +726,9 @@ def build_tree(cfg: TreeConfig, binsT, grad, hess, feature_mask, mesh=None,
     (R,) landing node of every row — growth already routed each row
     to its final node (leaves park: _route_level only advances rows
     whose node has feature >= 0), so callers that need per-row leaf
-    values (the boosting update) can gather leaf_value[node] instead
+    values (the boosting update) can look leaf_value[node] up instead
     of re-walking the tree from the root (predict_trees), saving
-    max_depth gathers over the (C, R) bin matrix per round.
+    max_depth passes over the (C, R) bin matrix per round.
     """
     tree, node_of_row = _grow_tree(cfg, binsT, grad, hess, feature_mask,
                                    mesh, subtract)
@@ -874,32 +874,86 @@ def _subtract_siblings(prev_g, prev_h, gb, hb, split, side):
     return g, h
 
 
+# The widest level a walk of a finished tree looks up by select-and-sum
+# (`_lookup`: S compares a row, twice, and the row's bin picked); a wider
+# level is gathered per row instead, five gathers whose cost does not
+# grow with S. On the v5e at 2^22 rows a level took 151 ms against 208
+# at 8192 slots and 301 against 208 at 16384. The leaf value is one
+# lookup against one gather: 10 ms against 36 at 2048 slots, 38 against
+# 36 at 4096 (PERF.md §6, the walk's crossovers)
+_WALK_SELECT_SLOTS = 8192
+_LEAF_SELECT_SLOTS = 2048
+
+
+def walk_levels(max_depth: int) -> Dict[str, int]:
+    """How many levels of a `max_depth` walk take each form, from the
+    levels' widths alone: the `train.validate` span's attributes."""
+    select = sum(2 ** d <= _WALK_SELECT_SLOTS for d in range(max_depth))
+    return {"walk_select_levels": select,
+            "walk_gather_levels": max_depth - select}
+
+
+def _walk_level_select(tree, binsT, node, depth: int, n_bins: int):
+    """One level of a walk, `_route_level_at`'s form: the row's node's
+    fields looked up over the level's own 2^depth slots and the row's
+    bin picked from the (C, R) matrix, all exact integer selects. A leaf
+    reads feature -1, as does a row parked above the level (no slot
+    matches it): both stay where they are."""
+    level = slice(2 ** depth - 1, 2 ** (depth + 1) - 1)
+    local = node - (2 ** depth - 1)
+    feat = _lookup(jnp.where(tree["is_leaf"][level], 0,
+                             tree["feature"][level] + 1), local) - 1
+    bin_dl = _lookup(2 * tree["bin"][level]
+                     + (tree["default_left"][level] != 0), local)
+    row_bin = _pick_row(binsT, feat)
+    go_left = jnp.where(row_bin == (n_bins - 1), bin_dl % 2 == 1,
+                        row_bin <= bin_dl // 2)
+    return jnp.where(feat >= 0, 2 * node + jnp.where(go_left, 1, 2), node)
+
+
+def _walk_level_gather(tree, binsT, node, n_bins: int):
+    """One level of a walk by per-row gathers from the whole tree's
+    tables and the (C, R) matrix."""
+    feat = tree["feature"][node]
+    dl = tree["default_left"][node]
+    row_bin = jnp.take_along_axis(
+        binsT, jnp.maximum(feat, 0)[None, :], axis=0)[0]
+    go_left = jnp.where(row_bin == (n_bins - 1), dl,
+                        row_bin <= tree["bin"][node])
+    return jnp.where(tree["is_leaf"][node] | (feat < 0), node,
+                     2 * node + jnp.where(go_left, 1, 2))
+
+
 def _walk_trees(trees, binsT, max_depth: int, n_bins: int):
-    """Per-tree landing node of every row. binsT: (C, R)."""
+    """Per-tree landing node of every row. binsT: (C, R). Each level
+    takes the form its width asks for (`walk_levels`); both land every
+    row on the same node."""
     if isinstance(binsT, FusedBins):
         # prediction re-walks every feature per level — bin once here
-        # rather than re-deriving per gather (the fused path optimizes
+        # rather than re-deriving per level (the fused path optimizes
         # the level BUILD; a resume/val predict is a one-off)
         from shifu_tpu.ops.pallas_hist import bins_from_values
         binsT = bins_from_values(binsT.valuesT, binsT.cuts, n_bins)
 
     def one_tree(tree):
-        r = binsT.shape[1]
-        node = jnp.zeros(r, jnp.int32)
-        for _ in range(max_depth):
-            feat = tree["feature"][node]
-            sbin = tree["bin"][node]
-            dl = tree["default_left"][node]
-            leaf = tree["is_leaf"][node]
-            row_bin = jnp.take_along_axis(
-                binsT, jnp.maximum(feat, 0)[None, :], axis=0)[0]
-            miss = row_bin == (n_bins - 1)
-            go_left = jnp.where(miss, dl, row_bin <= sbin)
-            nxt = 2 * node + jnp.where(go_left, 1, 2)
-            node = jnp.where(leaf | (feat < 0), node, nxt)
+        node = jnp.zeros(binsT.shape[1], jnp.int32)
+        for depth in range(max_depth):
+            if 2 ** depth <= _WALK_SELECT_SLOTS:
+                node = _walk_level_select(tree, binsT, node, depth, n_bins)
+            else:
+                node = _walk_level_gather(tree, binsT, node, n_bins)
         return node
 
     return jax.vmap(one_tree)(trees)
+
+
+def _leaf_values(tree, node):
+    """tree["leaf_value"][node], selected up to _LEAF_SELECT_SLOTS
+    entries and gathered above; the select moves float32 as its bits."""
+    values = tree["leaf_value"]
+    if values.shape[0] <= _LEAF_SELECT_SLOTS:
+        return _lookup(values, node)
+    return values[node]
 
 
 @partial(jax.jit, static_argnames=("max_depth", "n_bins"))
@@ -908,7 +962,7 @@ def predict_trees(trees, binsT, max_depth: int, n_bins: int):
     arrays; binsT: (C, R) transposed. Returns (T, R) raw scores (caller
     averages for RF / shrinks+offsets for GBT)."""
     nodes = _walk_trees(trees, binsT, max_depth, n_bins)
-    return jax.vmap(lambda tree, n: tree["leaf_value"][n])(trees, nodes)
+    return jax.vmap(_leaf_values)(trees, nodes)
 
 
 @partial(jax.jit, static_argnames=("max_depth", "n_bins"))
@@ -1171,7 +1225,8 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                 # don't bias it; the early-stop decision is a per-round
                 # host branch, so this sync is intentional — host_fetch
                 # times and counts it
-                with obs_trace.span("train.validate"):
+                with obs_trace.span("train.validate",
+                                    **walk_levels(cfg.max_depth)):
                     vraw, err = tree_validation.shifu_validate(
                         cfg, jax.tree.map(lambda a: a[None], tree), vb,
                         vraw, vy, vw)
@@ -1381,7 +1436,8 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
             with obs_trace.span("train.wait"):
                 jax.block_until_ready([g["pred_T"] for g in groups])
             # ONE fetch decides every bag's round: (bags,) errors
-            with obs_trace.span("train.validate"):
+            with obs_trace.span("train.validate",
+                                **walk_levels(cfg.max_depth)):
                 errs = []
                 for g, trees_T in zip(groups, trees_t):
                     g["vraw_T"], err = tree_validation.shifu_validate(

@@ -49,9 +49,9 @@ def shifu_validate(cfg: TreeConfig, trees_T, vbinsT, vraw_T, vy, vw):
     into that ensemble's raw scores `vraw_T` (G, V), and each one's
     `gbdt._val_error`. Returns (vraw_T, (G,) errors)."""
     with jax.named_scope("validate"):
-        # one tree at a time: a walk batched over trees holds 0.5 GB a
-        # tree of temporaries at 2^22 rows, and 16 GB for a group that is
-        # no power of two (the chip's compiler; one tree holds 60 MB)
+        # one tree at a time: the temporaries are one tree's whatever the
+        # group (a gather walk batched over a group of five compiled to
+        # 16 GB at 2^22 rows; one tree's select walk holds 0.4 MB)
         score = jax.lax.map(lambda tree: predict_trees(
             jax.tree.map(lambda a: a[None], tree), vbinsT, cfg.max_depth,
             cfg.n_bins)[0], trees_T)

@@ -554,6 +554,7 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
     `gbdt.rf_group_bytes` reckons for the group, from which the builder
     sizes its groups: a round of four holds 2.82 GB of arguments and
     1.97 GB of temporaries for 11.39 GB reckoned."""
+    import re
     from shifu_tpu.models import gbdt, tree_validation
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     r, c, v, depth, bags = 2 ** 24, 28, 2 ** 22, 8, 5
@@ -574,7 +575,13 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
     validated = tree_validation.shifu_validate.lower(
         cfg, trees, shape((c, v), I32), shape((group, v)), shape((v,)),
         shape((v,))).compile()
-    assert "shifu_validate" in validated.as_text()
+    val_text = validated.as_text()
+    assert "shifu_validate" in val_text
+    # a depth-8 walk takes the select form at every level and for its
+    # 511 leaf values: nothing is gathered per row
+    assert gbdt.walk_levels(depth) == {"walk_select_levels": depth,
+                                       "walk_gather_levels": 0}
+    assert not re.search(r"= \S+ gather\(", val_text), "the walk gathers"
     val_memory = validated.memory_analysis()
     others = bags - group if group < bags else 0
     held = (round_memory.argument_size_in_bytes
@@ -585,6 +592,6 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
                                    val_rows=v)
     assert held < reckoned, (held, reckoned)
     # the walk goes a tree at a time: its temporaries are one tree's,
-    # whatever the group (batched over trees, 2.25 GB at 4 and 16.2 GB
-    # at 5: a group that is no power of two)
+    # whatever the group (the gather walk batched over trees held
+    # 2.25 GB at 4 and 16.2 GB at 5: a group that is no power of two)
     assert val_memory.temp_size_in_bytes < 0.1e9
