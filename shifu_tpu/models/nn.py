@@ -271,20 +271,79 @@ def forward(spec: MLPSpec, params: Params, x: jax.Array,
     """Batched forward pass → (N,) score in (0,1) for binary output.
     Dropout (train-time only) mirrors NNMaster's per-iteration node
     sampling (`NNMaster.doCompute:323` dropout nodes)."""
+    return forward_from_sums(spec, params, first_layer_sums(spec, params, x),
+                             dropout_key)
+
+
+def _cast(spec: MLPSpec):
     # bfloat16 compute: GEMM operands and stored activations in bf16,
     # accumulation pinned to f32 (mm_f32's preferred_element_type), so
     # bias-add, activation and every reduction happen in f32; master
     # params/grads stay f32 — autodiff through the casts yields f32
     # grads, so the optimizer and checkpoints are unchanged. Halves the
     # HBM bytes the wide training shape streams per epoch.
-    bf16 = spec.compute_dtype == "bfloat16"
-    cast = (lambda a: a.astype(jnp.bfloat16)) if bf16 else (lambda a: a)
-    h = cast(x)
+    if spec.compute_dtype == "bfloat16":
+        return lambda a: a.astype(jnp.bfloat16)
+    return lambda a: a
+
+
+def first_layer_sums(spec: MLPSpec, params: Params,
+                     x: jax.Array) -> jax.Array:
+    """x W1 + b1, (N, units of the first layer): the first hidden
+    layer's sums, or for LR the logit. The product multiplies in
+    `first_layer_operand_dtype(spec)`."""
+    cast = _cast(spec)
+    x = cast(x)
     # one named scope a layer (metadata only): a profiler trace then
     # names each device op by its layer, forward and backward
+    with jax.named_scope("layer0"):
+        return mm_f32(x, cast(params[0]["w"])) + params[0]["b"]
+
+
+# jax's default matmul precisions that multiply float32 in one
+# bfloat16 pass on a TPU
+_ONE_PASS = (None, "default", "bfloat16", "fastest")
+
+
+def first_layer_operand_dtype(spec: MLPSpec):
+    """The dtype `first_layer_sums`' product multiplies in: bfloat16
+    where the spec computes in it, or where jax's default precision on
+    a TPU rounds float32 operands for one MXU pass; float32 elsewhere:
+    a CPU, a precision above the default, and a product onto a single
+    unit (an LR logit), which XLA computes on a TPU as a float32
+    multiply and reduce on the vector unit. A caller that takes a
+    column's term out of the sums rounds it so (`processor/sensitivity`)."""
+    if spec.compute_dtype == "bfloat16":
+        return jnp.bfloat16
+    if (jax.default_backend() == "tpu" and spec.layer_dims[1] > 1
+            and jax.config.jax_default_matmul_precision in _ONE_PASS):
+        return jnp.bfloat16
+    return jnp.float32
+
+
+def forward_from_sums(spec: MLPSpec, params: Params, z: jax.Array,
+                      dropout_key: Optional[jax.Array] = None,
+                      rows_last: bool = False) -> jax.Array:
+    """`forward` from the first layer's sums z (`first_layer_sums`) on:
+    what Shifu's `CacheBasicFloatNetwork` scores from a row's cached
+    first layer. z is (..., N, units), or with `rows_last` (..., units,
+    N), the rows on the minor axis; the scores are (..., N) for one
+    output, else (..., N, outputs) or (..., outputs, N)."""
+    cast = _cast(spec)
+    units = -2 if rows_last else -1
+
+    def dense(h, layer):
+        if rows_last:
+            return jnp.einsum("...ir,io->...or", h, cast(layer["w"]),
+                              preferred_element_type=jnp.float32) \
+                + layer["b"][:, None]
+        return mm_f32(h, cast(layer["w"])) + layer["b"]
+
+    h = z
     for i, layer in enumerate(params[:-1]):
         with jax.named_scope(f"layer{i}"):
-            h = mm_f32(h, cast(layer["w"])) + layer["b"]
+            if i:
+                h = dense(h, layer)
             h = activation(spec.activations[i])(h)
             if dropout_key is not None and spec.dropout_rate > 0.0:
                 dropout_key, sub = jax.random.split(dropout_key)
@@ -294,14 +353,16 @@ def forward(spec: MLPSpec, params: Params, x: jax.Array,
                               jnp.zeros((), h.dtype))
             h = cast(h)
     with jax.named_scope(f"layer{len(params) - 1}"):
-        out = mm_f32(h, cast(params[-1]["w"])) + params[-1]["b"]
+        out = dense(h, params[-1]) if len(params) > 1 else h
         if spec.output_activation == "softmax":
             # multi-class NATIVE head: one unit per flattened tag
             # (train#multiClassifyMethod NATIVE — the reference builds an
             # Encog net with tags.size() output neurons)
-            return jax.nn.softmax(out, axis=-1)
+            return jax.nn.softmax(out, axis=units)
         out = activation(spec.output_activation)(out)
-        return out[..., 0] if spec.output_dim == 1 else out
+        if spec.output_dim > 1:
+            return out
+        return out[..., 0, :] if rows_last else out[..., 0]
 
 
 def penalty(spec: MLPSpec, params: Params):

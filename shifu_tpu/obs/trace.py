@@ -140,12 +140,15 @@ SPAN_FAMILIES: Dict[str, Tuple[str, ...]] = {
     # with validation rows, once a round: the dispatch of the round's
     # validation scoring, `tree_validation.shifu_validate`, and the
     # fetch of its errors), fetch (the remaining device→host copies
-    # and result assembly);
+    # and result assembly), ablate (`processor/sensitivity`'s SE/ST
+    # variable selection, beside the quick train's job: the rows'
+    # placement, the dispatch of `shifu_ablate`, the wait and the one
+    # fetch of every column's delta);
     # build (ring buffer only, backfilled from jax's own events: one
     # stage of building a program, `stage` = trace|lower|load|compile
     # of function `fun`, under the span open on the building thread)
     "train": ("job", "prepare", "shuffle", "place", "bag", "program",
-              "wait", "validate", "fetch", "build"),
+              "wait", "validate", "fetch", "ablate", "build"),
     # the one sanctioned device→host sync, data/pipeline.host_fetch
     "host": ("sync",),
 }
@@ -175,11 +178,14 @@ ANNOTATION_PREFIX = "shifu:"
 # per-row leaf gather and the prediction update); inside hist, on a
 # data mesh only, allreduce (the level's one psum of the chips' local
 # histograms). A random forest's draw (`models/rf_draw.py`: `bags`, `masks`):
-# bag (a group's Poisson instance weights and feature subsets).
+# bag (a group's Poisson instance weights and feature subsets). SE/ST
+# variable selection (`processor/sensitivity.shifu_ablate`): ablate
+# (the first layer's sums, every column wiped out of them and the rows
+# scored again).
 DEVICE_SCOPES = ("forward_loss", "update", "validate", "select",
                  "embed", "wide", "deep", "table_update",
                  "gradients", "hist", "split", "route", "leaf",
-                 "allreduce", "bag")
+                 "allreduce", "bag", "ablate")
 _LAYER_SCOPE = re.compile(r"layer\d+")
 _WORD = re.compile(r"[A-Za-z_]\w*")
 

@@ -72,6 +72,12 @@ TILES_PER_PARTIAL = 8   # grid steps accumulated into one output block
 LANES = 128
 MAX_WIDTH = 128         # a layer's weights and a chunk's activations:
 #                         one pass of the MXU's width, a few vregs a row
+# the widest input: a grid step's (F8, ROW_TILE) block of xT, double
+# buffered, is F8 x 128 KiB of VMEM. The v5e's compiler takes F8 = 120
+# and refuses 128 and above at every hidden width from 16 to 128
+# (`tests/test_chip_compile.py` compiles both sides): at 128 the block
+# alone is the kernel's whole 16 MiB
+MAX_INPUT = 120
 
 _ACTS = ("tanh", "ptanh", "sigmoid", "relu", "leakyrelu", "linear")
 
@@ -85,10 +91,13 @@ def _loss_kind(name: str) -> str:
 
 def serves(spec: nn_mod.MLPSpec) -> bool:
     """True for the nets the kernel implements: one output unit, at
-    least one hidden layer and none wider than MAX_WIDTH, no dropout,
-    float32 compute, activations it has a derivative for."""
+    most MAX_INPUT inputs, at least one hidden layer and none wider than
+    MAX_WIDTH, no dropout, float32 compute, activations it has a
+    derivative for. A wider input is XLA's epoch: its row tile would not
+    fit the kernel's VMEM."""
     acts = [str(a).lower() for a in spec.activations]
     return (spec.output_dim == 1
+            and spec.input_dim <= MAX_INPUT
             and 1 <= len(spec.hidden_dims)
             and max(spec.hidden_dims) <= MAX_WIDTH
             and spec.dropout_rate == 0

@@ -150,16 +150,17 @@ class _ImportanceAccumulator:
         if self.kind in ("gbt", "rf"):
             return
         if self.kind in ("nn", "lr"):
-            from shifu_tpu.models import nn as nn_mod
-            from shifu_tpu.processor.varselect import _sensitivity_kernel
-            jx = jnp.asarray(result.dense)
-            base = nn_mod.forward(self._spec, self._jparams, jx)
-            # n_real=1 → per-column SUMS of squared deltas, mergeable
-            deltas = np.asarray(_sensitivity_kernel(
-                self._spec, self._jparams, jx, base, n_real=1))
-            for name, d in zip(result.dense_names, deltas):
-                self.sums[name] = self.sums.get(name, 0.0) + float(d)
-            self.n += result.dense.shape[0]
+            from shifu_tpu.processor import sensitivity
+            rows, cols = result.dense.shape
+            deltas, _ = sensitivity.shifu_ablate(
+                self._spec, self._jparams, jnp.asarray(result.dense), rows,
+                sensitivity.ablate_blocks(
+                    rows, cols, max(self._spec.layer_dims[1:])))
+            # the chunk's mean times its rows: per-column SUMS of squared
+            # deltas, mergeable
+            for name, d in zip(result.dense_names, np.asarray(deltas)):
+                self.sums[name] = self.sums.get(name, 0.0) + float(d) * rows
+            self.n += rows
             return
         # wdl/mtl: host-loop column ablation through the generic
         # predictor (dense cols zeroed; index cols set to missing slot)

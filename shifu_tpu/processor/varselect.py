@@ -6,10 +6,9 @@ Replaces `core/processor/VarSelectModelProcessor.java:124-318`:
   columns by the stats phase's metrics and keep the top filterNum;
 - SE / ST sensitivity runs the reference's "wipe one column, re-score"
   MapReduce job (`core/varselect/VarSelectMapper.java:54-272`, cached
-  forward via CacheBasicFloatNetwork) as ONE vmapped column-ablation
-  pass — the single biggest algorithmic win of the TPU port: the
-  reference re-forwards each record per column on CPU; here all C
-  ablated forwards run as one batched kernel;
+  forward via CacheBasicFloatNetwork) in `processor/sensitivity.py`:
+  the quick train, then one program that takes each column's term out
+  of the cached first-layer sums and scores every row again;
 - missingRateThreshold and forceSelect/forceRemove are honored like
   `VarSelectModelProcessor.candidates` preprocessing;
 - recursive mode (-r) re-runs SE on the surviving set — the reference's
@@ -28,7 +27,6 @@ from __future__ import annotations
 import logging
 import os
 import time
-from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -42,7 +40,7 @@ from shifu_tpu.config.model_config import ModelConfig
 from shifu_tpu.models import nn as nn_mod
 from shifu_tpu.processor import norm as norm_proc
 from shifu_tpu.processor.base import ProcessorContext, step_guard
-from shifu_tpu.train.trainer import train_nn
+from shifu_tpu.processor.sensitivity import sensitivity_select
 
 log = logging.getLogger("shifu_tpu")
 
@@ -198,30 +196,15 @@ def _filter_by_stats(ctx: ProcessorContext, candidates: List[ColumnConfig],
         cc.finalSelect = cc.finalSelect or ok
 
 
-@partial(jax.jit, static_argnames=("spec",))
-def _sensitivity_kernel(spec, params, x, base_score, n_real=None):
-    """(C,) mean squared score delta when column c is wiped to 0
-    (normalized space ⇒ 0 is the mean / missing value), the
-    `VarSelectMapper` MSE delta — all columns at once via vmap.
-    `n_real` divides out mesh padding rows (all-zero rows score
-    identically wiped or not, so they add 0 to the sums)."""
-    c = x.shape[1]
-    n = n_real if n_real is not None else x.shape[0]
-
-    def wiped(col):
-        mask = jnp.ones((c,)).at[col].set(0.0)
-        s = nn_mod.forward(spec, params, x * mask[None, :])
-        return jnp.sum(jnp.square(s - base_score)) / n
-
-    return jax.vmap(wiped)(jnp.arange(c))
-
-
 def _filter_by_sensitivity(ctx: ProcessorContext,
                            candidates: List[ColumnConfig], by: str,
                            seed: int) -> None:
     """SE: train a quick NN on all candidates, ablate each column, rank
-    by score MSE delta. ST ranks by relative delta (delta / score var),
-    approximating the reference's sensitivity-type toggle."""
+    by score MSE delta (`processor/sensitivity.sensitivity_select`). ST
+    ranks by relative delta (delta / score var), approximating the
+    reference's sensitivity-type toggle. Here: the candidates' loading
+    and normalizing, the dense columns' deltas summed by source column,
+    the `se.0` file and the selection."""
     mc = ctx.model_config
     vs = mc.varSelect
     for cc in candidates:
@@ -248,41 +231,16 @@ def _filter_by_sensitivity(ctx: ProcessorContext,
         sens_mc.normalize = _copy.copy(mc.normalize)
         sens_mc.normalize.normType = dense_equiv
     result = norm_proc.normalize_columns(sens_mc, cols, dset)
-    x = result.dense.astype(np.float32)
-    y = dset.tags
-    w = dset.weights
-
-    # half-epoch quick train (TrainModelProcessor isForVarSelect,
-    # TrainModelProcessor.java:1588-1591)
-    import copy
-    conf = copy.copy(mc.train)
-    conf.numTrainEpochs = max(mc.train.numTrainEpochs // 2, 10)
-    conf.baggingNum = 1
-    res = train_nn(conf, x, y, w, seed=seed)
-    params = jax.tree.map(jnp.asarray, res.params_per_bag[0])
-
-    # sensitivity re-scoring shards rows over the data mesh — the MR
-    # VarSelectMapper's split (VarSelectMapper.java:54); the vmapped
-    # column ablation rides on top of the row sharding
-    from shifu_tpu.parallel import mesh as mesh_mod
-    mesh = mesh_mod.default_mesh()
-    n_real = x.shape[0]
-    jx = mesh_mod.shard_axis(mesh, x, 0)
-    base = nn_mod.forward(res.spec, params, jx)
-    deltas = np.asarray(_sensitivity_kernel(res.spec, params, jx, base,
-                                            n_real))
+    sel = sensitivity_select(mc.train, result.dense.astype(np.float32),
+                             dset.tags, dset.weights, seed, vs.filterNum, by)
 
     # map dense output columns back to source columns (onehot/index
     # families expand; sum deltas per source column)
+    names = {c.columnName for c in cols}
     per_col: Dict[str, float] = {}
-    for name, d in zip(result.dense_names, deltas):
-        src = name.rsplit("_", 1)[0] if name not in {c.columnName for c in cols} \
-            else name
+    for name, d in zip(result.dense_names, sel.deltas):
+        src = name if name in names else name.rsplit("_", 1)[0]
         per_col[src] = per_col.get(src, 0.0) + float(d)
-
-    if by == "ST":
-        var = float(np.var(np.asarray(base)[:n_real])) or 1.0
-        per_col = {k: v / var for k, v in per_col.items()}
 
     from shifu_tpu.resilience import atomic_write
     se_path = ctx.path_finder.se_path(0)

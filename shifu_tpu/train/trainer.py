@@ -63,15 +63,18 @@ class TrainResult:
 def split_validation(n: int, valid_rate: float, seed: int,
                      cross_over: bool = False) -> Tuple[np.ndarray, np.ndarray]:
     """Random train/valid split (`AbstractNNWorker.init` validation
-    sampling). Returns boolean masks (train, valid)."""
-    rng = np.random.default_rng(seed)
-    is_val = rng.random(n) < valid_rate
+    sampling, a row held out where its draw falls below the rate): the
+    round(n * valid_rate) rows of smallest draw from the seed's numpy
+    generator, at least one row held out and, past one row, one kept.
+    The counts, and so every shape a job compiles from them, depend on
+    n and the rate alone, never on the seed. Returns boolean masks
+    (train, valid)."""
     if valid_rate <= 0.0:
         return np.ones(n, bool), np.zeros(n, bool)
-    if is_val.all():
-        is_val[0] = False
-    if not is_val.any():
-        is_val[-1] = True
+    n_val = max(min(int(round(n * valid_rate)), n - 1), 1)
+    draw = np.random.default_rng(seed).random(n)
+    is_val = np.zeros(n, bool)
+    is_val[np.argpartition(draw, n_val - 1)[:n_val]] = True
     return ~is_val, is_val
 
 

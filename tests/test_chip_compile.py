@@ -434,6 +434,63 @@ def test_mlp_kernel_compiles(one_chip, hidden, acts, loss, out_act, bags):
         ((32, rp), F32), (chunks, F32), ((bags,) + chunks, F32), *shapes)
 
 
+def test_mlp_kernel_compiles_at_its_widest_input_and_not_past_it(one_chip):
+    """`pallas_mlp.MAX_INPUT`: a grid step reads a (F8, ROW_TILE) block
+    of the inputs, double-buffered, F8 x 128 KiB of VMEM. At MAX_INPUT
+    inputs the kernel compiles; at the next sublane tile the compiler
+    refuses it for VMEM, which is why `serves` stops there."""
+    from shifu_tpu.models import nn as nn_mod
+    from shifu_tpu.ops import pallas_mlp
+    rp = -(-400_000 // pallas_mlp.ROW_TILE) * pallas_mlp.ROW_TILE
+    chunks = (rp // pallas_mlp.CHUNK, pallas_mlp.CHUNK)
+    for inputs, fits in ((pallas_mlp.MAX_INPUT, True),
+                         (pallas_mlp.MAX_INPUT + 8, False)):
+        spec = nn_mod.MLPSpec(inputs, (50,), ("tanh",))
+        params = jax.eval_shape(
+            lambda: nn_mod.init_params(spec, jax.random.PRNGKey(0)))
+        flat = jax.tree.structure(params)
+
+        def step(xT, y, w, *leaves):
+            return jax.value_and_grad(lambda q: pallas_mlp.loss(
+                spec, q, xT, y, w))(jax.tree.unflatten(flat, leaves))
+
+        shapes = [((-(-inputs // 8) * 8, rp), F32), (chunks, F32),
+                  (chunks, F32)] + [(a.shape, a.dtype)
+                                    for a in jax.tree.leaves(params)]
+        if fits:
+            _compile(step, one_chip, *shapes)
+        else:
+            with pytest.raises(Exception, match="(?i)vmem"):
+                _compile(step, one_chip, *shapes)
+
+
+def test_se_epsilon_ablation_compiles_inside_its_bytes(one_chip,
+                                                     monkeypatch):
+    """`processor/sensitivity.shifu_ablate` at the benchmark's
+    `se-epsilon`: 400,000 rows x 2,000 columns under a 50-unit tanh
+    layer on a one-chip TPU. The first layer's sums read x in place (no
+    operand copy of it, which would be 1.6 GB), and the temporaries are
+    at most the sums (80 MB) and a block's work: nothing of (columns,
+    rows, ...), which would be 3.2 GB a hidden unit. Every op carries
+    the scope `ablate`."""
+    from shifu_tpu.models import nn as nn_mod
+    from shifu_tpu.processor import sensitivity
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, cols = 400_000, 2_000
+    spec = nn_mod.MLPSpec(cols, (50,), ("tanh",))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: nn_mod.init_params(spec,
+                                                  jax.random.PRNGKey(0))))
+    x = jax.ShapeDtypeStruct((rows, cols), F32, sharding=one_chip)
+    compiled = sensitivity.shifu_ablate.lower(
+        spec, params, x, rows,
+        sensitivity.ablate_blocks(rows, cols, 50)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        4 * 50 * rows + 2 * sensitivity.BLOCK_BYTES)
+    assert "ablate" in compiled.as_text()
+
+
 def test_nn_higgs_epoch_program_holds_the_kernel_and_no_activation(
         one_chip, monkeypatch):
     """The benchmark's `nn-higgs.train` job as `train_nn` builds it on a

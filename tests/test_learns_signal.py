@@ -91,7 +91,7 @@ def lr_sensitivity(rng, tmp_path):
     ablation kernel, in blocks with an uneven trailing one (50k rows in
     blocks of 20k): the planted importances (beta_c ∝ c + 1) come back
     in rank order."""
-    from shifu_tpu.processor.varselect import _sensitivity_kernel
+    from shifu_tpu.processor import sensitivity
     n, cols, block = 50_000, 8, 20_000
     beta = (np.arange(cols, dtype=np.float32) + 1.0) / cols
     x, y, w = synth.planted_linear_table(rng, n, beta)
@@ -101,9 +101,11 @@ def lr_sensitivity(rng, tmp_path):
     total = jnp.zeros(cols, jnp.float32)
     for s in range(0, n, block):
         xb = jnp.asarray(x[s:s + block])
-        total = total + _sensitivity_kernel(
-            res.spec, params, xb, nn_mod.forward(res.spec, params, xb),
-            n_real=n)
+        rows = xb.shape[0]
+        deltas, _ = sensitivity.shifu_ablate(
+            res.spec, params, xb, rows,
+            sensitivity.ablate_blocks(rows, cols, 1))
+        total = total + deltas * (rows / n)
     ranks = np.empty(cols, np.int64)
     ranks[np.argsort(np.asarray(total))] = np.arange(cols)
     assert np.corrcoef(ranks, np.arange(cols))[0, 1] > 0.9

@@ -234,3 +234,18 @@ def test_who_takes_the_kernel(monkeypatch, small_tiles, route):
         one, tuple(inputs), w_bags[0], jax.random.PRNGKey(0))
     names = [e.params["name"] for e in _pallas_eqns(jaxpr.jaxpr, [])]
     assert names == (["shifu_mlp_loss_grad"] if takes else [])
+
+
+@pytest.mark.parametrize("inputs,takes", [
+    (28, True), (pallas_mlp.MAX_INPUT, True),
+    (pallas_mlp.MAX_INPUT + 1, False), (2_000, False)])
+def test_the_kernel_takes_inputs_up_to_its_widest(monkeypatch, inputs, takes):
+    """`serves` bounds the input width: past MAX_INPUT a grid step's
+    row tile of the inputs does not fit the kernel's VMEM (the v5e's
+    compiler refuses it, `tests/test_chip_compile.py`), and the job's
+    epoch is XLA's, as an SE job's quick train at 2,000 inputs is."""
+    monkeypatch.setenv("SHIFU_TPU_MESH_DEVICES", "1")
+    monkeypatch.setattr(pallas_mlp, "on_chip", lambda: True)
+    spec = nn_mod.MLPSpec(inputs, (50,), ("tanh",))
+    assert pallas_mlp.serves(spec) == takes
+    assert trainer.mlp_kernel_serves(spec, True) == takes
