@@ -230,6 +230,29 @@ def psum_bytes(cfg: "TreeConfig", n_cols: int, mesh, subtract: bool,
     return 2 * 4 * cells * cfg.n_bins * n_trees
 
 
+def hist_levels(cfg: "TreeConfig", n_cols: int, groups, subtract: bool,
+                rounds: int = 1) -> dict:
+    """The histogram passes of a lockstep job, from shapes alone, for its
+    `train.job` span: `hist_tree_levels`, the tree-levels whose
+    histograms it builds (every level of every tree of every group,
+    the leaf level's on its `_LEAF_COLUMNS` with them, `rounds` times),
+    and `hist_shared_levels`, those built in a kernel pass shared with
+    another tree of the group (`pallas_hist.trees_per_pass`; none on
+    the XLA scatter route). `groups` holds the groups' tree counts."""
+    from shifu_tpu.ops.pallas_hist import trees_per_pass
+    shared = 0
+    for d in range(cfg.max_depth + 1 if _hist_mode() == "pallas" else 0):
+        cols = n_cols if d < cfg.max_depth else min(n_cols, _LEAF_COLUMNS)
+        for t in groups:
+            tp = trees_per_pass(t, _kernel_slots(d, subtract), cols,
+                                cfg.n_bins)
+            # t // tp full passes, then one of the t % tp trees left
+            shared += (t - t % tp if tp > 1 else 0) + (
+                t % tp if t % tp > 1 else 0)
+    return {"hist_tree_levels": rounds * sum(groups) * (cfg.max_depth + 1),
+            "hist_shared_levels": rounds * shared}
+
+
 def _local_level_histograms(binsT, slot, grad, hess, n_level_nodes, n_bins):
     """Single-shard histogram kernel (slot already computed, incl. the
     trailing dump slot for inactive rows). binsT is TRANSPOSED (C, R) —
@@ -310,6 +333,27 @@ def _level_histograms(binsT, node_of_row, grad, hess, level_offset,
                                    n_bins)
 
 
+def _local_forest_histograms(binsT, slot_T, grad_T, hess_T, n_level_nodes,
+                             n_bins):
+    """Single-shard pass of a lockstep group: (T, R) slot/grad/hess →
+    (T, n_level_nodes, C, n_bins) G and H. On the Pallas route the
+    kernel takes the group's row state whole (`pallas_hist`'s grouped
+    form: the trees of a pass share each row tile's bin one-hot, one
+    `pallas_call` a level); the XLA scatter maps over the trees."""
+    if _hist_mode() == "pallas":
+        from shifu_tpu.ops import pallas_hist
+        interpret = jax.default_backend() != "tpu"
+        if isinstance(binsT, FusedBins):
+            return pallas_hist.level_histograms_fused(
+                binsT.valuesT, binsT.cuts, slot_T, grad_T, hess_T,
+                n_level_nodes, n_bins, interpret=interpret)
+        return pallas_hist.level_histograms_pallas(
+            binsT, slot_T, grad_T, hess_T, n_level_nodes, n_bins,
+            interpret=interpret)
+    return jax.vmap(lambda s, g, h: _local_level_histograms(
+        binsT, s, g, h, n_level_nodes, n_bins))(slot_T, grad_T, hess_T)
+
+
 @jax.named_scope("hist")
 def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
                              n_level_nodes, n_bins, mesh=None):
@@ -320,7 +364,7 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
 
     Same explicit shard_map + psum structure as _level_histograms —
     rows shard over 'data', each device builds local histograms for
-    ALL trees (vmap over the tree axis), one psum reduces G and H of
+    ALL trees (`_local_forest_histograms`), one psum reduces G and H of
     all of them, stacked. RF used to
     rely on GSPMD partitioning a vmapped scatter here; that both risks
     a silent all-gather of the row-sharded bins AND compiles
@@ -331,10 +375,6 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
     valid = (local >= 0) & (local < n_level_nodes)
     slot_T = _by_row(mesh, jnp.where(valid, local, n_level_nodes))
 
-    def local_hists(b, s, g, h):
-        return jax.vmap(lambda s_, g_, h_: _local_level_histograms(
-            b, s_, g_, h_, n_level_nodes, n_bins))(s, g, h)
-
     if _data_size(mesh) > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -343,13 +383,15 @@ def _forest_level_histograms(binsT, node_T, grad_T, hess_T, level_offset,
                            P(None, "data"), P(None, "data")),
                  out_specs=(P(), P()), check_vma=False)
         def sharded(b, s, g, h):
-            local = jnp.stack(local_hists(b, s, g, h))
+            local = jnp.stack(_local_forest_histograms(
+                b, s, g, h, n_level_nodes, n_bins))
             with jax.named_scope("allreduce"):
                 return tuple(jax.lax.psum(local, "data"))
 
         return sharded(binsT, slot_T, grad_T, hess_T)
 
-    return local_hists(binsT, slot_T, grad_T, hess_T)
+    return _local_forest_histograms(binsT, slot_T, grad_T, hess_T,
+                                    n_level_nodes, n_bins)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "subtract",
@@ -1342,12 +1384,14 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                             val_rows=-(-n_val // chips))
     starts = range(0, n_bags, group)
     by_row = _row_sharding(hist_mesh, 2) if chips > 1 else None
+    sizes = [min(group, n_bags - s) for s in starts]
     with obs_trace.span("train.job", family="gbt", rows=r, steps=n_trees,
                         bags=n_bags, chips=chips, group_trees=group,
                         groups=len(starts), val_rows=n_val,
                         bag_rate=float(bagging_rate),
                         psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract,
-                                              group)):
+                                              group),
+                        **hist_levels(cfg, c, sizes, subtract, n_trees)):
         with obs_trace.span("train.prepare"):
             fm = np.asarray(feature_mask if feature_mask is not None
                             else np.ones(c, np.float32), np.float32)
@@ -1632,12 +1676,14 @@ def build_rf(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                             next(iter(mesh.devices.flat)))
     starts = range(0, n_trees, group)
     by_row = _row_sharding(hist_mesh, 2) if chips > 1 else None
+    sizes = [min(group, n_trees - s) for s in starts]
     with obs_trace.span("train.job", family="rf", rows=r, steps=n_trees,
                         bags=1, chips=chips, trees=n_trees,
                         group_trees=group, groups=len(starts),
                         subset_cols=k, bag_rate=float(bagging_rate),
                         psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract,
-                                              group)):
+                                              group),
+                        **hist_levels(cfg, c, sizes, subtract)):
         with obs_trace.span("train.prepare"):
             key = jax.random.key(int(seed))
             edges = rf_draw.poisson_thresholds(bagging_rate)

@@ -54,6 +54,19 @@ kernel itself reads S8 and not S, and the jitted entry is keyed on S8:
 the levels of 1, 1, 2, 4 and 8 slots are one traced and lowered
 program.
 
+A lockstep group (`gbdt._forest_level_histograms`: the trees of a
+random forest, or the bags of a bagged GBT, over one bin matrix) hands
+its (T, R) slot, grad and hess to the same entries. The bin one-hot
+depends on the bins alone, so `trees_per_pass` trees share it: their
+(2·S8, TR) node one-hots stack into one (2·Tp·S8, TR) operand, one MXU
+contraction a row tile for all of them, where `vmap` over the (R,) call
+built the one-hot again for every tree. Tp is the largest whose stacked
+operand is at most one pass of the array's 128 rows and whose tiles are
+one tree's (another row tile would change the order of the sums), so a
+tree's histograms are its own call's bit for bit. The passes are an
+outer grid axis of the one call: a level of a group is one kernel
+event, as under `vmap`.
+
 `interpret=True` runs the same kernel on CPU for tests (conftest's
 8-device CPU mesh), keeping kernel parity checkable without a chip.
 """
@@ -62,6 +75,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +85,7 @@ from jax.experimental.pallas import tpu as pltpu
 from shifu_tpu.config.environment import knob_int, knob_str
 
 __all__ = ["level_histograms_pallas", "level_histograms_fused",
-           "bins_from_values"]
+           "bins_from_values", "trees_per_pass"]
 
 
 def _slots8(n_slots: int) -> int:
@@ -86,21 +100,33 @@ def _hist_body(bins_of, n_cols: int, slot_ref, grad_ref, hess_ref, out_ref,
     slot, grad and hess blocks' T row tiles in ascending order, the
     (TC, TR) int32 bins tile `bins_of(lanes)` gives for that tile's lane
     slice → accumulate the (2·S8, B·TC) output block, G in sublanes
-    [0, S), H in [S8, S8 + S). `n_cols` is TC."""
-    # grid = (col_tiles, row_steps): the ROW (reduction) dimension is
-    # innermost, so each output block's revisits are consecutive grid
-    # steps — required for the += accumulation pattern on TPU (the
-    # output VMEM buffer is flushed between non-consecutive revisits)
-    i = pl.program_id(1)
-    tiles, tr = slot_ref.shape
-    s8 = out_ref.shape[0] // 2
+    [0, S), H in [S8, S8 + S). `n_cols` is TC.
+
+    Grouped form: (Tp, T, TR) blocks of slot, grad and hess hold the
+    T row tiles of Tp trees, and the output block is Tp such (2·S8, B·TC)
+    blocks stacked, tree t's at sublanes [2·t·S8, 2·(t+1)·S8). The bin
+    one-hot, which depends on the bins alone, is built once a row tile
+    for all of them."""
+    # grid = ([passes,] col_tiles, row_steps): the ROW (reduction)
+    # dimension is innermost, so each output block's revisits are
+    # consecutive grid steps — required for the += accumulation pattern
+    # on TPU (the output VMEM buffer is flushed between non-consecutive
+    # revisits)
+    grouped = slot_ref.ndim == 3
+    i = pl.program_id(2 if grouped else 1)
+    n_trees = slot_ref.shape[0] if grouped else 1
+    tiles, tr = slot_ref.shape[-2:]
+    s8 = out_ref.shape[0] // (2 * n_trees)
     # what no tile changes is built once a step (and traced once): the
-    # slot a sublane of the stacked operand stands for, and the sublane
-    # index of the bin one-hot
+    # slot a sublane of a tree's stacked operand stands for, and the
+    # sublane index of the bin one-hot
     sub = jax.lax.broadcasted_iota(jnp.int32, (2 * s8, tr), 0)
     is_h = sub >= s8
     sub_slot = jnp.where(is_h, sub - s8, sub)
     lane = jax.lax.broadcasted_iota(jnp.int32, (n_cols * n_bins, tr), 0)
+
+    def row(ref, t, k):
+        return ref[t, k:k + 1, :] if grouped else ref[k:k + 1, :]
 
     # a Python loop: the tiles lie in ONE basic block, where the
     # scheduler builds tile k+1's one-hot on the vector unit while tile
@@ -109,9 +135,8 @@ def _hist_body(bins_of, n_cols: int, slot_ref, grad_ref, hess_ref, out_ref,
     # these eight short bodies (PERF.md, PR 36)
     for k in range(tiles):
         binsT = bins_of(slice(k * tr, (k + 1) * tr))
-        slot = slot_ref[k:k + 1, :]                 # (1, TR)
-        grad = grad_ref[k:k + 1, :]
-        hess = hess_ref[k:k + 1, :]
+        rows = [(row(slot_ref, t, k), row(grad_ref, t, k),
+                 row(hess_ref, t, k)) for t in range(n_trees)]  # (1, TR)
 
         # bin one-hot, transposed + bin-major (sublane l = b·TC + c):
         # tpu.repeat stacks B copies of the (TC, TR) tile along sublanes
@@ -124,15 +149,18 @@ def _hist_body(bins_of, n_cols: int, slot_ref, grad_ref, hess_ref, out_ref,
                                       ).astype(jnp.float32)  # (B·TC, TR)
 
         # node one-hot weighted by grad in the first S8 sublanes and by
-        # hess in the next S8: ONE (2·S8, TR) operand, so the bin one-hot
-        # streams through the MXU once for both (at S ≤ 64 the two
-        # together fill no more of the 128-wide array than either did).
-        # A row's dump slot (S; rows not in this level) matches at most a
-        # pad sublane, which the caller drops.
-        ghw = (slot == sub_slot).astype(jnp.float32) * jnp.where(
-            is_h, hess, grad)                                 # (2·S8, TR)
+        # hess in the next S8: ONE (2·S8, TR) operand a tree, so the bin
+        # one-hot streams through the MXU once for both (at S ≤ 64 the
+        # two together fill no more of the 128-wide array than either
+        # did), and the trees of a pass stacked on it. A row's dump slot
+        # (S; rows not in this level) matches at most a pad sublane,
+        # which the caller drops.
+        ghw = [(slot == sub_slot).astype(jnp.float32)
+               * jnp.where(is_h, hess, grad)
+               for slot, grad, hess in rows]             # (2·S8, TR) each
+        ghw = ghw[0] if n_trees == 1 else jnp.concatenate(ghw, axis=0)
 
-        # MXU NT contraction over rows: (2·S8, TR) · (B·TC, TR)ᵀ
+        # MXU NT contraction over rows: (2·Tp·S8, TR) · (B·TC, TR)ᵀ
         part = jax.lax.dot_general(
             ghw, onehot_bins, (((1,), (1,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
@@ -250,22 +278,51 @@ def derive_tiles(n_cols: int, n_slots: int, n_bins: int,
     return row_tile, col_tile
 
 
-def _row_tiles(slot, grad, hess, row_tile: int):
+# rows of the MXU's array (v5e: 128 x 128): the stacked operand of a
+# grouped pass is held to one pass of it
+_MXU_ROWS = 128
+
+
+def trees_per_pass(n_trees: int, n_slots: int, n_cols: int, n_bins: int,
+                   highest: Optional[bool] = None) -> int:
+    """Trees of a lockstep group whose histograms one grouped pass
+    builds (Tp), from shapes alone: the largest Tp <= n_trees whose
+    stacked (2·Tp·S8, TR) operand is at most one pass of the MXU's rows
+    (4 trees up to 16 slots, 2 at 32, 1 from 64 up) and whose tiles
+    (`derive_tiles` at Tp·S8 slots) are a single tree's. Another row
+    tile would change the order of the float32 sums, and with it every
+    tree's bits; above 64 slots a pass is bound by the MXU's rows, and
+    one tree a pass gives up nothing. `highest` None reads
+    SHIFU_TPU_HIST_PRECISION."""
+    if highest is None:
+        highest = _highest()
+    s8 = _slots8(n_slots)
+    tiles = derive_tiles(n_cols, s8, n_bins, highest)
+    tp = max(1, min(n_trees, _MXU_ROWS // (2 * s8)))
+    while tp > 1 and derive_tiles(n_cols, tp * s8, n_bins, highest) != tiles:
+        tp -= 1
+    return tp
+
+
+def _row_tiles(slot, grad, hess, row_tile: int, trees_per_pass: int = 1):
     """The (R,) per-row vectors as (tiles, row_tile) views, one row tile
     a sublane, and the row tiles a grid step covers: eight, or every
     tile where there are fewer. Rows that fill no whole step are padded
     with slot -1, which like every slot outside [0, S8) matches no
-    sublane of the node one-hot."""
-    r = slot.shape[0]
+    sublane of the node one-hot. A group's (T, R) row state views as
+    (T, tiles, row_tile), its trees padded the same way to a whole
+    number of passes of `trees_per_pass`."""
+    r = slot.shape[-1]
     tiles = -(-r // row_tile)
     step = min(8, tiles)
     pad = -(-tiles // step) * step * row_tile - r
+    pads = [(0, -n % trees_per_pass) for n in slot.shape[:-1]] + [(0, pad)]
 
     def view(v, dtype, fill):
         v = v.astype(dtype)
-        if pad:
-            v = jnp.pad(v, (0, pad), constant_values=fill)
-        return v.reshape(-1, row_tile)
+        if any(p for _, p in pads):
+            v = jnp.pad(v, pads, constant_values=fill)
+        return v.reshape(v.shape[:-1] + (-1, row_tile))
 
     return (view(slot, jnp.int32, -1), view(grad, jnp.float32, 0),
             view(hess, jnp.float32, 0)), step
@@ -273,7 +330,7 @@ def _row_tiles(slot, grad, hess, row_tile: int):
 
 def _hist_call(kern, matrix, cuts, slot, grad, hess, slots8: int,
                n_bins: int, row_tile: int, col_tile: int, interpret: bool,
-               name: str):
+               name: str, trees_per_pass: int = 1):
     """The pallas_call both kernels share. `matrix` is the (C, R) bins
     or raw values, padded to whole column and row tiles, `cuts` the
     fused kernel's (C, K) cut table or None; slot, grad and hess go in
@@ -281,58 +338,89 @@ def _hist_call(kern, matrix, cuts, slot, grad, hess, slots8: int,
     each (rows past the matrix's last whole step are read as whatever
     the block holds there and weigh nothing: their slot is the pad's
     -1); one (2·S8, col_tile·n_bins) output block a column tile,
-    revisited along the row (reduction) axis."""
-    views, step = _row_tiles(slot, grad, hess, row_tile)
+    revisited along the row (reduction) axis.
+
+    A group's (T, R) row state adds an outer grid axis of passes, each
+    over `trees_per_pass` trees' (Tp, step, TR) blocks into one
+    (2·Tp·S8, col_tile·n_bins) output block: still ONE `pallas_call`,
+    one device event a level of a group."""
+    views, step = _row_tiles(slot, grad, hess, row_tile, trees_per_pass)
     cp, rp = matrix.shape
-    # (col_tiles, row_steps) — rows innermost; see _hist_body
+    # ([passes,] col_tiles, row_steps) — rows innermost; see _hist_body
     grid = (cp // col_tile, -(-rp // (step * row_tile)))
+    block = (2 * slots8 * trees_per_pass, col_tile * n_bins)
+    if slot.ndim == 2:
+        passes = views[0].shape[0] // trees_per_pass
+        grid = (passes,) + grid
+        rows_block = (trees_per_pass, step, row_tile)
+        maps = (lambda p, j, i: (j, i), lambda p, j, i: (j, 0),
+                lambda p, j, i: (p, i, 0), lambda p, j, i: (p, j))
+    else:
+        passes = 1
+        rows_block = (step, row_tile)
+        maps = (lambda j, i: (j, i), lambda j, i: (j, 0),
+                lambda j, i: (i, 0), lambda j, i: (0, j))
+    mat_map, cut_map, row_map, out_map = maps
     operands = [matrix]
-    in_specs = [pl.BlockSpec((col_tile, step * row_tile),
-                             lambda j, i: (j, i))]
+    in_specs = [pl.BlockSpec((col_tile, step * row_tile), mat_map)]
     if cuts is not None:
         operands.append(cuts)
-        in_specs.append(pl.BlockSpec((col_tile, cuts.shape[1]),
-                                     lambda j, i: (j, 0)))
-    in_specs += [pl.BlockSpec((step, row_tile), lambda j, i: (i, 0))] * 3
-    lanes = col_tile * n_bins
+        in_specs.append(pl.BlockSpec((col_tile, cuts.shape[1]), cut_map))
+    in_specs += [pl.BlockSpec(rows_block, row_map)] * 3
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((2 * slots8, lanes), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((2 * slots8, grid[0] * lanes),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec(block, out_map),
+        out_shape=jax.ShapeDtypeStruct(
+            (passes * block[0], grid[-2] * block[1]), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",),
             vmem_limit_bytes=_vmem_budget()),
         interpret=interpret,
         name=name,
     )(*operands, *views)
 
 
+def _highest() -> bool:
+    """SHIFU_TPU_HIST_PRECISION=highest: the f32-exact contraction."""
+    return (knob_str("SHIFU_TPU_HIST_PRECISION", "") or
+            "").lower() == "highest"
+
+
 def _tiles(c: int, r: int, n_slots: int, n_bins: int, row_tile: int,
-           col_tile: int):
-    """(highest, row_tile, col_tile) of a call: SHIFU_TPU_HIST_PRECISION,
-    the tiles pinned by the caller or derived from the VMEM budget, held
-    to the matrix's own extent."""
-    highest = (knob_str("SHIFU_TPU_HIST_PRECISION", "") or
-               "").lower() == "highest"
+           col_tile: int, slot):
+    """(highest, row_tile, col_tile, trees, trees_per_pass) of a call:
+    SHIFU_TPU_HIST_PRECISION, the tiles pinned by the caller or derived
+    from the VMEM budget, held to the matrix's own extent; for a group's
+    (T, R) `slot` its T and `trees_per_pass`, for one tree's (R,) 0 and
+    1."""
+    highest = _highest()
     d_row, d_col = derive_tiles(c, n_slots, n_bins, highest)
+    trees = slot.shape[0] if slot.ndim == 2 else 0
+    tp = trees_per_pass(trees, n_slots, c, n_bins, highest) if trees else 1
     return (highest, min(row_tile or d_row, max(8, r)),
-            min(col_tile or d_col, max(1, c)))
+            min(col_tile or d_col, max(1, c)), trees, tp)
 
 
-def _split_gh(out, n_slots: int, n_bins: int, col_tile: int, c: int):
+def _split_gh(out, n_slots: int, n_bins: int, col_tile: int, c: int,
+              n_trees: int = 0):
     """The kernel's (2·S8, [tile j][bin b][col c]) output → the G and
-    the H histogram, each (S, C, B); cheap XLA slices and transposes on
-    the small output."""
+    the H histogram, each (S, C, B); a group's output, tree t's
+    (2·S8, ...) block the t-th, → each (T, S, C, B). Cheap XLA slices
+    and transposes on the small output."""
     def reassemble(a):
-        a = a.reshape(n_slots, -1, n_bins, col_tile)
-        a = a.transpose(0, 1, 3, 2).reshape(n_slots, -1, n_bins)
-        return a[:, :c, :]
+        lead = a.shape[:-2]
+        a = a.reshape(lead + (n_slots, -1, n_bins, col_tile))
+        a = jnp.swapaxes(a, -1, -2).reshape(lead + (n_slots, -1, n_bins))
+        return a[..., :c, :]
 
     s8 = _slots8(n_slots)
-    return reassemble(out[:n_slots]), reassemble(out[s8:s8 + n_slots])
+    if n_trees:
+        out = out.reshape(-1, 2 * s8, out.shape[-1])[:n_trees]
+    return (reassemble(out[..., :n_slots, :]),
+            reassemble(out[..., s8:s8 + n_slots, :]))
 
 
 def level_histograms_pallas(binsT: jax.Array, slot: jax.Array,
@@ -346,6 +434,11 @@ def level_histograms_pallas(binsT: jax.Array, slot: jax.Array,
     padding). Tile sizes derive from the VMEM budget by default
     (`derive_tiles`); pass row_tile/col_tile > 0 to pin them.
 
+    A lockstep group's (T, R) slot/grad/hess → two
+    (T, n_slots, C, n_bins) histograms, each tree's bit for bit what its
+    own (R,) call gives: `trees_per_pass` trees a pass share every row
+    tile's bin one-hot, and the passes are a grid axis of the one call.
+
     Precision: the MXU multiplies in bf16 by default — the one-hot
     side is exact, so only grad/hess values truncate (~0.3% relative
     per element, statistically inert for split gains; measured on
@@ -354,21 +447,23 @@ def level_histograms_pallas(binsT: jax.Array, slot: jax.Array,
     multi-pass algorithm, which starts from the smallest aligned row
     tile (128 lanes) to leave VMEM for its extra passes."""
     c, r = binsT.shape
-    highest, row_tile, col_tile = _tiles(c, r, n_slots, n_bins, row_tile,
-                                         col_tile)
+    highest, row_tile, col_tile, trees, tp = _tiles(
+        c, r, n_slots, n_bins, row_tile, col_tile, slot)
     out = _level_histograms_pallas(binsT, slot, grad, hess,
                                    _slots8(n_slots), n_bins, row_tile,
-                                   col_tile, interpret, highest)
-    return _split_gh(out, n_slots, n_bins, col_tile, c)
+                                   col_tile, interpret, highest, tp)
+    return _split_gh(out, n_slots, n_bins, col_tile, c, trees)
 
 
 @functools.partial(jax.jit, static_argnames=("slots8", "n_bins",
                                              "row_tile", "col_tile",
-                                             "interpret", "highest"))
+                                             "interpret", "highest",
+                                             "trees_per_pass"))
 def _level_histograms_pallas(binsT, slot, grad, hess,
                              slots8: int, n_bins: int,
                              row_tile: int, col_tile: int,
-                             interpret: bool, highest: bool):
+                             interpret: bool, highest: bool,
+                             trees_per_pass: int):
     c, r = binsT.shape
     pad_r = (-r) % row_tile
     pad_c = (-c) % col_tile
@@ -378,7 +473,7 @@ def _level_histograms_pallas(binsT, slot, grad, hess,
                              precision=_precision(highest))
     return _hist_call(kern, binsT.astype(jnp.int32), None, slot, grad, hess,
                       slots8, n_bins, row_tile, col_tile, interpret,
-                      "shifu_level_histograms")
+                      "shifu_level_histograms", trees_per_pass)
 
 
 def level_histograms_fused(valuesT: jax.Array, cutsT: jax.Array,
@@ -392,23 +487,26 @@ def level_histograms_fused(valuesT: jax.Array, cutsT: jax.Array,
     identity boundaries over host-mapped codes — gbdt.make_fused_inputs
     packs both), and performs the bin lookup inside the kernel so the
     (C, R) int32 bin matrix never exists in HBM. Same tiling, output
-    layout, and precision contract as the int-bins kernel."""
+    layout, grouped form and precision contract as the int-bins
+    kernel."""
     c, r = valuesT.shape
-    highest, row_tile, col_tile = _tiles(c, r, n_slots, n_bins, row_tile,
-                                         col_tile)
+    highest, row_tile, col_tile, trees, tp = _tiles(
+        c, r, n_slots, n_bins, row_tile, col_tile, slot)
     out = _level_histograms_fused(valuesT, cutsT, slot, grad, hess,
                                   _slots8(n_slots), n_bins, row_tile,
-                                  col_tile, interpret, highest)
-    return _split_gh(out, n_slots, n_bins, col_tile, c)
+                                  col_tile, interpret, highest, tp)
+    return _split_gh(out, n_slots, n_bins, col_tile, c, trees)
 
 
 @functools.partial(jax.jit, static_argnames=("slots8", "n_bins",
                                              "row_tile", "col_tile",
-                                             "interpret", "highest"))
+                                             "interpret", "highest",
+                                             "trees_per_pass"))
 def _level_histograms_fused(valuesT, cutsT, slot, grad, hess,
                             slots8: int, n_bins: int,
                             row_tile: int, col_tile: int,
-                            interpret: bool, highest: bool):
+                            interpret: bool, highest: bool,
+                            trees_per_pass: int):
     c, r = valuesT.shape
     pad_r = (-r) % row_tile
     pad_c = (-c) % col_tile
@@ -424,4 +522,4 @@ def _level_histograms_fused(valuesT, cutsT, slot, grad, hess,
     return _hist_call(kern, valuesT.astype(jnp.float32),
                       cutsT.astype(jnp.float32), slot, grad, hess, slots8,
                       n_bins, row_tile, col_tile, interpret,
-                      "shifu_level_histograms_fused")
+                      "shifu_level_histograms_fused", trees_per_pass)

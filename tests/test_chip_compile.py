@@ -130,12 +130,13 @@ def test_fused_hist_kernel_compiles(one_chip, c, r, b, s):
 @pytest.mark.parametrize("s,r", [(1, 2 ** 22), (64, 2 ** 22),
                                  (8, 2 ** 22 + 1536)])
 def test_hist_kernel_compiles_under_vmap_over_trees(one_chip, s, r):
-    """The lockstep forest's pass (`gbdt._forest_level_histograms`): a
-    vmap of the kernel over three trees' row state, the bin matrix
-    shared. The `pallas_call` gains a grid axis and the stacked G/H
-    operand is built per tree inside the kernel: Mosaic takes it at the
-    root's one slot, at a full pass of the array's width, and at rows
-    that fill no whole eight-tile grid step."""
+    """The form the lockstep forest's pass took before the grouped one,
+    and `tools/hist_forms.py`'s comparison: a vmap of the kernel over
+    three trees' row state, the bin matrix shared. The `pallas_call`
+    gains a grid axis and the stacked G/H operand is built per tree
+    inside the kernel: Mosaic takes it at the root's one slot, at a full
+    pass of the array's width, and at rows that fill no whole eight-tile
+    grid step."""
     from shifu_tpu.ops import pallas_hist
     c, b, trees = 28, 64, 3
     _compile(jax.vmap(
@@ -143,6 +144,42 @@ def test_hist_kernel_compiles_under_vmap_over_trees(one_chip, s, r):
             bt, sl, g, h, s, b), in_axes=(0, 0, 0, None)), one_chip,
         ((trees, r), I32), ((trees, r), F32), ((trees, r), F32),
         ((c, r), I32))
+
+
+# (trees, slots, rows, kernel): the forest cells' groups at 2^24 rows,
+# four trees a pass up to 16 slots, two at 32 (two passes of a group of
+# four, one of a group of two), one at 64; rows that fill no whole
+# eight-tile grid step; the fused values-and-cuts kernel
+GROUPED_CASES = [(4, 8, 2 ** 24, "bins"), (4, 16, 2 ** 24, "bins"),
+                 (4, 32, 2 ** 24, "bins"), (2, 32, 2 ** 24, "bins"),
+                 (4, 64, 2 ** 24, "bins"), (4, 1, 2 ** 22 + 1536, "bins"),
+                 (4, 8, 2 ** 24, "fused")]
+
+
+@pytest.mark.parametrize("trees,s,r,kernel", GROUPED_CASES)
+def test_grouped_hist_kernel_compiles_at_the_forest_cells_shapes(
+        one_chip, trees, s, r, kernel):
+    """The lockstep forest's pass (`gbdt._forest_level_histograms`): the
+    group's (T, rows) row state handed to the kernel whole, the trees of
+    a pass stacked on one (2·Tp·S8, TR) operand against each row tile's
+    one bin one-hot, the passes a grid axis. Mosaic takes it inside the
+    kernel's `vmem_limit_bytes` at the 28 x 2^24 table's (Tp, S8) of
+    (4, 8), (4, 16) and (2, 32), and a level of a group stays ONE kernel
+    call (`hist_kernel_roofline` counts one event a group-level)."""
+    from shifu_tpu.ops import pallas_hist
+    c, b = 28, 64
+    rows = (((trees, r), I32), ((trees, r), F32), ((trees, r), F32))
+    if kernel == "fused":
+        text = _compile(lambda v, ct, sl, g, h:
+                        pallas_hist.level_histograms_fused(
+                            v, ct, sl, g, h, s, b), one_chip,
+                        ((c, r), F32), ((c, b - 1), F32), *rows)
+    else:
+        text = _compile(lambda bt, sl, g, h:
+                        pallas_hist.level_histograms_pallas(
+                            bt, sl, g, h, s, b), one_chip,
+                        ((c, r), I32), *rows)
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
 
 
 # (columns, rows, max depth, bins, trees): the benchmark's gbt-higgs cell,

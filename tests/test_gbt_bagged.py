@@ -187,6 +187,51 @@ def test_a_small_group_gives_the_one_group_trees_bit_for_bit(rng,
         assert ea == eb
 
 
+def _per_tree_pass(binsT, slot_T, grad_T, hess_T, n_level_nodes, n_bins):
+    """A lockstep level's histograms a bag at a time: each bag's own
+    (R,) kernel call (interpret mode), stacked."""
+    from shifu_tpu.ops.pallas_hist import level_histograms_pallas
+    each = [level_histograms_pallas(binsT, slot_T[t], grad_T[t], hess_T[t],
+                                    n_level_nodes, n_bins, interpret=True)
+            for t in range(slot_T.shape[0])]
+    return tuple(jnp.stack(a) for a in zip(*each))
+
+
+@pytest.mark.parametrize("placement", ["one_device", "mesh"])
+def test_grouped_kernel_gives_the_per_tree_kernels_bags(monkeypatch,
+                                                        placement):
+    """The histogram kernel forced on (interpret mode): five bags whose
+    levels take a group's row state in one grouped pass grow, bit for
+    bit, the trees and validation errors of bags whose levels take each
+    bag's own kernel call; on one device, and on the rig's eight-device
+    data mesh (host rows placed by row, `_forest_level_histograms`'
+    `shard_map` and its one `psum`)."""
+    binsT, y, w, vbT, vy, (bins, y_h, vb, vy_h) = _device_job(
+        np.random.default_rng(41))
+    if placement == "mesh":
+        binsT, y, w = bins, y_h, np.ones_like(y_h)
+        val = (vb, vy_h)
+    else:
+        val = (vbT, vy)
+    monkeypatch.setenv("SHIFU_TPU_HIST", "pallas")
+
+    def job():
+        jax.clear_caches()       # the route is read while a program traces
+        return gbdt.build_gbt_bagged(_cfg("log"), binsT, y, w, 2, 5, 1.0,
+                                     True, SEED, val_data=val)
+
+    try:
+        grouped = job()
+        monkeypatch.setattr(gbdt, "_local_forest_histograms", _per_tree_pass)
+        per_tree = job()
+    finally:
+        jax.clear_caches()
+    for (a, ea), (b, eb) in zip(grouped, per_tree):
+        assert _same(a, b)
+        assert ea == eb
+    assert not _same(grouped[0][0], grouped[1][0])
+
+
 def test_group_size_is_read_from_bytes():
     """On a 16 GB chip five bags of 2^24 rows of 28 columns with 2^22
     validation rows take 4 bags at a time (the five would pad to a tile
@@ -242,12 +287,21 @@ def test_device_validation_rows_give_the_host_rows_errors(rng, builder):
     np.testing.assert_allclose(on_device, on_host, rtol=1e-6)
 
 
-def test_the_job_span_and_its_phases(rng, monkeypatch, tmp_path):
+@pytest.mark.parametrize("route,shared", [("xla", 0), ("pallas", 48)])
+def test_the_job_span_and_its_phases(rng, monkeypatch, request, tmp_path,
+                                     route, shared):
     """`shifu:train.job` says how the bags were grown (bags, groups,
-    group size, validation rows); a group's draw is one `train.bag`, a
-    round one `train.validate` after its groups' programs."""
+    group size, validation rows) and how many of its tree-levels came
+    from a histogram pass shared with another bag: 3 rounds of groups of
+    2, 2 and 1 at depth 3, where on the kernel's route each group of two
+    takes both bags a pass at all four levels and on the scatter's none
+    does; a group's draw is one `train.bag`, a round one
+    `train.validate` after its groups' programs."""
     from shifu_tpu.obs import trace as obs_trace
     from tests.test_train_spans import _profiled_spans
+    monkeypatch.setenv("SHIFU_TPU_HIST", route)
+    jax.clear_caches()           # the route is read while a program traces
+    request.addfinalizer(jax.clear_caches)
     assert obs_trace.span_registered("train.validate")
     binsT, y, w, vbT, vy, _ = _device_job(rng)
     monkeypatch.setattr(gbdt, "_rf_group_trees", lambda *a, **k: 2)
@@ -261,6 +315,8 @@ def test_the_job_span_and_its_phases(rng, monkeypatch, tmp_path):
     assert (int(stats["bags"]), int(stats["groups"]),
             int(stats["group_trees"]), int(stats["val_rows"]),
             int(stats["steps"])) == (5, 3, 2, 400, 3)
+    assert (int(stats["hist_tree_levels"]),
+            int(stats["hist_shared_levels"])) == (3 * 5 * 4, shared)
     bags = [e for e in evs if e[0] == "shifu:train.bag"]
     assert [int(e[3]["trees"]) for e in bags] == [2, 2, 1]
     validate = sorted(e for e in evs if e[0] == "shifu:train.validate")

@@ -215,8 +215,19 @@ def _two_contractions(binsT, slot, grad, hess, n_slots, n_bins, row_tile,
     in ascending row order. The node one-hot has the kernel's sublane
     padding (slots up to a multiple of 8), so a one-slot level takes
     the CPU backend's matrix product as the kernel does and not its
-    matrix-vector routine, which sums in another order."""
+    matrix-vector routine, which sums in another order. For the same
+    reason the rows are padded as the kernel pads them, to whole row
+    tiles of the tile it takes, with slot -1 and no weight: a last tile
+    of fewer rows is a narrower product, which the CPU backend also sums
+    in another order."""
     c, r = binsT.shape
+    row_tile = min(row_tile, max(8, r))
+    pad = -r % row_tile
+    if pad:
+        binsT = jnp.pad(binsT, ((0, 0), (0, pad)))
+        slot = jnp.pad(slot, (0, pad), constant_values=-1)
+        grad, hess = (jnp.pad(v, (0, pad)) for v in (grad, hess))
+        r += pad
     s8 = -(-n_slots // 8) * 8
     g = jnp.zeros((s8, c, n_bins), jnp.float32)
     h = jnp.zeros((s8, c, n_bins), jnp.float32)
@@ -374,3 +385,130 @@ def test_dense_row_operands_through_level_histograms_on_the_mesh(
     np.testing.assert_array_equal(np.asarray(g1), g0)
     np.testing.assert_array_equal(np.asarray(h1), h0)
     assert np.abs(g0).sum() > 0
+
+
+# ---------------------------------------------------------------------------
+# a lockstep group's (T, R) row state handed to the kernel whole: the trees
+# of a pass share every row tile's bin one-hot, and each tree's histograms
+# are its own call's bit for bit
+# ---------------------------------------------------------------------------
+
+def _group_case(rng, n_trees, n_slots, n, c, n_bins):
+    binsT = rng.integers(0, n_bins, (c, n)).astype(np.int32)
+    # -1, the dump slot S and past it: rows of no slot of this level
+    slot = rng.integers(-1, n_slots + 2, (n_trees, n)).astype(np.int32)
+    grad = rng.normal(0, 1, (n_trees, n)).astype(np.float32)
+    hess = rng.uniform(0.5, 1.5, (n_trees, n)).astype(np.float32)
+    return [jnp.asarray(a) for a in (binsT, slot, grad, hess)]
+
+
+def _kernel_of(form, n_bins):
+    """The bins kernel, or the fused one on values b + 0.5 against cuts
+    1, 2, ... (bin b, clamped to n_bins - 2 as the fused lookup does)."""
+    from shifu_tpu.ops.pallas_hist import level_histograms_pallas
+
+    def kernel(binsT, slot, grad, hess, n_slots, **kw):
+        if form == "fused":
+            c = binsT.shape[0]
+            cuts = jnp.broadcast_to(
+                jnp.arange(1, n_bins - 1, dtype=jnp.float32),
+                (c, n_bins - 2))
+            return level_histograms_fused(
+                jnp.minimum(binsT, n_bins - 2).astype(jnp.float32) + 0.5,
+                cuts, slot, grad, hess, n_slots, n_bins, interpret=True, **kw)
+        return level_histograms_pallas(binsT, slot, grad, hess, n_slots,
+                                       n_bins, interpret=True, **kw)
+
+    return kernel
+
+
+def _assert_each_tree_its_own_call(kernel, binsT, slot, grad, hess, n_slots,
+                                   **kw):
+    got_g, got_h = kernel(binsT, slot, grad, hess, n_slots, **kw)
+    assert got_g.shape == got_h.shape == (
+        slot.shape[0], n_slots, binsT.shape[0], got_g.shape[-1])
+    for t in range(slot.shape[0]):
+        want_g, want_h = kernel(binsT, slot[t], grad[t], hess[t], n_slots,
+                                **kw)
+        np.testing.assert_array_equal(np.asarray(got_g[t]),
+                                      np.asarray(want_g), err_msg=f"G {t}")
+        np.testing.assert_array_equal(np.asarray(got_h[t]),
+                                      np.asarray(want_h), err_msg=f"H {t}")
+    assert float(jnp.abs(got_h).sum()) > 0
+
+
+@pytest.mark.parametrize("form", ["bins", "fused"])
+@pytest.mark.parametrize("n_slots", [1, 2, 8, 16, 32, 64, 128])
+@pytest.mark.parametrize("n_trees", [1, 2, 4, 5])
+def test_grouped_pass_is_each_trees_own_call(form, n_trees, n_slots):
+    """The grouped entry (interpret mode) against per-tree calls stacked
+    by hand, bit for bit: one tree, a pass of all the trees, the trees
+    over more passes than one (five at 16 and 32 slots: a last pass of
+    padded trees), one tree a pass from 64 slots up; rows that fill no
+    whole row tile, rows at -1, at the dump slot and past it."""
+    binsT, slot, grad, hess = _group_case(
+        np.random.default_rng((n_trees, n_slots)), n_trees, n_slots,
+        9 * _TILE + 77, 5, 16)
+    _assert_each_tree_its_own_call(_kernel_of(form, 16), binsT, slot, grad,
+                                   hess, n_slots, row_tile=_TILE)
+
+
+def test_trees_a_pass_fill_one_pass_of_the_array_at_one_tile():
+    """Trees a pass: as many as the 128 rows of one MXU pass hold, G and
+    H of each (2·Tp·S8 = 128 at the edge: 8 trees at 8 slots, 4 at 16,
+    2 at 32), one from 64 slots up, never more than the group; and none
+    that would change the row tile: a budget under which the stacked
+    operand of more trees would shrink it keeps one tree a pass."""
+    from shifu_tpu.ops.pallas_hist import derive_tiles, trees_per_pass
+    c, b = 28, 64
+    cases = {(8, 8): 8, (9, 8): 8, (4, 1): 4, (4, 8): 4, (4, 16): 4,
+             (5, 16): 4, (8, 16): 4, (4, 32): 2, (3, 32): 2, (4, 64): 1,
+             (4, 128): 1, (4, 256): 1, (1, 8): 1, (2, 2): 2}
+    for (t, s), tp in cases.items():
+        assert trees_per_pass(t, s, c, b) == tp, (t, s)
+        s8 = -(-s // 8) * 8
+        assert 2 * tp * s8 <= 128 or tp == 1
+        assert derive_tiles(c, tp * s8, b) == derive_tiles(c, s8, b)
+    assert trees_per_pass(4, 8, 1, b) == 4        # the leaf level's column
+    assert trees_per_pass(4, 8, c, b, highest=True) == 4
+
+
+def test_a_budget_that_would_shrink_the_tile_keeps_one_tree_a_pass(
+        monkeypatch):
+    """At 16 MiB of VMEM the one-tree tile at 28 columns x 64 bins stays
+    (512, 28), and a stacked operand of two trees would halve the row
+    tile: the grouped entry then takes a tree a pass, and every tree is
+    its own call's bit for bit at the tile both derive."""
+    from shifu_tpu.ops.pallas_hist import derive_tiles, trees_per_pass
+    monkeypatch.setenv("SHIFU_TPU_HIST_VMEM_MB", "16")
+    assert derive_tiles(28, 8, 64) == (512, 28)
+    assert derive_tiles(28, 16, 64)[0] < 512
+    assert trees_per_pass(4, 8, 28, 64) == 1
+    binsT, slot, grad, hess = _group_case(np.random.default_rng(41), 3, 8,
+                                          2 * 512 + 77, 28, 64)
+    _assert_each_tree_its_own_call(_kernel_of("bins", 64), binsT, slot, grad,
+                                   hess, 8)
+    monkeypatch.setenv("SHIFU_TPU_HIST_VMEM_MB", "64")
+    assert trees_per_pass(4, 8, 28, 64) == 4
+
+
+def test_nine_trees_take_two_passes_of_eight():
+    """Eight trees fill a pass at 8 slots; a ninth takes a second pass,
+    padded with trees of no rows, inside the same call."""
+    binsT, slot, grad, hess = _group_case(np.random.default_rng(41), 9, 8,
+                                          3 * _TILE + 5, 4, 16)
+    _assert_each_tree_its_own_call(_kernel_of("bins", 16), binsT, slot, grad,
+                                   hess, 8, row_tile=_TILE)
+
+
+def test_grouped_pass_keeps_the_f32_exact_tile(monkeypatch):
+    """SHIFU_TPU_HIST_PRECISION=highest: one tree derives the 128-lane
+    row tile, and so does a pass of three trees; each tree's histograms
+    are its own call's bit for bit."""
+    from shifu_tpu.ops.pallas_hist import trees_per_pass
+    monkeypatch.setenv("SHIFU_TPU_HIST_PRECISION", "highest")
+    assert trees_per_pass(3, 8, 5, 16) == 3
+    binsT, slot, grad, hess = _group_case(np.random.default_rng(41), 3, 8,
+                                          9 * _TILE + 77, 5, 16)
+    _assert_each_tree_its_own_call(_kernel_of("bins", 16), binsT, slot, grad,
+                                   hess, 8)

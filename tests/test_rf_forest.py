@@ -143,10 +143,60 @@ def test_a_device_table_gives_the_forest_of_the_same_host_rows(table):
     assert _same(on_device, _forest(table))
 
 
-def test_job_span_says_how_the_forest_was_grown(table, monkeypatch,
-                                                tmp_path):
+def _per_tree_pass(binsT, slot_T, grad_T, hess_T, n_level_nodes, n_bins):
+    """A lockstep level's histograms a tree at a time: each tree's own
+    (R,) kernel call (interpret mode), stacked."""
+    from shifu_tpu.ops.pallas_hist import level_histograms_pallas
+    each = [level_histograms_pallas(binsT, slot_T[t], grad_T[t], hess_T[t],
+                                    n_level_nodes, n_bins, interpret=True)
+            for t in range(slot_T.shape[0])]
+    return tuple(jnp.stack(a) for a in zip(*each))
+
+
+@pytest.mark.parametrize("placement", ["one_device", "mesh"])
+def test_grouped_kernel_gives_the_per_tree_kernels_forest(table, monkeypatch,
+                                                          placement):
+    """The histogram kernel forced on (interpret mode): the forest whose
+    levels take the group's row state in one grouped pass (seven trees
+    a pass up to 8 slots) equals, bit for bit, the forest whose levels
+    take each tree's own kernel call; on one device, and on the rig's
+    eight-device data mesh, where each chip's pass runs inside
+    `_forest_level_histograms`' `shard_map` before the one `psum`."""
+    bins, y, w, cfg = table
+    if placement == "one_device":
+        device = jax.devices()[0]
+        bins, y, w = (jax.device_put(jnp.asarray(a), device)
+                      for a in (bins.T, y, w))
+    monkeypatch.setenv("SHIFU_TPU_HIST", "pallas")
+
+    def forest():
+        jax.clear_caches()       # the route is read while a program traces
+        return gbdt.build_rf(cfg, bins, y, w, 7, "TWOTHIRDS", 1.0, seed=SEED)
+
+    try:
+        grouped = forest()
+        monkeypatch.setattr(gbdt, "_local_forest_histograms", _per_tree_pass)
+        per_tree = forest()
+    finally:
+        jax.clear_caches()
+    assert _same(grouped, per_tree)
+    assert len({tuple(f) for f in grouped["feature"]}) > 1
+
+
+@pytest.mark.parametrize("route,shared", [("xla", 0), ("pallas", 30)])
+def test_job_span_says_how_the_forest_was_grown(table, monkeypatch, request,
+                                                tmp_path, route, shared):
+    """`shifu:train.job` says how the forest was grown (trees, groups,
+    subset, bag rate), and how many of its tree-levels came from a
+    histogram pass shared with another tree: at depth 4 and 9 columns,
+    groups of 3, 3 and 1 take three trees a pass at every one of the
+    five levels (the leaf level's 8 slots on one column among them) on
+    the kernel's route, none on the scatter's."""
     from shifu_tpu.obs import trace as obs_trace
     from tests.test_train_spans import _profiled_spans
+    monkeypatch.setenv("SHIFU_TPU_HIST", route)
+    jax.clear_caches()           # the route is read while a program traces
+    request.addfinalizer(jax.clear_caches)
     assert obs_trace.span_registered("train.bag")
     assert "bag" in obs_trace.DEVICE_SCOPES
     assert obs_trace.device_scopes(
@@ -161,6 +211,8 @@ def test_job_span_says_how_the_forest_was_grown(table, monkeypatch,
     assert (int(stats["trees"]), int(stats["group_trees"]),
             int(stats["groups"]), int(stats["subset_cols"])) == (7, 3, 3, 6)
     assert float(stats["bag_rate"]) == 1.0
+    assert (int(stats["hist_tree_levels"]),
+            int(stats["hist_shared_levels"])) == (7 * 5, shared)
     bags = [e for e in evs if e[0] == "shifu:train.bag"]
     programs = [e for e in evs if e[0] == "shifu:train.program"]
     assert [int(e[3]["trees"]) for e in bags] == [3, 3, 1]
