@@ -966,27 +966,35 @@ def _walk_level_gather(tree, binsT, node, n_bins: int):
                      2 * node + jnp.where(go_left, 1, 2))
 
 
-def _walk_trees(trees, binsT, max_depth: int, n_bins: int):
-    """Per-tree landing node of every row. binsT: (C, R). Each level
-    takes the form its width asks for (`walk_levels`); both land every
-    row on the same node."""
+def _bin_matrix(binsT, n_bins: int):
+    """The (C, R) int32 bins a walk reads. Prediction re-walks every
+    feature per level, so fused inputs are binned once here rather than
+    re-derived per level (the fused path optimizes the level BUILD; a
+    resume/val predict is a one-off)."""
     if isinstance(binsT, FusedBins):
-        # prediction re-walks every feature per level — bin once here
-        # rather than re-deriving per level (the fused path optimizes
-        # the level BUILD; a resume/val predict is a one-off)
         from shifu_tpu.ops.pallas_hist import bins_from_values
-        binsT = bins_from_values(binsT.valuesT, binsT.cuts, n_bins)
+        return bins_from_values(binsT.valuesT, binsT.cuts, n_bins)
+    return binsT
 
-    def one_tree(tree):
-        node = jnp.zeros(binsT.shape[1], jnp.int32)
-        for depth in range(max_depth):
-            if 2 ** depth <= _WALK_SELECT_SLOTS:
-                node = _walk_level_select(tree, binsT, node, depth, n_bins)
-            else:
-                node = _walk_level_gather(tree, binsT, node, n_bins)
-        return node
 
-    return jax.vmap(one_tree)(trees)
+def _walk_tree(tree, binsT, max_depth: int, n_bins: int):
+    """One tree's landing node of every row, (R,); binsT: (C, R) int32.
+    Each level takes the form its width asks for (`walk_levels`); both
+    land every row on the same node."""
+    node = jnp.zeros(binsT.shape[1], jnp.int32)
+    for depth in range(max_depth):
+        if 2 ** depth <= _WALK_SELECT_SLOTS:
+            node = _walk_level_select(tree, binsT, node, depth, n_bins)
+        else:
+            node = _walk_level_gather(tree, binsT, node, n_bins)
+    return node
+
+
+def _walk_trees(trees, binsT, max_depth: int, n_bins: int):
+    """Per-tree landing node of every row, (T, R). binsT: (C, R)."""
+    binsT = _bin_matrix(binsT, n_bins)
+    return jax.vmap(lambda tree: _walk_tree(tree, binsT, max_depth,
+                                            n_bins))(trees)
 
 
 def _leaf_values(tree, node):
@@ -998,13 +1006,24 @@ def _leaf_values(tree, node):
     return values[node]
 
 
+def _predict_tree(tree, binsT, max_depth: int, n_bins: int):
+    """One tree's leaf value for every row, (R,), walked on the rows'
+    own (R,) vectors; binsT: (C, R) int32. A caller with one tree at a
+    time calls this, not `predict_trees` of a stack of one: under `vmap`
+    the compiler lays a tree axis of 1 to 4 second-minor, and the
+    511-slot leaf lookup then fills a vector register with one row where
+    it could hold eight (PERF.md §6)."""
+    return _leaf_values(tree, _walk_tree(tree, binsT, max_depth, n_bins))
+
+
 @partial(jax.jit, static_argnames=("max_depth", "n_bins"))
 def predict_trees(trees, binsT, max_depth: int, n_bins: int):
     """Sum of per-tree leaf values. trees: pytree of (T, n_nodes)
     arrays; binsT: (C, R) transposed. Returns (T, R) raw scores (caller
     averages for RF / shrinks+offsets for GBT)."""
-    nodes = _walk_trees(trees, binsT, max_depth, n_bins)
-    return jax.vmap(_leaf_values)(trees, nodes)
+    binsT = _bin_matrix(binsT, n_bins)
+    return jax.vmap(lambda tree: _predict_tree(tree, binsT, max_depth,
+                                               n_bins))(trees)
 
 
 @partial(jax.jit, static_argnames=("max_depth", "n_bins"))
@@ -1286,6 +1305,16 @@ def build_gbt(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
         return stacked, val_errs
 
 
+def _group_leaf_values(values_T, node_T):
+    """(G, R) leaf values of a lockstep group's rows: `_lookup` of each
+    tree's (S,) values on its own (R,) node vector, a tree at a time, as
+    `_gbt_round_core` looks its one tree up. Under `vmap` the compiler
+    lays the group's axis of 1 to 4 trees second-minor, and the S-slot
+    reduction fills a vector register with one row where it could hold
+    eight (PERF.md §6)."""
+    return jax.lax.map(lambda a: _lookup(*a), (values_T, node_T))
+
+
 def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w, inst_w, pred_T,
                            fm_T, mesh=None, subtract=None):
     """One boosting round of a lockstep group of G bags: bag b's
@@ -1298,8 +1327,7 @@ def _gbt_bagged_round_core(cfg: TreeConfig, binsT, y, w, inst_w, pred_T,
                                    mesh=mesh, subtract=subtract,
                                    return_nodes=True)
     with jax.named_scope("leaf"):
-        contrib_T = jax.vmap(lambda tr, n: _lookup(tr["leaf_value"], n)
-                             )(trees_T, node_T)
+        contrib_T = _group_leaf_values(trees_T["leaf_value"], node_T)
         return (_replicated(mesh, trees_T),
                 _by_row(mesh, pred_T + cfg.learning_rate * contrib_T))
 
@@ -1391,6 +1419,10 @@ def build_gbt_bagged(cfg: TreeConfig, bins: np.ndarray, y: np.ndarray,
                         bag_rate=float(bagging_rate),
                         psum_bytes=psum_bytes(cfg, c, hist_mesh, subtract,
                                               group),
+                        # a tree at a time: each round's update and, with
+                        # validation rows, its walk, for every bag
+                        leaf_lookups_per_tree=n_trees * n_bags * (
+                            1 + (val_data is not None)),
                         **hist_levels(cfg, c, sizes, subtract, n_trees)):
         with obs_trace.span("train.prepare"):
             fm = np.asarray(feature_mask if feature_mask is not None
@@ -1786,9 +1818,8 @@ def _leaf_contrib_chunk(cfg: TreeConfig, tree, node_c):
 
 @partial(jax.jit, static_argnames=("cfg",))
 def _predict_chunk(cfg: TreeConfig, tree, binsT_c):
-    return predict_trees(jax.tree.map(lambda a: a[None], tree),
-                         binsT_c.astype(jnp.int32),
-                         cfg.max_depth, cfg.n_bins)[0]
+    return _predict_tree(tree, binsT_c.astype(jnp.int32), cfg.max_depth,
+                         cfg.n_bins)
 
 
 @partial(jax.jit, static_argnames=("loss",))

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from shifu_tpu.models.gbdt import TreeConfig, _val_error, predict_trees
+from shifu_tpu.models.gbdt import TreeConfig, _predict_tree, _val_error
 
 
 def place(val_data, mesh):
@@ -45,15 +45,16 @@ def place(val_data, mesh):
 def shifu_validate(cfg: TreeConfig, trees_T, vbinsT, vraw_T, vy, vw):
     """One round of validation for G ensembles: each one's new tree
     (`trees_T`, (G, nodes)) walked over the validation rows
-    (`gbdt.predict_trees`), its leaf values added at the learning rate
+    (`gbdt._predict_tree`), its leaf values added at the learning rate
     into that ensemble's raw scores `vraw_T` (G, V), and each one's
     `gbdt._val_error`. Returns (vraw_T, (G,) errors)."""
     with jax.named_scope("validate"):
-        # one tree at a time: the temporaries are one tree's whatever the
-        # group (a gather walk batched over a group of five compiled to
-        # 16 GB at 2^22 rows; one tree's select walk holds 0.4 MB)
-        score = jax.lax.map(lambda tree: predict_trees(
-            jax.tree.map(lambda a: a[None], tree), vbinsT, cfg.max_depth,
-            cfg.n_bins)[0], trees_T)
+        # one tree at a time, on the rows' own (V,) vectors: the
+        # temporaries are one tree's whatever the group (a gather walk
+        # batched over a group of five compiled to 16 GB at 2^22 rows;
+        # one tree's select walk holds 0.4 MB), and no tree axis of one
+        # is laid second-minor under the leaf lookup
+        score = jax.lax.map(lambda tree: _predict_tree(
+            tree, vbinsT, cfg.max_depth, cfg.n_bins), trees_T)
         vraw_T = vraw_T + cfg.learning_rate * score
         return vraw_T, _val_error(vraw_T, vy, vw, cfg.loss)

@@ -647,7 +647,11 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
     predictions and weights, its raw scores), stay under what
     `gbdt.rf_group_bytes` reckons for the group, from which the builder
     sizes its groups: a round of four holds 2.82 GB of arguments and
-    1.97 GB of temporaries for 11.39 GB reckoned."""
+    1.97 GB of temporaries for 11.39 GB reckoned. Both programs look a
+    tree's 511 leaf values up on its own (rows,) vector: no 511-slot
+    reduction carries a unit or group axis ahead of the rows, which the
+    compiler lays second-minor (a (4, 128) or (1, 128) tile: the vector
+    unit at half or an eighth of its rows)."""
     import re
     from shifu_tpu.models import gbdt, tree_validation
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -662,7 +666,8 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
         cfg, shape((c, r), I32), shape((r,)), shape((r,)),
         shape((group, r)), shape((group, r)), shape((group, c)), mesh=None,
         subtract=True).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 2 * depth + 1
+    round_text = compiled.as_text()
+    assert round_text.count("tpu_custom_call") == 2 * depth + 1
     round_memory = compiled.memory_analysis()
     trees = jax.tree.map(lambda a: shape((group,) + a.shape, a.dtype),
                          jax.eval_shape(lambda: gbdt._empty_tree(cfg)))
@@ -676,6 +681,10 @@ def test_gbt_higgs_bagged_round_and_validation_compile_inside_their_bytes(
     assert gbdt.walk_levels(depth) == {"walk_select_levels": depth,
                                        "walk_gather_levels": 0}
     assert not re.search(r"= \S+ gather\(", val_text), "the walk gathers"
+    for text, rows in ((round_text, r), (val_text, v)):
+        assert not re.search(rf"\[\d+,511,{rows}\]", text), \
+            "a leaf lookup runs under a tree axis"
+        assert f"[511,{rows}]" in text, "no 511-slot leaf lookup"
     val_memory = validated.memory_analysis()
     others = bags - group if group < bags else 0
     held = (round_memory.argument_size_in_bytes

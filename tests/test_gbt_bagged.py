@@ -187,6 +187,32 @@ def test_a_small_group_gives_the_one_group_trees_bit_for_bit(rng,
         assert ea == eb
 
 
+@pytest.mark.parametrize("group", [1, 2, 4, 5])
+def test_a_tree_at_a_time_update_gives_the_vmapped_lookups_bits(group):
+    """A round's boosting update looks each tree's leaf values up on its
+    own (R,) node vector (`_group_leaf_values`): bit for bit what `vmap`
+    of `_lookup` over the group gives, -0.0 kept, for groups of 1, 2, 4
+    and 5 bags, with rows parked at -1 and at ids outside the 511-slot
+    table reading 0."""
+    rng = np.random.default_rng(4200 + group)
+    n_nodes, rows = 511, 1000
+    values = rng.standard_normal((group, n_nodes)).astype(np.float32)
+    values[rng.random((group, n_nodes)) < 0.05] = -0.0
+    node = rng.integers(0, n_nodes, (group, rows)).astype(np.int32)
+    parked = rng.random((group, rows))
+    node[parked < 0.05] = -1
+    node[parked > 0.95] = n_nodes + rng.integers(0, 600, (group, rows))[
+        parked > 0.95]
+    values, node = jnp.asarray(values), jnp.asarray(node)
+    got = np.asarray(jax.jit(gbdt._group_leaf_values)(values, node))
+    want = np.asarray(jax.jit(jax.vmap(gbdt._lookup))(values, node))
+    assert got.shape == (group, rows)
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+    outside = (np.asarray(node) < 0) | (np.asarray(node) >= n_nodes)
+    assert outside.any() and not got[outside].view(np.int32).any()
+    assert (got.view(np.int32) == np.float32(-0.0).view(np.int32)).any()
+
+
 def _per_tree_pass(binsT, slot_T, grad_T, hess_T, n_level_nodes, n_bins):
     """A lockstep level's histograms a bag at a time: each bag's own
     (R,) kernel call (interpret mode), stacked."""
@@ -291,12 +317,13 @@ def test_device_validation_rows_give_the_host_rows_errors(rng, builder):
 def test_the_job_span_and_its_phases(rng, monkeypatch, request, tmp_path,
                                      route, shared):
     """`shifu:train.job` says how the bags were grown (bags, groups,
-    group size, validation rows) and how many of its tree-levels came
-    from a histogram pass shared with another bag: 3 rounds of groups of
-    2, 2 and 1 at depth 3, where on the kernel's route each group of two
-    takes both bags a pass at all four levels and on the scatter's none
-    does; a group's draw is one `train.bag`, a round one
-    `train.validate` after its groups' programs."""
+    group size, validation rows), how many leaf lookups ran a tree at a
+    time (each round's update and walk of every bag) and how many of its
+    tree-levels came from a histogram pass shared with another bag: 3
+    rounds of groups of 2, 2 and 1 at depth 3, where on the kernel's
+    route each group of two takes both bags a pass at all four levels and
+    on the scatter's none does; a group's draw is one `train.bag`, a
+    round one `train.validate` after its groups' programs."""
     from shifu_tpu.obs import trace as obs_trace
     from tests.test_train_spans import _profiled_spans
     monkeypatch.setenv("SHIFU_TPU_HIST", route)
@@ -317,6 +344,8 @@ def test_the_job_span_and_its_phases(rng, monkeypatch, request, tmp_path,
             int(stats["steps"])) == (5, 3, 2, 400, 3)
     assert (int(stats["hist_tree_levels"]),
             int(stats["hist_shared_levels"])) == (3 * 5 * 4, shared)
+    # each round's update and walk of every bag, a tree at a time
+    assert int(stats["leaf_lookups_per_tree"]) == 3 * 5 * 2
     bags = [e for e in evs if e[0] == "shifu:train.bag"]
     assert [int(e[3]["trees"]) for e in bags] == [2, 2, 1]
     validate = sorted(e for e in evs if e[0] == "shifu:train.validate")

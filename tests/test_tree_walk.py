@@ -1,8 +1,10 @@
-"""The walk of a finished tree (`gbdt._walk_trees`, under `predict_trees`,
-`leaf_indices` and `tree_validation.shifu_validate`) against the per-row
-gather walk it replaced, kept here as the oracle in numpy: the same
-landing node for every row and the same leaf-value bits, whatever form
-each level takes (`gbdt.walk_levels`)."""
+"""The walk of a finished tree (`gbdt._walk_tree`, under `predict_trees`,
+`leaf_indices`, `tree_validation.shifu_validate` and the streaming tier's
+`_predict_chunk`) against the per-row gather walk it replaced, kept here
+as the oracle in numpy: the same landing node for every row and the same
+leaf-value bits, whatever form each level takes (`gbdt.walk_levels`).
+Callers that walk one tree at a time (`gbdt._predict_tree`) give the
+bits of `predict_trees` over a stack of that one tree."""
 
 import math
 
@@ -102,22 +104,27 @@ WALK_CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(WALK_CASES))
-def test_walk_lands_where_the_gather_walk_lands(case):
+def _case_trees_and_bins(case):
     rng = _rng(case)
     n_trees, depth, n_rows, n_cols = WALK_CASES[case]
     leaf_share = 0.02 if case == "mixed_forms" else 0.15
     trees = _random_trees(rng, n_trees, depth, n_cols, leaf_share)
-    binsT = _random_bins(rng, n_rows, n_cols)
-    walked = jnp.asarray(binsT)
-    if case == "fused_bins":
-        # raw values whose cuts give back `binsT`: value = bin, cut k at
-        # k + 0.5, NaN for the missing bin
-        values = np.where(binsT == N_BINS - 1, np.nan,
-                          binsT.astype(np.float32))
-        cuts = np.broadcast_to(np.arange(N_BINS - 2, dtype=np.float32)
-                               + 0.5, (n_cols, N_BINS - 2))
-        walked = FusedBins(jnp.asarray(values), jnp.asarray(cuts))
+    return trees, _random_bins(rng, n_rows, n_cols), depth
+
+
+def _fused(binsT):
+    """Raw values whose cuts give back `binsT`: value = bin, cut k at
+    k + 0.5, NaN for the missing bin."""
+    values = np.where(binsT == N_BINS - 1, np.nan, binsT.astype(np.float32))
+    cuts = np.broadcast_to(np.arange(N_BINS - 2, dtype=np.float32) + 0.5,
+                           (binsT.shape[0], N_BINS - 2))
+    return FusedBins(jnp.asarray(values), jnp.asarray(cuts))
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_walk_lands_where_the_gather_walk_lands(case):
+    trees, binsT, depth = _case_trees_and_bins(case)
+    walked = _fused(binsT) if case == "fused_bins" else jnp.asarray(binsT)
     want = _oracle_nodes(trees, binsT, depth, N_BINS)
     got = np.asarray(gbdt.leaf_indices(trees, walked, depth, N_BINS))
     np.testing.assert_array_equal(got, want)
@@ -137,12 +144,37 @@ def test_walk_lands_where_the_gather_walk_lands(case):
                           "walk_gather_levels": 0}
 
 
-@pytest.mark.parametrize("group", [4, 1])
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_one_tree_walk_gives_the_stack_of_ones_bits(case):
+    """`gbdt._predict_tree`, one tree walked on the rows' own (R,)
+    vectors, as `shifu_validate`'s `lax.map` and the streaming tier's
+    `_predict_chunk` call it: bit for bit what `predict_trees` gives for
+    a stack of that one tree, in every walk case (fused inputs binned
+    by `predict_trees` itself)."""
+    trees, binsT, depth = _case_trees_and_bins(case)
+    walked = _fused(binsT) if case == "fused_bins" else jnp.asarray(binsT)
+    cfg = TreeConfig(max_depth=depth, n_bins=N_BINS)
+    one = jax.jit(gbdt._predict_tree, static_argnums=(2, 3))
+    for t in range(np.asarray(trees["feature"]).shape[0]):
+        tree = jax.tree.map(lambda a: a[t], trees)
+        stacked = gbdt.predict_trees(jax.tree.map(lambda a: a[None], tree),
+                                     walked, depth, N_BINS)[0]
+        np.testing.assert_array_equal(
+            _bits(one(tree, jnp.asarray(binsT), depth, N_BINS)),
+            _bits(stacked))
+        # the streaming tier's chunk takes int bins of any width
+        chunk = gbdt._predict_chunk(cfg, tree,
+                                    jnp.asarray(binsT, jnp.int16))
+        np.testing.assert_array_equal(_bits(chunk), _bits(stacked))
+
+
+@pytest.mark.parametrize("group", [5, 4, 2, 1])
 def test_shifu_validate_gives_the_gather_walks_scores_and_errors(group):
     """A round's validation program: each tree's leaf values added at the
     learning rate into its ensemble's raw scores and each ensemble's
     error, bit for bit what the same arithmetic gives on the oracle's
-    scores."""
+    scores and on `predict_trees` over a stack of each one tree, for
+    groups of 1, 2, 4 and 5 trees."""
     rng = _rng(f"validate{group}")
     depth, n_rows, n_cols = 5, 1000, 6
     cfg = TreeConfig(max_depth=depth, n_bins=N_BINS, learning_rate=0.1,
@@ -160,6 +192,10 @@ def test_shifu_validate_gives_the_gather_walks_scores_and_errors(group):
     want_err = gbdt._val_error(want_raw, vy, vw, cfg.loss)
     np.testing.assert_array_equal(_bits(got_raw), _bits(want_raw))
     np.testing.assert_array_equal(_bits(got_err), _bits(want_err))
+    stacks = jnp.concatenate([gbdt.predict_trees(
+        jax.tree.map(lambda a: a[t:t + 1], trees), jnp.asarray(binsT), depth,
+        N_BINS) for t in range(group)])
+    np.testing.assert_array_equal(_bits(stacks), _bits(score))
 
 
 @pytest.mark.parametrize("builder", ["build_gbt", "build_gbt_bagged"])
